@@ -8,12 +8,7 @@ underscores (PILOSA_TPU_CLUSTER_REPLICAS, matching the reference's PILOSA_*).
 from __future__ import annotations
 
 import os
-
-try:
-    import tomllib
-except ModuleNotFoundError:  # Python < 3.11: same parser under its PyPI name
-    import tomli as tomllib
-
+import tomllib
 from dataclasses import dataclass, field
 
 from pilosa_tpu.utils.duration import parse_duration

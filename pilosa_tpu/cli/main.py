@@ -245,9 +245,16 @@ def cmd_server(args) -> int:
         tracing_sampler_param=cfg.tracing.sampler_param,
         tracing_endpoint=cfg.tracing.agent_host_port,
     ).open()
-    mesh_desc = f"{mesh.size}-device mesh" if mesh is not None else "1 device"
+    # name the device actually serving: a server that came up on the CPU
+    # because of an exported JAX_PLATFORMS must say so on its first line
+    import jax
+    from pilosa_tpu import native
+    devs = list(mesh.devices.flat) if mesh is not None else jax.devices()[:1]
     print(f"pilosa-tpu {__version__} serving at {server.uri} "
-          f"(data: {data_dir}, node: {server.node_id}, {mesh_desc})",
+          f"(data: {data_dir}, node: {server.node_id}, "
+          f"platform: {devs[0].platform}, device_kind: "
+          f"{devs[0].device_kind!r}, devices: {len(devs)}, native storage: "
+          f"{'loaded' if native.available() else 'NOT loaded (numpy path)'})",
           flush=True)
 
     stop = threading.Event()

@@ -100,10 +100,21 @@ class View:
             if os.path.exists(cache_path):
                 self.rank_caches[shard] = load_cache(cache_path)
             else:
-                cache = make_cache(self.cache_type, self.cache_size)
-                cache.bulk_add((rid, frag.row_count(rid)) for rid in frag.row_ids())
-                self.rank_caches[shard] = cache
+                self.rank_caches[shard] = self._rebuilt_rank_cache(frag)
         return frag
+
+    def _rebuilt_rank_cache(self, frag: Fragment):
+        """A fresh rank cache from the fragment's exact row counts: ONE
+        vectorized key pass (Fragment.row_counts) — per-row row_count scans
+        the whole key space per row on the dict store, quadratic in a
+        10k-row field — under the fragment write lock, so a concurrent
+        import into the same fragment can neither mutate the store mid-pass
+        nor store an older count over a newer one."""
+        cache = make_cache(self.cache_type, self.cache_size)
+        with frag.mu:
+            ids = frag.row_ids()
+            cache.bulk_add(zip(ids, frag.row_counts(ids).tolist()))
+        return cache
 
     # -- fragment routing ---------------------------------------------------
 
@@ -173,9 +184,7 @@ class View:
         frag = self.fragments.get(shard)
         if frag is None:
             return
-        cache = make_cache(self.cache_type, self.cache_size)
-        cache.bulk_add((rid, frag.row_count(rid)) for rid in frag.row_ids())
-        self.rank_caches[shard] = cache
+        self.rank_caches[shard] = self._rebuilt_rank_cache(frag)
 
     def load_frozen_fragment(self, shard: int, positions: np.ndarray,
                              presorted: bool = False) -> Fragment:

@@ -1,16 +1,25 @@
 """Native runtime: C++ storage kernels loaded via ctypes.
 
-Builds `libroaring_native.so` from roaring_native.cc on first import (g++
--O3 -march=native), with a pure-numpy fallback when no compiler is present.
-Use `available()` to check, `lib()` for the raw handle; the typed wrappers
-below are what storage code calls.
+Builds the shared library from roaring_native.cc on first import (g++ -O3
+-march=native), with a pure-numpy fallback when no compiler is present —
+a failed build or load is logged once, loudly, never swallowed. The
+library's file name carries a key over the source, the compile command and
+this host's CPU flags, so a checkout copied from another machine (the
+library is git-ignored but rides along in a directory copy) never loads
+that machine's -march=native code: a different CPU means a different name
+and a fresh build. Use `available()` to check, `lib()` for the raw handle;
+the typed wrappers below are what storage code calls.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
 import os
+import platform
 import subprocess
+import tempfile
 import threading
 from typing import Optional
 
@@ -18,21 +27,61 @@ import numpy as np
 
 _HERE = os.path.dirname(__file__)
 _SRC = os.path.join(_HERE, "roaring_native.cc")
-_SO = os.path.join(_HERE, "libroaring_native.so")
+_CXX = ["g++", "-O3", "-march=native", "-shared", "-fPIC", "-std=c++17"]
 
+_log = logging.getLogger(__name__)
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _build_failed = False
 
 
-def _build() -> bool:
-    cmd = ["g++", "-O3", "-march=native", "-shared", "-fPIC", "-std=c++17",
-           "-o", _SO, _SRC]
+def _cpu_flags() -> str:
+    """What -march=native keys on: the machine type plus the first CPU's
+    feature flags (/proc/cpuinfo; empty where the file is absent)."""
+    flags = ""
     try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("flags", "Features")):
+                    flags = " ".join(sorted(line.split(":", 1)[1].split()))
+                    break
+    except OSError:
+        pass
+    return f"{platform.machine()} {flags}"
+
+
+def _so_path() -> str:
+    h = hashlib.sha256()
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    h.update(" ".join(_CXX).encode())
+    h.update(_cpu_flags().encode())
+    return os.path.join(_HERE,
+                        f"libroaring_native-{h.hexdigest()[:16]}.so")
+
+
+def _build(so: str) -> bool:
+    """Compile to a temporary name and rename into place: two processes
+    importing at once (a subprocess cluster's nodes) each build their own
+    file, and whichever renames last wins with a complete library."""
+    fd, tmp = tempfile.mkstemp(prefix=".build-", suffix=".so", dir=_HERE)
+    os.close(fd)
+    try:
+        subprocess.run(_CXX + ["-o", tmp, _SRC], check=True,
+                       capture_output=True, timeout=120)
+        os.chmod(tmp, 0o755)  # mkstemp's 0600 would survive the rename
+        os.replace(tmp, so)
         return True
-    except (subprocess.SubprocessError, FileNotFoundError, OSError):
+    except (subprocess.SubprocessError, OSError) as e:
+        detail = getattr(e, "stderr", b"") or b""
+        _log.warning(
+            "native storage library NOT built (%s: %s) — storage runs on "
+            "the slower numpy path\n%s", type(e).__name__, e,
+            detail.decode(errors="replace")[-2000:])
         return False
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
 
 
 def lib() -> Optional[ctypes.CDLL]:
@@ -42,15 +91,21 @@ def lib() -> Optional[ctypes.CDLL]:
     with _lock:
         if _lib is not None or _build_failed:
             return _lib
-        if not os.path.exists(_SO) or (
-                os.path.exists(_SRC)
-                and os.path.getmtime(_SRC) > os.path.getmtime(_SO)):
-            if not _build():
-                _build_failed = True
-                return None
         try:
-            handle = ctypes.CDLL(_SO)
-        except OSError:
+            so = _so_path()
+        except OSError as e:
+            _log.warning("native storage library source unreadable (%s) — "
+                         "storage runs on the slower numpy path", e)
+            _build_failed = True
+            return None
+        if not os.path.exists(so) and not _build(so):
+            _build_failed = True
+            return None
+        try:
+            handle = ctypes.CDLL(so)
+        except OSError as e:
+            _log.warning("native storage library %s failed to load (%s) — "
+                         "storage runs on the slower numpy path", so, e)
             _build_failed = True
             return None
         _configure(handle)

@@ -752,6 +752,10 @@ class Handler:
         # latency histograms, batcher queue-wait split, h2d/d2h bytes
         from pilosa_tpu.utils import telemetry as _telemetry
         snap["kernels"] = _telemetry.kernels.snapshot()
+        # which devices this node serves from (platform, device_kind,
+        # allocator stats): the first thing to read before trusting any
+        # device number from this dump
+        snap["deviceMemory"] = _telemetry.device_memory_stats()
         # on-demand XLA profile capture state (POST /debug/device-profile)
         snap["deviceProfiler"] = _telemetry.device_profiler.snapshot()
         return self._json(snap)

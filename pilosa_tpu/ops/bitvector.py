@@ -661,8 +661,7 @@ def patch_sparse_rows(sp: jax.Array, adds: jax.Array,
 
 
 def eval_hybrid(program, leaves: list, kinds: list,
-                n_words: int = SHARD_WIDTH // WORD_BITS,
-                sparse_dense_fn=None):
+                n_words: int = SHARD_WIDTH // WORD_BITS):
     """Evaluate a nested-tuple bitmap program over MIXED dense/sparse/run
     leaves -> (kind, device array). The representation flows bottom-up:
     intersections keep the cheapest faithful representation (sparse∩* is
@@ -674,12 +673,7 @@ def eval_hybrid(program, leaves: list, kinds: list,
     operands of unions/xors (point-set growth under ∪/^ is unbounded for
     intervals). Dispatched eagerly per node (operand shapes differ per
     node, so one fused program would recompile per query shape anyway);
-    each kernel is a tiny K- or R-slot pass. `sparse_dense_fn` swaps the
-    sparse∩dense kernel (the Pallas blocked variant plugs in here,
-    ops/pallas_kernels.py) so the gated path cannot drift from the XLA
-    contract."""
-    sd = sparse_dense_fn or sparse_intersect_dense
-
+    each kernel is a tiny K- or R-slot pass."""
     def dense_of(kind, arr):
         if kind == "sparse":
             return sparse_to_dense(arr, n_words)
@@ -707,9 +701,9 @@ def eval_hybrid(program, leaves: list, kinds: list,
                 elif k == "run" and k2 == "run":
                     acc = run_intersect(acc, x)
                 elif k == "sparse":
-                    acc = sd(acc, x)
+                    acc = sparse_intersect_dense(acc, x)
                 elif k2 == "sparse":
-                    acc, k = sd(x, acc), "sparse"
+                    acc, k = sparse_intersect_dense(x, acc), "sparse"
                 elif k == "run":
                     acc, k = run_intersect_dense(acc, x, n_words), "dense"
                 elif k2 == "run":
@@ -742,8 +736,7 @@ def eval_hybrid(program, leaves: list, kinds: list,
 
 
 def hybrid_count(program, leaves: list, kinds: list,
-                 n_words: int = SHARD_WIDTH // WORD_BITS,
-                 sparse_dense_fn=None) -> int:
+                 n_words: int = SHARD_WIDTH // WORD_BITS) -> int:
     """Total count of a mixed dense/sparse/run program — sparse results
     count their live slots, run results sum interval lengths (neither
     ever materializes a plane), dense results popcount.
@@ -769,8 +762,7 @@ def hybrid_count(program, leaves: list, kinds: list,
             acc = run_intersect(acc, x)
         return int(np.asarray(run_intersect_count(acc, ops[-1])).sum())
 
-    kind, arr = eval_hybrid(program, leaves, kinds, n_words=n_words,
-                            sparse_dense_fn=sparse_dense_fn)
+    kind, arr = eval_hybrid(program, leaves, kinds, n_words=n_words)
     if kind == "sparse":
         per_shard = sparse_count(arr)
     elif kind == "run":

@@ -4,8 +4,10 @@ The XLA path (parallel/mesh.py) already fuses bitwise ops into the popcount
 reduce; these kernels additionally control blocking explicitly — one shard's
 lane block per grid step, accumulated in SMEM — so multi-operand programs
 never materialize intermediates in HBM, and give a place to fuse future
-device-side container decompression. Falls back to interpret mode off-TPU
-(tests run on the CPU backend).
+device-side container decompression. On a TPU the kernels compile through
+Mosaic; on the CPU backend (tier-1) they run in interpret mode, which
+checks results but neither tiling nor VMEM — chip_smoke.py's kernel phase
+compiles every entry point here at production shape.
 """
 
 from __future__ import annotations
@@ -21,14 +23,29 @@ from jax.experimental.pallas import tpu as pltpu
 from pilosa_tpu.utils.telemetry import counted_jit
 
 # one shard row = 32768 uint32 lanes = [256, 128] tiles; block 16 shards
-# deep to amortize grid overhead (16 * 128 KiB * 2 operands * 2 pipeline
-# buffers = 8 MiB of VMEM, inside the 16 MiB scoped limit; measured r3:
-# blk=16 streams ~379 GB/s on v5e, matching the XLA scan path)
+# deep to amortize grid overhead. Each operand block is double-buffered by
+# the pipeline, so a kernel holds n_operands * blk * W * 4 B * 2 in VMEM:
+# 8 MiB for two [16, 32768] operands, inside the 16 MiB scoped limit.
+# Kernels whose operand count varies size their block from the arity
+# (_program_block).
 SHARD_BLOCK = 16
+# operand-block VMEM budget (double buffers included): leaves headroom
+# under the 16 MiB scoped limit for the output tile and compiler scratch
+_VMEM_OPERAND_BUDGET = 12 << 20
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Interpret mode is a decision per platform, not a default: the CPU
+    backend interprets (tier-1 needs it), a TPU compiles, and any other
+    backend is refused rather than silently interpreted."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"pallas kernels support the tpu (compiled) and cpu (interpreted) "
+        f"backends, not {backend!r}")
 
 
 def _and_count_kernel(blk, a_ref, b_ref, out_ref):
@@ -77,10 +94,30 @@ def intersect_count(a: jax.Array, b: jax.Array) -> jax.Array:
     return padded[:s, 0]
 
 
+def _program_block(n_leaves: int, w: int) -> tuple[int, int]:
+    """(shard, word) block for an n-leaf program: the deepest block whose
+    double-buffered operands fit _VMEM_OPERAND_BUDGET. Depth halves down
+    to the 8-sublane minimum first; past that (7+ full-width leaves) the
+    word axis splits, which costs an accumulation over a second grid axis."""
+    blk_s, blk_w = SHARD_BLOCK, w
+    while n_leaves * blk_s * blk_w * 4 * 2 > _VMEM_OPERAND_BUDGET:
+        if blk_s > 8:
+            blk_s //= 2
+        elif blk_w % 256 == 0:  # halves stay 128-lane multiples
+            blk_w //= 2
+        else:
+            raise ValueError(
+                f"{n_leaves} leaves of width {w} exceed the VMEM budget")
+    return blk_s, blk_w
+
+
 def _program_count_kernel(program, n_leaves, blk, *refs):
-    """Evaluate a static bitmap program over leaf blocks, fused popcount."""
+    """Evaluate a static bitmap program over leaf blocks, fused popcount,
+    accumulated over the word grid axis (innermost, so the output block
+    stays pinned while operand blocks stream)."""
     leaf_refs = refs[:n_leaves]
     out_ref = refs[n_leaves]
+    wb = pl.program_id(1)
 
     def ev(p):
         if p[0] == "leaf":
@@ -102,7 +139,12 @@ def _program_count_kernel(program, n_leaves, blk, *refs):
 
     res = ev(program)
     counts = jnp.sum(jax.lax.population_count(res).astype(jnp.int32), axis=-1)
-    out_ref[...] = jnp.broadcast_to(counts[:, None], (blk, 128))
+
+    @pl.when(wb == 0)
+    def _init():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    out_ref[...] += jnp.broadcast_to(counts[:, None], (blk, 128))
 
 
 @counted_jit("pallas", static_argnames=("program",))
@@ -127,14 +169,14 @@ def program_count(leaves, program) -> jax.Array:
         leaf_list = [padded_stack[j] for j in range(leaves.shape[0])]
     n_leaves = len(leaf_list)
     sp, w = leaf_list[0].shape
-    blk = SHARD_BLOCK
+    blk, wblk = _program_block(n_leaves, w)
     kernel = functools.partial(_program_count_kernel, program, n_leaves, blk)
     padded = pl.pallas_call(
         kernel,
-        grid=(sp // blk,),
-        in_specs=[pl.BlockSpec((blk, w), lambda i: (i, 0))
+        grid=(sp // blk, w // wblk),
+        in_specs=[pl.BlockSpec((blk, wblk), lambda i, j: (i, j))
                   for _ in range(n_leaves)],
-        out_specs=pl.BlockSpec((blk, 128), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((blk, 128), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((sp, 128), jnp.int32),
         interpret=_interpret(),
     )(*leaf_list)
@@ -267,56 +309,6 @@ def pair_stream_counts(rows: jax.Array, ii: jax.Array,
         interpret=_interpret(),
     )(ii, jj, rows, rows)
     return out[:, 0, 0]
-
-
-# -- hybrid sparse containers -------------------------------------------------
-# The sparse∩dense gather-and-test (ops/bitvector.py sparse_intersect_dense)
-# with explicit shard blocking: one (shard-block) step holds the [blk, K]
-# index tile and the [blk, W] dense tile in VMEM and emits the masked index
-# tile — the dense operand streams HBM->VMEM double-buffered instead of
-# relying on XLA's gather fusion. Plugs into bitvector.eval_hybrid as
-# `sparse_dense_fn` (PILOSA_TPU_PALLAS=1), so the gated path shares the
-# sentinel/sort contract with the XLA form and cannot drift.
-
-
-def _sparse_dense_kernel(a_ref, b_ref, out_ref):
-    from pilosa_tpu.ops.bitvector import SPARSE_SENTINEL
-
-    idx = a_ref[...]                                   # [blk, K] int32
-    dense = b_ref[...]                                 # [blk, W] uint32
-    safe = jnp.minimum(idx, SPARSE_SENTINEL - 1)
-    w = jnp.take_along_axis(dense, safe >> 5, axis=-1)
-    bit = (w >> (safe & 31).astype(jnp.uint32)) & jnp.uint32(1)
-    hit = (bit != 0) & (idx < SPARSE_SENTINEL)
-    out_ref[...] = jnp.where(hit, idx, SPARSE_SENTINEL)
-
-
-@counted_jit("pallas")
-def sparse_intersect_dense(sp: jax.Array, dense: jax.Array) -> jax.Array:
-    """int32[S, K] sparse row x uint32[S, W] dense plane -> sorted
-    sentinel-padded int32[S, K] intersection — the Pallas form of
-    bitvector.sparse_intersect_dense (parity tested in tests/test_hybrid.py).
-    Zero-padded pad shards are harmless: a pad index 0 tests bit 0 of a
-    zero dense pad row, misses, and masks to the sentinel."""
-    from pilosa_tpu.ops.bitvector import SPARSE_SENTINEL  # noqa: F401
-
-    s, k = sp.shape
-    w = dense.shape[-1]
-    sp_p, dense_p = _pad_shards(sp, 0), _pad_shards(dense, 0)
-    spd = sp_p.shape[0]
-    blk = SHARD_BLOCK
-    masked = pl.pallas_call(
-        _sparse_dense_kernel,
-        grid=(spd // blk,),
-        in_specs=[
-            pl.BlockSpec((blk, k), lambda i: (i, 0)),
-            pl.BlockSpec((blk, w), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((blk, k), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((spd, k), jnp.int32),
-        interpret=_interpret(),
-    )(sp_p, dense_p)
-    return jnp.sort(masked[:s], axis=-1)
 
 
 # -- TopN: fused popcount-rank over the candidate slab ------------------------
@@ -531,36 +523,24 @@ def bsi_sum_counts(planes: jax.Array, filter_row: jax.Array) -> jax.Array:
     return out[:s, :depth + 1].T
 
 
-def available() -> bool:
-    """Pallas compiles on this backend (real TPU or interpret fallback)."""
-    try:
-        a = np.zeros((1, 256), dtype=np.uint32)
-        intersect_count(jnp.asarray(a), jnp.asarray(a))
-        return True
-    except Exception:  # noqa: BLE001
-        return False
-
-
 # -- mesh composition (shard_map wrappers) -----------------------------------
 # pallas_call computes on per-device blocks, so composing with a mesh is a
 # shard_map whose body runs the single-device kernel on its local shard
 # slice and psums the partials over the shard axis on ICI — PILOSA_TPU_PALLAS
-# now works on the same replica×shard meshes as the XLA path (VERDICT r3
-# weak #3: DeviceRunner used to force use_pallas=False under a mesh).
+# works on the same replica×shard meshes as the XLA path.
 
 
 @functools.lru_cache(maxsize=None)
 def _program_count_mesh_fn(mesh, program, n_leaves: int):
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from pilosa_tpu.parallel.mesh import SHARD_AXIS
 
     @counted_jit("pallas")
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(tuple(P(SHARD_AXIS, None) for _ in range(n_leaves)),),
-        out_specs=P(), check_rep=False)
+        out_specs=P(), check_vma=False)
     def run(leaves_blk):
         counts = program_count(leaves_blk, program)  # local [S_loc]
         return jax.lax.psum(jnp.sum(counts), SHARD_AXIS)
@@ -580,7 +560,6 @@ def program_count_mesh(mesh, leaves: tuple, program) -> jax.Array:
 
 @functools.lru_cache(maxsize=None)
 def _pair_stream_mesh_fn(mesh):
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from pilosa_tpu.parallel.mesh import REPLICA_AXIS, SHARD_AXIS
@@ -589,9 +568,9 @@ def _pair_stream_mesh_fn(mesh):
 
     @counted_jit("pallas")
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(None, SHARD_AXIS, None), rep_spec, rep_spec),
-        out_specs=rep_spec, check_rep=False)
+        out_specs=rep_spec, check_vma=False)
     def run(rows_blk, ii_blk, jj_blk):
         local = pair_stream_counts(rows_blk, ii_blk, jj_blk)  # [K_loc]
         return jax.lax.psum(local, SHARD_AXIS)

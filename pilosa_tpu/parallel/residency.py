@@ -31,7 +31,9 @@ import numpy as np
 from pilosa_tpu.utils import accounting
 from pilosa_tpu.utils import profile as qprofile
 
-DEFAULT_BUDGET_BYTES = 4 << 30  # half a v5e chip's HBM
+# a quarter of a v5e chip's 16 GB of HBM; the rest is headroom for query
+# intermediates (TopN recount slabs, GroupBy axis slabs, BSI masks)
+DEFAULT_BUDGET_BYTES = 4 << 30
 
 
 class DeviceResidency:

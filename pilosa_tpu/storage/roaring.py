@@ -796,6 +796,11 @@ class Bitmap:
         if hasattr(self.containers, "irange"):
             # ordered store: O(log n + k) range walk instead of full scan
             return list(self.containers.irange(lo, hi))
+        if hi - lo < len(self.containers):
+            # a narrow range (one row = 16 keys) in a wide store: probe
+            # the range instead of scanning every key — per-row reads of a
+            # 10k-row fragment were otherwise quadratic in its key count
+            return [k for k in range(lo, hi + 1) if k in self.containers]
         return sorted(k for k in self.containers if lo <= k <= hi)
 
     def min(self) -> Optional[int]:

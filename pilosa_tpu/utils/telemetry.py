@@ -664,6 +664,7 @@ def device_memory_stats() -> list[dict]:
             stats = None
         out.append({"device": str(d),
                     "platform": getattr(d, "platform", "?"),
+                    "device_kind": getattr(d, "device_kind", "?"),
                     "memoryStats": stats})
     return out
 

@@ -4,8 +4,7 @@ Three layers under test:
 
 * the sparse kernel family (ops/bitvector.py): padded sorted-index
   algebra vs a numpy set-algebra oracle, including sentinel padding,
-  empty rows, the galloping orientation, and the Pallas blocked
-  sparse∩dense variant's parity;
+  empty rows and the galloping orientation;
 * the HybridManager (parallel/residency.py): threshold choice,
   promote/demote hysteresis, heat-informed demotion, kill switches;
 * the executor integration: sparse leaves in the residency manager with
@@ -92,23 +91,6 @@ def test_sparse_kernels_empty_rows():
     assert _as_set(bv.sparse_difference(full, empty)) == {1, 5, 9}
     assert int(np.asarray(bv.sparse_count(empty))[0]) == 0
     assert np.asarray(bv.sparse_to_dense(empty, W)).sum() == 0
-
-
-def test_pallas_sparse_dense_parity():
-    """The blocked Pallas gather-and-test variant returns bit-identical
-    sorted sentinel-padded output (the PILOSA_TPU_PALLAS contract)."""
-    from pilosa_tpu.ops import pallas_kernels as pk
-
-    rng = np.random.default_rng(3)
-    sa = set(rng.choice(SHARD_WIDTH, 500, replace=False).tolist())
-    sb = set(rng.choice(SHARD_WIDTH, 5000, replace=False).tolist())
-    sp = jnp.asarray(np.stack(
-        [bv.sparse_from_columns(np.asarray(sorted(sa)), 512)] * 3))
-    dense = jnp.asarray(np.stack(
-        [bv.dense_from_columns(np.asarray(sorted(sb)))] * 3))
-    want = np.asarray(bv.sparse_intersect_dense(sp, dense))
-    got = np.asarray(pk.sparse_intersect_dense(sp, dense))
-    assert (want == got).all()
 
 
 def test_eval_hybrid_mixed_tree():

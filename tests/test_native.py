@@ -86,3 +86,61 @@ def test_oplog_parse():
     bad = bytearray(data)
     bad[9] ^= 0xFF
     assert native.oplog_parse(bytes(bad)) is None
+
+
+# -- the loader: built from what git commits, never a foreign library --------
+
+
+def _fresh_loader(monkeypatch, tmp_path):
+    """Point the loader at a scratch directory holding only the source."""
+    import shutil
+
+    shutil.copy(native._SRC, tmp_path / "roaring_native.cc")
+    monkeypatch.setattr(native, "_HERE", str(tmp_path))
+    monkeypatch.setattr(native, "_SRC", str(tmp_path / "roaring_native.cc"))
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_build_failed", False)
+
+
+def test_loader_never_loads_a_foreign_library(monkeypatch, tmp_path):
+    """A library built on another machine (the git-ignored .so rides along
+    in a directory copy) has another name — the name keys on this host's
+    CPU flags — so it is never dlopen'ed: the loader builds its own."""
+    _fresh_loader(monkeypatch, tmp_path)
+    mine = native._so_path()
+    monkeypatch.setattr(native, "_cpu_flags", lambda: "x86_64 some other cpu")
+    foreign = native._so_path()
+    monkeypatch.undo()
+    _fresh_loader(monkeypatch, tmp_path)
+    assert foreign != mine and native._so_path() == mine
+    # what a copied tree carries: the old fixed name and a foreign build.
+    # Loading either would fail loudly here (garbage, not ELF) — on a real
+    # foreign CPU it would be an illegal instruction with no traceback
+    for name in ("libroaring_native.so", foreign.rsplit("/", 1)[1]):
+        (tmp_path / name).write_bytes(b"built for another machine")
+    handle = native.lib()
+    assert handle is not None and handle._name == mine
+    assert native.popcount64(np.array([3, 1], dtype=np.uint64)) == 3
+    # built under a temporary name and renamed into place: nothing left over
+    assert not [p.name for p in tmp_path.iterdir()
+                if p.name.startswith(".build-")]
+
+
+def test_loader_name_follows_the_source(monkeypatch, tmp_path):
+    _fresh_loader(monkeypatch, tmp_path)
+    before = native._so_path()
+    with open(native._SRC, "a") as f:
+        f.write("\n// edited\n")
+    assert native._so_path() != before
+
+
+def test_failed_build_is_logged_not_swallowed(monkeypatch, tmp_path, caplog):
+    _fresh_loader(monkeypatch, tmp_path)
+    monkeypatch.setattr(native, "_CXX", ["g++", "--no-such-flag"])
+    with caplog.at_level("WARNING", logger="pilosa_tpu.native"):
+        assert native.lib() is None
+        assert native.lib() is None  # logged once: the failure is latched
+    logged = [r for r in caplog.records if "NOT built" in r.getMessage()]
+    assert len(logged) == 1
+    # the numpy path still answers
+    assert native.popcount64(np.array([3, 1], dtype=np.uint64)) == 3

@@ -283,12 +283,9 @@ def test_qos_config_toml_roundtrip(tmp_path):
     rendered = Config()
     rendered.qos.mode = "enforce"
     rendered.qos.principals = {"key:x": {"queries-per-s": 9.0}}
-    import tomli as tomllib  # noqa: F401 — py3.10 fallback name
-    try:
-        import tomllib as tl
-    except ModuleNotFoundError:
-        import tomli as tl
-    back = tl.loads(rendered.to_toml())
+    import tomllib
+
+    back = tomllib.loads(rendered.to_toml())
     assert back["qos"]["mode"] == "enforce"
     assert back["qos"]["principals"]["key:x"]["queries-per-s"] == 9.0
 
