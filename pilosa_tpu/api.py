@@ -201,6 +201,22 @@ class API:
             return True
         return explicit or self.long_query_time > 0
 
+    @staticmethod
+    def _parse(pql: str):
+        """PQL text -> Query through the parse cache, under the
+        `pql.parse` span (tag `cached`; a concurrent thread's miss can
+        read as this one's)."""
+        from pilosa_tpu.pql import parse_string_cached
+        with tracing.span("pql.parse") as sp:
+            misses = parse_string_cached.cache_info().misses
+            try:
+                query = parse_string_cached(pql)
+            except ValueError as e:
+                raise ApiError(str(e))
+            sp.set_tag("cached",
+                       misses == parse_string_cached.cache_info().misses)
+        return query
+
     def query_results(self, index_name: str, pql: str,
                       shards: Optional[list[int]] = None,
                       remote: bool = False,
@@ -225,11 +241,7 @@ class API:
             raise NotFoundError(f"index not found: {index_name}")
         query = pql
         if isinstance(pql, str):
-            from pilosa_tpu.pql import parse_string_cached
-            try:
-                query = parse_string_cached(pql)
-            except ValueError as e:
-                raise ApiError(str(e))
+            query = self._parse(pql)
         if self.max_writes_per_request > 0:
             # reject oversized write batches up front (MaxWritesPerRequest,
             # api.go / http handler validation; server/config.go:47);
@@ -411,11 +423,7 @@ class API:
             raise NotFoundError(f"index not found: {index_name}")
         query = pql
         if isinstance(pql, str):
-            from pilosa_tpu.pql import parse_string_cached
-            try:
-                query = parse_string_cached(pql)
-            except ValueError as e:
-                raise ApiError(str(e))
+            query = self._parse(pql)
         from pilosa_tpu import planner as _planner
         out = []
         for call in query.calls:

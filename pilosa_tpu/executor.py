@@ -48,7 +48,7 @@ from pilosa_tpu.pql import (
     parse_string_cached,
 )
 from pilosa_tpu.pql.ast import BETWEEN, EQ, GT, GTE, LT, LTE, NEQ
-from pilosa_tpu.utils import qctx
+from pilosa_tpu.utils import qctx, tracing
 from pilosa_tpu.utils import profile as qprofile
 
 WORDS = SHARD_WIDTH // 32
@@ -125,9 +125,7 @@ class Executor:
         # observability (nop defaults; reference: executor per-call counters
         # executor.go:258-293, spans executor.go:85)
         from pilosa_tpu.utils.stats import NopStatsClient
-        from pilosa_tpu.utils import tracing
         self.stats = NopStatsClient()
-        self.tracer = tracing.global_tracer
         # host row cache: (index, field, view, shard, row, generation) ->
         # dense numpy row (the reference's fragment rowCache analog,
         # fragment.go:112)
@@ -514,8 +512,9 @@ class Executor:
             # linear mutation scanner; unique column ids make them
             # useless to the LRU plan cache and the full parser is ~10x
             # slower per call. Everything else keeps the cached parse.
-            query = (parse_mutations_fast(query)
-                     or parse_string_cached(query))
+            with tracing.span("pql.parse"):
+                query = (parse_mutations_fast(query)
+                         or parse_string_cached(query))
         if not isinstance(query, Query):
             raise TypeError("query must be a PQL string or Query")
         index = self.holder.index(index_name)
@@ -559,8 +558,8 @@ class Executor:
             for call in query.calls:
                 qctx.check()
                 self.stats.count(f"query/{call.name}")
-                t_call = _time.perf_counter() if prof is not None else 0.0
-                with self.tracer.start_span(f"executor.{call.name}") as span:
+                with tracing.span(f"executor.{call.name}",
+                                  index=index_name) as span:
                     if distributed:
                         result = self._execute_distributed(index, call, shards)
                     else:
@@ -569,12 +568,12 @@ class Executor:
                         # ids -> keys on the coordinator only; remote
                         # sub-results stay raw (translateResults,
                         # executor.go:2323,2483)
-                        result = self._translate_result(index, call, result)
+                        with tracing.span("reduce"):
+                            result = self._translate_result(
+                                index, call, result)
                     results.append(result)
-                    span.set_tag("index", index_name)
                 if prof is not None:
-                    prof.record_call(
-                        call.name, (_time.perf_counter() - t_call) * 1e3)
+                    prof.record_call(call.name, span.ms)
             return results
         finally:
             if dl_token is not None:
@@ -593,8 +592,9 @@ class Executor:
             # short-circuit / pushdown-mark, then install the plan node so
             # plan-cache events recorded during evaluation join it. The
             # profiler serializes it as the call's `plan` entry.
-            call, plan_info = self.planner.plan_call(
-                index, call, self._query_shards(index, shards))
+            with tracing.span("plan"):
+                call, plan_info = self.planner.plan_call(
+                    index, call, self._query_shards(index, shards))
             plan_tok = _planner.current_plan.set(plan_info)
             prof = qprofile.current_profile.get()
             if prof is not None:
@@ -1199,6 +1199,12 @@ class Executor:
         return doc
 
     def _compile(self, index: Index, call: Call, shards: list[int]):
+        """_compile_tree under the `leaves` span: residency lookups, and on
+        a first touch the leaf's build and upload (its child spans)."""
+        with tracing.span("leaves"):
+            return self._compile_tree(index, call, shards)
+
+    def _compile_tree(self, index: Index, call: Call, shards: list[int]):
         """Walk the call tree -> (program, leaves, kinds) where leaves are
         HBM-resident device arrays from the residency manager and kinds[i]
         marks leaf i "dense" ([S, W] uint32 plane), "sparse" ([S, slots]
@@ -1502,22 +1508,25 @@ class Executor:
         pc = self.plan_cache
         if (pc is not None and pc.enabled
                 and child.name in _planner.BITMAP_CALLS):
-            key = _planner.subtree_cache_key(self, index, child, shards)
-            if key is not None:
-                key = ("count",) + key  # scalar value, distinct from the
-                # dense row result of the same subtree
-                epoch = pc.epoch
-                cached = pc.get(key)
-                _planner.record_cache_event(child, cached is not None)
-                if cached is not None:
-                    # cached Counts heat their operands too (see
-                    # _composed_row_dev: reuse is still access)
-                    self._heat_call_touch(index, child, shards, reads=1)
-                    self._record_actual(cached)
-                    return cached
+            with tracing.span("plan"):
+                key = _planner.subtree_cache_key(self, index, child, shards)
+                cached = None
+                if key is not None:
+                    key = ("count",) + key  # scalar value, distinct from
+                    # the dense row result of the same subtree
+                    epoch = pc.epoch
+                    cached = pc.get(key)
+                    _planner.record_cache_event(child, cached is not None)
+            if cached is not None:
+                # cached Counts heat their operands too (see
+                # _composed_row_dev: reuse is still access)
+                self._heat_call_touch(index, child, shards, reads=1)
+                self._record_actual(cached)
+                return cached
         n = self._count_device(index, child, shards)
         if key is not None:
-            pc.put(key, int(n), PlanCache.SCALAR_COST, epoch=epoch)
+            with tracing.span("plan"):
+                pc.put(key, int(n), PlanCache.SCALAR_COST, epoch=epoch)
         self._record_actual(n)
         return n
 
@@ -1531,8 +1540,6 @@ class Executor:
             plan["actualCardinality"] = int(count)
 
     def _count_device(self, index: Index, child: Call, shards) -> int:
-        import time as _time
-
         from pilosa_tpu.utils import accounting
         program, leaves, kinds = self._compile(index, child, shards)
         if "sparse" in kinds or "run" in kinds:
@@ -1542,39 +1549,18 @@ class Executor:
             # (the hybrid-count pushdown). Skips the batcher and the
             # dense chain kernel, which both assume uint32 planes.
             from pilosa_tpu.ops import bitvector as bv
-            acct = accounting.current_account.get()
-            heat_on = self.heat is not None and self.heat.enabled
-            t0 = (_time.perf_counter()
-                  if (acct is not None or heat_on) else 0.0)
-            n = bv.hybrid_count(program, leaves, kinds)
-            if acct is not None or heat_on:
-                elapsed_ms = (_time.perf_counter() - t0) * 1e3
-                if acct is not None:
-                    acct.charge(device_ms=elapsed_ms)
-                if heat_on:
-                    self._heat_call_touch(index, child, shards,
-                                          device_ms=elapsed_ms)
-            return n
-        if self.batcher is not None:
+
+            def launch():
+                return bv.hybrid_count_dev(program, leaves, kinds)
+        elif self.batcher is not None and (
+                shape := self._batchable(program, leaves)) is not None:
             # concurrent Counts coalesce into one device dispatch
             # (continuous batching — parallel/batcher.py; the batcher's
-            # _run charges each co-batched query its wall-time share)
-            if program == ("leaf", 0) and len(leaves) == 1:
-                return self.batcher.count("id", leaves[0], None)
-            if (len(leaves) == 2 and isinstance(program, tuple)
-                    and len(program) == 3
-                    and program[0] in self._BATCHABLE_OPS
-                    and program[1] == ("leaf", 0)
-                    and program[2] == ("leaf", 1)
-                    and leaves[0].shape == leaves[1].shape):
-                return self.batcher.count(program[0], leaves[0], leaves[1])
-        # un-batched dispatches are this query's alone: charge full wall
-        # (batched counts above are smeared across co-batched queries —
-        # their heat was already charged per leaf in _row_leaf_dev)
-        acct = accounting.current_account.get()
-        heat_on = self.heat is not None and self.heat.enabled
-        t0 = _time.perf_counter() if (acct is not None or heat_on) else 0.0
-        if (isinstance(program, tuple) and len(program) > 3
+            # _run charges each co-batched query its wall-time share,
+            # and their heat was already charged per leaf in
+            # _row_leaf_dev)
+            return self.batcher.count(*shape)
+        elif (isinstance(program, tuple) and len(program) > 3
                 and program[0] == "and"
                 and all(p == ("leaf", i) for i, p in enumerate(program[1:]))
                 and not self.runner.use_pallas
@@ -1584,17 +1570,47 @@ class Executor:
             # arity, so cardinality-reordered chains of the same width
             # share a compilation (ops/bitvector.py)
             from pilosa_tpu.ops.bitvector import intersect_chain_count_total
-            n = int(intersect_chain_count_total(tuple(leaves)))
+
+            def launch():
+                return intersect_chain_count_total(tuple(leaves))
         else:
-            n = self.runner.count_total_leaves(leaves, program)
-        if acct is not None or heat_on:
-            elapsed_ms = (_time.perf_counter() - t0) * 1e3
-            if acct is not None:
-                acct.charge(device_ms=elapsed_ms)
-            if heat_on:
-                self._heat_call_touch(index, child, shards,
-                                      device_ms=elapsed_ms)
+            def launch():
+                return self.runner.count_total_leaves_dev(leaves, program)
+        # un-batched dispatches are this query's alone: `dispatch` is the
+        # host enqueueing the programs (asynchronous launches, which still
+        # block while the runtime's in-flight queue is full: on a busy
+        # device most of the wait is booked here), `device.wait` the one
+        # fetch that blocks on them, and the two together are the wall
+        # charged to the caller's account and the operands' heat
+        with tracing.span("dispatch") as enqueue:
+            handle = launch()
+        with tracing.span("device.wait") as wait:
+            partials = np.asarray(handle)
+        with tracing.span("reduce"):
+            # per-shard (or whole) int32 partials, finished exactly on host
+            n = int(partials.sum(dtype=np.int64))
+        elapsed_ms = enqueue.ms + wait.ms
+        acct = accounting.current_account.get()
+        if acct is not None:
+            acct.charge(device_ms=elapsed_ms)
+        if self.heat is not None and self.heat.enabled:
+            self._heat_call_touch(index, child, shards, device_ms=elapsed_ms)
         return n
+
+    def _batchable(self, program, leaves: list):
+        """(op, a, b) for the programs the continuous batcher coalesces —
+        a bare leaf, or one binary op over two leaves of one shape — else
+        None."""
+        if program == ("leaf", 0) and len(leaves) == 1:
+            return "id", leaves[0], None
+        if (len(leaves) == 2 and isinstance(program, tuple)
+                and len(program) == 3
+                and program[0] in self._BATCHABLE_OPS
+                and program[1] == ("leaf", 0)
+                and program[2] == ("leaf", 1)
+                and leaves[0].shape == leaves[1].shape):
+            return program[0], leaves[0], leaves[1]
+        return None
 
     # ------------------------------------------------- leaf materialization
 
@@ -3749,60 +3765,64 @@ class Executor:
 
     def _reduce(self, call: Call, partials: list, index: Optional[Index] = None,
                 shards: Optional[list[int]] = None):
-        """Associative reduce (reduceFn, executor.go:2209-2242)."""
-        if not partials:
-            raise ExecutionError("no shards to execute")
-        if call.name == "Count":
-            return sum(partials)
-        if call.name == "Sum":
-            return ValCount(sum(p.val for p in partials),
-                            sum(p.count for p in partials))
-        if call.name in ("Min", "Max"):
-            best = None
-            for p in partials:
-                if p.count == 0:
-                    continue
-                if best is None:
-                    best = ValCount(p.val, p.count)
-                elif p.val == best.val:
-                    best.count += p.count
-                elif (call.name == "Min") == (p.val < best.val):
-                    best = ValCount(p.val, p.count)
-            return best or ValCount(0, 0)
-        if call.name == "TopN":
-            merged = merge_pairs(partials)
-            # n=0 is the reference zero value: unlimited (same mapping as
-            # the single-node path, _execute_topn)
-            n = call.uint_arg("n") or None
-            if n is not None and call.uint_slice_arg("ids") is None and index is not None:
-                # phase 2: exact recount of winning ids on the query's shards
-                # (executor.go:694-761)
-                ids = [i for i, _ in merged[:n]]
-                return self._recount_topn(index, call, ids, shards)
-            return Pairs(merged)
-        if call.name == "Rows":
-            out = sorted(set().union(*[set(p) for p in partials]))
-            limit = call.uint_arg("limit")
-            return RowIdentifiers(out[:limit] if limit is not None else out)
-        if call.name == "GroupBy":
-            acc: dict[str, dict] = {}
-            for p in partials:
-                for g in p:
-                    key = str(g["group"])
-                    if key in acc:
-                        acc[key]["count"] += g["count"]
-                    else:
-                        acc[key] = dict(g)
-            out = sorted(acc.values(), key=lambda g: [
-                (x["field"], x["rowID"]) for x in g["group"]])
-            limit = call.uint_arg("limit")
-            return GroupCounts(out[:limit] if limit is not None else out)
-        if call.name in BITMAP_CALLS:
-            out = partials[0]
-            for p in partials[1:]:
-                out = out.merge(p)
-            return out
-        return partials[0]
+        """Associative reduce (reduceFn, executor.go:2209-2242): host work,
+        the `reduce` span, but for TopN's exact recount, a fan-out of its
+        own that the span is closed before."""
+        with tracing.span("reduce") as sp:
+            if not partials:
+                raise ExecutionError("no shards to execute")
+            if call.name == "Count":
+                return sum(partials)
+            if call.name == "Sum":
+                return ValCount(sum(p.val for p in partials),
+                                sum(p.count for p in partials))
+            if call.name in ("Min", "Max"):
+                best = None
+                for p in partials:
+                    if p.count == 0:
+                        continue
+                    if best is None:
+                        best = ValCount(p.val, p.count)
+                    elif p.val == best.val:
+                        best.count += p.count
+                    elif (call.name == "Min") == (p.val < best.val):
+                        best = ValCount(p.val, p.count)
+                return best or ValCount(0, 0)
+            if call.name == "TopN":
+                merged = merge_pairs(partials)
+                # n=0 is the reference zero value: unlimited (same mapping as
+                # the single-node path, _execute_topn)
+                n = call.uint_arg("n") or None
+                if n is not None and call.uint_slice_arg("ids") is None and index is not None:
+                    # phase 2: exact recount of winning ids on the query's shards
+                    # (executor.go:694-761)
+                    ids = [i for i, _ in merged[:n]]
+                    sp.finish()
+                    return self._recount_topn(index, call, ids, shards)
+                return Pairs(merged)
+            if call.name == "Rows":
+                out = sorted(set().union(*[set(p) for p in partials]))
+                limit = call.uint_arg("limit")
+                return RowIdentifiers(out[:limit] if limit is not None else out)
+            if call.name == "GroupBy":
+                acc: dict[str, dict] = {}
+                for p in partials:
+                    for g in p:
+                        key = str(g["group"])
+                        if key in acc:
+                            acc[key]["count"] += g["count"]
+                        else:
+                            acc[key] = dict(g)
+                out = sorted(acc.values(), key=lambda g: [
+                    (x["field"], x["rowID"]) for x in g["group"]])
+                limit = call.uint_arg("limit")
+                return GroupCounts(out[:limit] if limit is not None else out)
+            if call.name in BITMAP_CALLS:
+                out = partials[0]
+                for p in partials[1:]:
+                    out = out.merge(p)
+                return out
+            return partials[0]
 
     def _recount_topn(self, index: Index, call: Call, ids: list[int],
                       shards: Optional[list[int]]):

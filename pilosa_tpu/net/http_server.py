@@ -113,8 +113,12 @@ class Handler:
     def __init__(self, api: API,
                  cluster_message_fn: Optional[Callable[[dict], None]] = None,
                  stats=None, query_timeout: float = 0.0, telemetry=None,
-                 qos_plane=None, events=None):
+                 qos_plane=None, events=None, tracer=None):
         self.api = api
+        # the node's recording Tracer (utils/tracing.py): dispatch installs
+        # it for the request, so every span opened while serving reports
+        # to THIS node's ring and exporter. None = aggregates only.
+        self.tracer = tracer
         self.cluster_message_fn = cluster_message_fn
         self.stats = stats
         self.query_timeout = query_timeout  # [cluster] query-timeout default
@@ -185,10 +189,16 @@ class Handler:
                  headers=None, client_addr=None):
         """-> (status, content_type, payload bytes)."""
         self._local.headers = headers
+        tracer_token = tracing.current_tracer.set(self.tracer)
         # extractTracing middleware (http/handler.go:226-234): adopt the
         # caller's trace id for every span opened while serving this request
         incoming_trace = (headers or {}).get(tracing.TRACE_HEADER) if headers else None
         token = tracing.current_trace_id.set(incoming_trace) if incoming_trace else None
+        # http.admit: the middleware below, route match, deadline and
+        # admission, up to the handler call (closed there, or on the way
+        # out where no handler ran)
+        admit = tracing.span("http.admit").__enter__()
+        is_work = False
         if self.events is not None and headers is not None \
                 and hasattr(headers, "get"):
             # HLC piggyback (utils/events.py): merge the caller's stamp
@@ -252,6 +262,12 @@ class Handler:
                 if is_work:
                     with self._counter_lock:
                         self.active_queries += 1
+                    if token is None:
+                        # one trace id for the whole request (the root
+                        # span's, minted when it opened), so logs, the
+                        # query history, exported spans and fan-out RPCs
+                        # all join on it
+                        token = tracing.current_trace_id.set(admit.trace_id)
                 try:
                     # inside the try: an invalid ?timeout= must map to a
                     # clean 400 like any other ApiError, not escape dispatch
@@ -317,6 +333,7 @@ class Handler:
                                 qos.retry_after_header(rej.retry_after),
                             "X-Pilosa-Shed-Reason": rej.reason})
                     else:
+                        admit.finish()
                         resp = handler(match.groupdict(), query, body)
                 except qctx.QueryTimeoutError as e:
                     resp = self._error(504, str(e))
@@ -343,6 +360,14 @@ class Handler:
                         self.stats.count("http/serverErrors")
                 return resp
         finally:
+            admit.finish()
+            root = admit.parent
+            if (not is_work and root is not None
+                    and root.name == "http.request"):
+                # the span table's `http.request` is a served work
+                # request; status, debug and scrape routes are apart
+                root.name = "http.other"
+            tracing.current_tracer.reset(tracer_token)
             if token is not None:
                 tracing.current_trace_id.reset(token)
             if acct_token is not None:
@@ -410,7 +435,8 @@ class Handler:
 
     @staticmethod
     def _json(payload, status: int = 200):
-        return status, "application/json", json.dumps(payload).encode()
+        with tracing.span("http.encode"):
+            return status, "application/json", json.dumps(payload).encode()
 
     @staticmethod
     def _body_json(body: bytes) -> dict:
@@ -491,8 +517,9 @@ class Handler:
                 from pilosa_tpu.utils import profile as qprofile
                 got = qprofile.last_profile.get()
                 prof = got.to_dict() if got is not None else None
-            payload = self.serializer.encode_query_response(
-                results, column_attr_sets=cas, profile=prof)
+            with tracing.span("http.encode"):
+                payload = self.serializer.encode_query_response(
+                    results, column_attr_sets=cas, profile=prof)
             return 200, PROTO_CONTENT_TYPE, payload
         return self._json(self.api.query(params["index"], pql,
                                          shards=shard_list, remote=remote,
@@ -752,6 +779,11 @@ class Handler:
         # latency histograms, batcher queue-wait split, h2d/d2h bytes
         from pilosa_tpu.utils import telemetry as _telemetry
         snap["kernels"] = _telemetry.kernels.snapshot()
+        # the span table (utils/tracing.py SpanStats): per span name n /
+        # wallMs / selfMs / cpuMs / log2 buckets since process start, and
+        # the clock (`nowMs`) a delta of two dumps is taken over — the
+        # one source per layer boundary
+        snap["spans"] = tracing.spans.snapshot()
         # which devices this node serves from (platform, device_kind,
         # allocator stats): the first thing to read before trusting any
         # device number from this dump
@@ -1184,6 +1216,8 @@ class Handler:
         counts.update(kcounts)
         timings = dict(snap.get("timings", {}))
         timings.update(ktimings)
+        # pilosa_spanMs{span=...}: wall time per span name
+        timings.update(tracing.spans.metrics_view())
         # HBM residency families: accounted bytes per representation
         # (zeros, plan cache and drift included) — the full rep keyspace
         # emitted unconditionally so headroom/drift alerts need no
@@ -1491,32 +1525,48 @@ class _RequestHandler(BaseHTTPRequestHandler):
             # listener on this same port
             self.close_connection = True
             return
-        parsed = urlparse(self.path)
-        length = int(self.headers.get("Content-Length", 0) or 0)
-        body = self.rfile.read(length) if length else b""
+        # http.request: the server-side whole of one request, the root of
+        # its span tree, from the parsed request line to the last byte
+        # written (the contextvar is this connection thread's: reset it,
+        # the next keep-alive request starts its own tree)
+        tracer_token = tracing.current_tracer.set(self.handler.tracer)
+        try:
+            with tracing.span("http.request", trace_id=self.headers.get(
+                    tracing.TRACE_HEADER)):
+                self._serve(method)
+        finally:
+            tracing.current_tracer.reset(tracer_token)
+
+    def _serve(self, method: str):
+        with tracing.span("http.read"):
+            parsed = urlparse(self.path)
+            length = int(self.headers.get("Content-Length", 0) or 0)
+            body = self.rfile.read(length) if length else b""
+            query = parse_qs(parsed.query)
         out = self.handler.dispatch(
-            method, parsed.path, parse_qs(parsed.query), body,
+            method, parsed.path, query, body,
             headers=self.headers, client_addr=self.client_address[0])
         # dispatch returns (status, ctype, payload[, extra-headers]) —
         # the 4th element carries e.g. Retry-After on QoS rejections
         status, ctype, payload = out[0], out[1], out[2]
         extra = out[3] if len(out) > 3 else None
-        self.send_response(status)
-        self.send_header("Content-Type", ctype)
-        self.send_header("Content-Length", str(len(payload)))
-        if self.handler.events is not None:
-            # HLC piggyback on every response: the caller merges it so
-            # its later events sort after anything this node recorded
-            # while serving (utils/events.py)
-            from pilosa_tpu.utils import events as _events
-            self.send_header(
-                _events.HLC_HEADER,
-                _events.encode_hlc(self.handler.events.clock.now()))
-        if extra:
-            for k, v in extra.items():
-                self.send_header(k, v)
-        self.end_headers()
-        self.wfile.write(payload)
+        with tracing.span("http.write"):
+            self.send_response(status)
+            self.send_header("Content-Type", ctype)
+            self.send_header("Content-Length", str(len(payload)))
+            if self.handler.events is not None:
+                # HLC piggyback on every response: the caller merges it so
+                # its later events sort after anything this node recorded
+                # while serving (utils/events.py)
+                from pilosa_tpu.utils import events as _events
+                self.send_header(
+                    _events.HLC_HEADER,
+                    _events.encode_hlc(self.handler.events.clock.now()))
+            if extra:
+                for k, v in extra.items():
+                    self.send_header(k, v)
+            self.end_headers()
+            self.wfile.write(payload)
 
     def do_GET(self):
         self._handle("GET")

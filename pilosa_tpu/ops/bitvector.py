@@ -737,9 +737,20 @@ def eval_hybrid(program, leaves: list, kinds: list,
 
 def hybrid_count(program, leaves: list, kinds: list,
                  n_words: int = SHARD_WIDTH // WORD_BITS) -> int:
-    """Total count of a mixed dense/sparse/run program — sparse results
-    count their live slots, run results sum interval lengths (neither
-    ever materializes a plane), dense results popcount.
+    """Total count of a mixed dense/sparse/run program: hybrid_count_dev's
+    per-shard partials, fetched (the one blocking call) and summed on the
+    host."""
+    return int(np.asarray(
+        hybrid_count_dev(program, leaves, kinds, n_words)).sum())
+
+
+def hybrid_count_dev(program, leaves: list, kinds: list,
+                     n_words: int = SHARD_WIDTH // WORD_BITS) -> jax.Array:
+    """Per-shard counts of a mixed dense/sparse/run program, launched and
+    NOT fetched — sparse results count their live slots, run results sum
+    interval lengths (neither ever materializes a plane), dense results
+    popcount. Every kernel is enqueued asynchronously; the caller's fetch
+    of the returned array is what waits for the device.
 
     The reduction stays PER-SHARD on device and sums on host: every
     hybrid kernel is per-shard local (zero collectives), so on a mesh the
@@ -760,16 +771,14 @@ def hybrid_count(program, leaves: list, kinds: list,
         acc = ops[0]
         for x in ops[1:-1]:
             acc = run_intersect(acc, x)
-        return int(np.asarray(run_intersect_count(acc, ops[-1])).sum())
+        return run_intersect_count(acc, ops[-1])
 
     kind, arr = eval_hybrid(program, leaves, kinds, n_words=n_words)
     if kind == "sparse":
-        per_shard = sparse_count(arr)
-    elif kind == "run":
-        per_shard = run_count(arr)
-    else:
-        per_shard = popcount(arr)
-    return int(np.asarray(per_shard).sum())
+        return sparse_count(arr)
+    if kind == "run":
+        return run_count(arr)
+    return popcount(arr)
 
 
 # ---------------------------------------------------------------------------

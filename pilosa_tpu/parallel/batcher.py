@@ -51,7 +51,7 @@ import numpy as np
 
 from pilosa_tpu import qos
 from pilosa_tpu.ops.bitvector import popcount
-from pilosa_tpu.utils import accounting
+from pilosa_tpu.utils import accounting, tracing
 from pilosa_tpu.utils import profile as qprofile
 from pilosa_tpu.utils.telemetry import counted_jit
 
@@ -167,7 +167,16 @@ class ContinuousBatcher:
 
     def submit(self, key: tuple, payload):
         """Enqueue one query under compatibility `key`; blocks until a
-        batch containing it executes; returns its result."""
+        batch containing it executes; returns its result. The wait is
+        the `<family>.wait` span (`batcher.wait` for the device read
+        batchers): submit to delivery, the interval _run books as
+        wait_ms_total; a leader's own launch and fetch nest under it."""
+        if self.KERNEL_FAMILY is None:
+            return self._submit(key, payload)
+        with tracing.span(self.KERNEL_FAMILY + ".wait"):
+            return self._submit(key, payload)
+
+    def _submit(self, key: tuple, payload):
         req = _Req(payload)
         with self._lock:
             self._pending[key].append(req)
@@ -523,6 +532,10 @@ class CountBatcher(ContinuousBatcher):
         return self.submit((op, tuple(a.shape), str(a.dtype)), (a, b))
 
     def _dispatch(self, key: tuple, payloads: list):
+        with tracing.span("dispatch", batch=len(payloads)):
+            return self._launch(key, payloads)
+
+    def _launch(self, key: tuple, payloads: list):
         op = key[0]
         slots: dict[int, int] = {}
         leaves: list = []
@@ -555,7 +568,8 @@ class CountBatcher(ContinuousBatcher):
         return _batched_counts(tuple(leaves), ii, jj, op)
 
     def _finalize(self, key: tuple, handle, payloads: list) -> list:
-        parts = np.asarray(handle)  # blocks: the batch's one round trip
+        with tracing.span("device.wait"):
+            parts = np.asarray(handle)  # blocks: the batch's one round trip
         counts = parts.astype(np.int64).sum(axis=-1)  # exact int64 finish
         return [int(c) for c in counts[:len(payloads)]]
 
@@ -622,12 +636,15 @@ class MinMaxBatcher(ContinuousBatcher):
 
     def _dispatch(self, key: tuple, payloads: list):
         planes, is_min = payloads[0][0], key[2]
-        masks, idx = _dedup_masks(payloads)
-        return _batched_min_max(planes, tuple(masks), is_min), idx
+        with tracing.span("dispatch", batch=len(payloads)):
+            masks, idx = _dedup_masks(payloads)
+            return _batched_min_max(planes, tuple(masks), is_min), idx
 
     def _finalize(self, key: tuple, handle, payloads: list) -> list:
         arrs, idx = handle
-        out = np.asarray(arrs).astype(np.int64)  # blocks: the round trip
+        with tracing.span("device.wait"):
+            out = np.asarray(arrs)  # blocks: the round trip
+        out = out.astype(np.int64)
         return [out[i] for i in idx]
 
 
@@ -644,12 +661,14 @@ class PlaneSumBatcher(ContinuousBatcher):
 
     def _dispatch(self, key: tuple, payloads: list):
         planes = payloads[0][0]
-        masks, idx = _dedup_masks(payloads)
-        return _batched_plane_sums(planes, tuple(masks)), idx
+        with tracing.span("dispatch", batch=len(payloads)):
+            masks, idx = _dedup_masks(payloads)
+            return _batched_plane_sums(planes, tuple(masks)), idx
 
     def _finalize(self, key: tuple, handle, payloads: list) -> list:
         arrs, idx = handle
-        out = np.asarray(arrs)  # blocks: the batch's one round trip
+        with tracing.span("device.wait"):
+            out = np.asarray(arrs)  # blocks: the batch's one round trip
         # finish the shard-chunk reduction in int64 (exact)
         totals = out.astype(np.int64).sum(axis=-1)  # [kp, depth+1]
         return [totals[i] for i in idx]

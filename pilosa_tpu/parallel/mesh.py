@@ -605,6 +605,11 @@ class DeviceRunner:
         return eval_row(tuple(leaves), program)
 
     def count_total_leaves(self, leaves: list, program) -> int:
+        return int(self.count_total_leaves_dev(leaves, program))
+
+    def count_total_leaves_dev(self, leaves: list, program) -> jax.Array:
+        """The program's total count as a device scalar, launched and not
+        fetched: the caller's fetch is what waits for the device."""
         # pad shards are all-zero so they contribute nothing to the count —
         # EXCEPT under "not", which complements pad shards to all-ones; the
         # executor always masks Not() through the existence row (itself a
@@ -620,14 +625,13 @@ class DeviceRunner:
             )
 
             if self.mesh is not None:
-                return int(program_count_mesh(self.mesh, tuple(leaves),
-                                              program))
-            return int(jnp.sum(program_count(tuple(leaves), program)))
+                return program_count_mesh(self.mesh, tuple(leaves), program)
+            return jnp.sum(program_count(tuple(leaves), program))
         if self.mesh is not None and self.ici_serving:
             # explicit shard_map + psum serving form: per-device partial
             # counts over the local shard slice, one ICI all-reduce
-            return int(eval_count_mesh(self.mesh, tuple(leaves), program))
-        return int(eval_count_total(tuple(leaves), program))
+            return eval_count_mesh(self.mesh, tuple(leaves), program)
+        return eval_count_total(tuple(leaves), program)
 
     # -- GroupBy cross-count dispatch (XLA / Pallas / mesh routing) --------
 

@@ -28,7 +28,7 @@ from typing import Callable, Optional
 import jax
 import numpy as np
 
-from pilosa_tpu.utils import accounting
+from pilosa_tpu.utils import accounting, tracing
 from pilosa_tpu.utils import profile as qprofile
 
 # a quarter of a v5e chip's 16 GB of HBM; the rest is headroom for query
@@ -85,9 +85,15 @@ class DeviceResidency:
             if prof is not None:
                 prof.record_residency(hit=True)
             return arr
-        host = make()
+        with tracing.span("leaf.build"):
+            host = make()
         uploaded = not isinstance(host, jax.Array)
-        arr = ((put or self.runner.put_leaf)(host) if uploaded else host)
+        if uploaded:
+            with tracing.span("leaf.upload", rep=key[0],
+                              bytes=host.nbytes):
+                arr = (put or self.runner.put_leaf)(host)
+        else:
+            arr = host
         if prof is not None:
             # host->device bytes count only real uploads: a mask already
             # composed on device (bsicmp results) costs no link transfer

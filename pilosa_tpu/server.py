@@ -153,14 +153,16 @@ class Server:
         self.client = InternalClient(tls_skip_verify=tls_skip_verify)
         from pilosa_tpu.utils.logger import Logger
         from pilosa_tpu.utils.stats import new_stats_client
-        from pilosa_tpu.utils.tracing import SpanExporter, Tracer
+        from pilosa_tpu.utils.tracing import TraceExporter, Tracer
         self.stats = new_stats_client(metric_service, metric_host)
         # [tracing] config (server/config.go:96-104): an endpoint enables
         # batched span export; sampler gates which traces ship. Accepts a
-        # full URL or the reference's bare agent "host:port" form.
+        # full URL or the reference's bare agent "host:port" form. The
+        # exporter is the one [metric] trace-export builds too.
         if tracing_endpoint and "://" not in tracing_endpoint:
             tracing_endpoint = f"http://{tracing_endpoint}/api/traces"
-        exporter = (SpanExporter(tracing_endpoint)
+        exporter = (TraceExporter(mode="http", endpoint=tracing_endpoint,
+                                  fmt="jaeger")
                     if tracing_endpoint else None)
         self.tracer = Tracer(exporter=exporter,
                              sampler_type=tracing_sampler_type,
@@ -217,7 +219,6 @@ class Server:
                                  translator=self.cluster_translate,
                                  cluster=self.cluster, client=self.client)
         self.executor.stats = self.stats
-        self.executor.tracer = self.tracer
         # distributed fan-out knobs (net/coalesce.py; docs/operations.md
         # "Fan-out and hedging"): persistent pool size, coalesce window /
         # envelope cap, hedged-read delay (0 disables hedging)
@@ -364,7 +365,6 @@ class Server:
                 "(expected off | file | http)")
         self.trace_exporter = None
         if trace_export != "off":
-            from pilosa_tpu.utils.tracing import TraceExporter
             spool = trace_export_path or os.path.join(
                 data_dir, "trace-spool.jsonl")
             self.trace_exporter = TraceExporter(
@@ -436,7 +436,7 @@ class Server:
         self.handler = Handler(self.api, cluster_message_fn=self.receive_message,
                                stats=self.stats, query_timeout=query_timeout,
                                telemetry=self.telemetry, qos_plane=self.qos,
-                               events=self.events)
+                               events=self.events, tracer=self.tracer)
         self.http = HTTPServer(self.handler, host=host, port=port,
                                tls_certificate=tls_certificate, tls_key=tls_key)
         self._bind_host = host

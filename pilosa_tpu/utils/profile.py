@@ -13,7 +13,8 @@ QueryProfile rides a contextvar (the utils/qctx.py pattern: fan-out pool
 submits run in copied contexts, so every thread serving this query sees
 the SAME profile object), and every layer appends its attribution record:
 
-  - per-call spans (executor.execute)
+  - per-call spans (executor.execute) and the request's stage tree
+    (`stages`: every utils/tracing.py span that finished under it)
   - per-shard-group fan-out: node, shard count, RPC wall time, transport
     (local / coalesced envelope / per-query proto / legacy fallback),
     hedge fired/won, per-shard failover retries (executor fan-out)
@@ -64,7 +65,8 @@ class QueryProfile:
     leader threads all record into the query's one profile concurrently."""
 
     __slots__ = ("trace_id", "node_id", "index", "pql", "start",
-                 "start_wall", "elapsed_ms", "calls", "fanout", "dispatches",
+                 "start_wall", "elapsed_ms", "calls", "stages", "fanout",
+                 "dispatches",
                  "residency_hits", "residency_misses", "h2d_bytes",
                  "remotes", "plans", "routes", "qos", "_lock", "_sealed",
                  "_cached_dict")
@@ -83,6 +85,7 @@ class QueryProfile:
         self.start_wall = time.time()  # wall-clock: export timestamps
         self.elapsed_ms: float = 0.0
         self.calls: list[dict] = []        # [{call, ms}]
+        self.stages: list[dict] = []       # the request's span tree
         self.fanout: list[dict] = []       # per-shard-group RPC records
         self.dispatches: list[dict] = []   # device/envelope dispatch shares
         self.residency_hits = 0
@@ -105,6 +108,25 @@ class QueryProfile:
             if self._sealed:
                 return
             self.calls.append({"call": name, "ms": round(ms, 3)})
+
+    def record_stage(self, span) -> None:
+        """One finished span (utils/tracing.py Span, its sink c): the
+        stages of this request as a tree. `startMs` counts from the
+        profile's start; `parent` is the `id` of the stage that opened
+        this one, absent from the list where that span outlives the
+        profile (the HTTP layer's)."""
+        rec = {"id": f"{span.span_id:016x}", "name": span.name,
+               "startMs": round((span.start - self.start) * 1e3, 3),
+               "ms": round(span.ms, 3),
+               "selfMs": round(span.self_ms, 3),
+               "parent": (f"{span.parent.span_id:016x}"
+                          if span.parent is not None else "")}
+        if span.tags:
+            rec["tags"] = {k: str(v) for k, v in span.tags.items()}
+        with self._lock:
+            if self._sealed:
+                return
+            self.stages.append(rec)
 
     def record_fanout(self, node_id: str, shards: int, ms: float,
                       transport: str, error: str = "",
@@ -217,6 +239,7 @@ class QueryProfile:
                 "startWall": self.start_wall,
                 "elapsedMs": self.elapsed_ms,
                 "calls": list(self.calls),
+                "stages": list(self.stages),
                 "fanout": list(self.fanout),
                 "dispatches": list(self.dispatches),
                 "residency": {"hits": self.residency_hits,

@@ -26,7 +26,7 @@ import time
 from typing import Callable, Optional
 
 from pilosa_tpu.analysis import lockwitness
-from pilosa_tpu.utils import threads
+from pilosa_tpu.utils import threads, tracing
 
 
 def enabled() -> bool:
@@ -557,6 +557,7 @@ def record_dispatch(family: str, *args) -> None:
     the jitted call here, so the kernel-stats entry counts the dispatch
     without a latency sample."""
     lockwitness.note_blocking("dispatch", family)
+    tracing.note_launch()
     if not enabled():
         return
     try:
@@ -599,6 +600,7 @@ def counted_jit(family: str, **jit_kwargs):
             # holding a witnessed lock stalls every sibling of that lock
             # behind the accelerator (no-op unless PILOSA_TPU_LOCKCHECK=1)
             lockwitness.note_blocking("dispatch", family)
+            tracing.note_launch()
             arity = -1
             h2d = 0
             if enabled():
@@ -698,9 +700,11 @@ def _dir_bytes(path: str) -> int:
 
 
 class DeviceProfiler:
-    """POST /debug/device-profile backing: wraps `jax.profiler.trace`
-    around a sleep of the requested duration, spooling the trace into a
-    byte-capped directory. Exactly one capture runs at a time (a second
+    """POST /debug/device-profile backing: a `jax.profiler` trace around
+    a sleep of the requested duration, host tracer on and Python tracer
+    off, spooling the trace into a byte-capped directory. The program's
+    spans are written into it as `pilosa.<name>` host events for its
+    duration. Exactly one capture runs at a time (a second
     request reports "busy" instead of queueing); serving is never
     blocked — the trace rides the requesting HTTP worker thread while
     query traffic proceeds, which is the point: the capture sees the
@@ -736,13 +740,29 @@ class DeviceProfiler:
             os.makedirs(out_dir, exist_ok=True)
             import jax
             t0 = time.perf_counter()
-            with jax.profiler.trace(out_dir):
+            # the program's own spans stand in for the Python tracer
+            # (python_tracer_level 1, the default, records every call of
+            # every request thread, slows them and takes seconds to
+            # serialize at stop): while the flag is up each span is a
+            # TraceAnnotation on the capture's clock (utils/tracing.py)
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            options.host_tracer_level = 2
+            jax.profiler.start_trace(out_dir, profiler_options=options)
+            tracing.set_capturing(True)
+            try:
                 time.sleep(seconds)
-            elapsed = time.perf_counter() - t0
+            finally:
+                tracing.set_capturing(False)
+                t_stop = time.perf_counter()
+                jax.profiler.stop_trace()
+            t_end = time.perf_counter()
             self.captures += 1
             doc = {"status": "ok", "dir": out_dir,
                    "spoolDir": self.spool_dir,
-                   "seconds": round(elapsed, 3),
+                   "seconds": round(t_end - t0, 3),
+                   # what stop_trace took to serialize the capture
+                   "stopSeconds": round(t_end - t_stop, 3),
                    "bytes": _dir_bytes(out_dir),
                    "captures": self.captures}
             self._enforce_cap()

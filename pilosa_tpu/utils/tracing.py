@@ -1,165 +1,255 @@
-"""Tracing: Tracer/Span interface with a global tracer and nop default.
+"""Tracing: one span primitive, one tree per served request, three sinks.
 
-Reference: tracing/tracing.go:9-59 (GlobalTracer, StartSpanFromContext, nop
-impls) + the opentracing/Jaeger adapter. Jaeger egress isn't available here;
-the concrete impl is an in-memory recording tracer usable for slow-query
-logging and tests, with HTTP header propagation hooks like
-InjectHTTPHeaders/extractTracing (tracing/tracing.go:22-26).
+Reference: tracing/tracing.go:9-59 (StartSpanFromContext, the per-node
+tracer, InjectHTTPHeaders/extractTracing) + the opentracing/Jaeger adapter.
+
+`span(name, **tags)` is the one primitive. A span knows its parent (the
+span open in the same context: fan-out pool threads run in copied
+contexts, so their spans nest under the submitter's), the request's trace
+id, wall time (`time.perf_counter`) and the thread's CPU time
+(`time.thread_time`) at both ends. On finish it reports to three sinks:
+
+  a. aggregates, always on: the process-global `spans` table (per name
+     n / wallMs / selfMs / cpuMs / log2 buckets), served by /debug/vars
+     `spans` and /metrics `pilosa_spanMs{span=...}`;
+     PILOSA_TPU_TELEMETRY=0 turns it off.
+  b. the device trace, only while DeviceProfiler.capture runs: the span
+     is also a `jax.profiler.TraceAnnotation("pilosa.<name>",
+     trace_id=...)`, so a capture's .xplane.pb holds the program's
+     stages on the capture's own clock, beside the device's operations.
+  c. the request's QueryProfile (`stages`, ?profile=true and
+     /debug/query-history) and the node's recording `Tracer` (a ring
+     read by tests and the slow-query tooling, and the exporter behind
+     [tracing] agent-host-port / [metric] trace-export).
+
+The tracer a span reports to is found through `current_tracer`, which the
+HTTP layer sets to its node's Tracer; outside a request there is none and
+only sink a sees the span.
 """
 
 from __future__ import annotations
 
+import collections
 import contextvars
+import os
 import random
 import threading
 import time
 from typing import Optional
 
+from pilosa_tpu.utils import profile as qprofile
 from pilosa_tpu.utils import threads
+from pilosa_tpu.utils.stats import _pow2_bucket
 
 TRACE_HEADER = "X-Pilosa-Trace-Id"
 
-# process-seeded PRNG for trace ids (see Tracer.start_span)
+# process-seeded PRNG for trace and span ids: uniqueness, not
+# cryptographic strength (uuid4 costs an os.urandom syscall per call,
+# visible in serving-path profiles)
 _trace_rng = random.Random()
 
 # trace id of the request being served, for cross-node propagation: the HTTP
-# handler sets it from the incoming header, the InternalClient injects it
-# into outgoing internal requests (InjectHTTPHeaders / extractTracing,
-# tracing/tracing.go:22-26, http/handler.go:226-234)
+# handler sets it from the incoming header (or mints one on a work route),
+# the InternalClient injects it into outgoing internal requests
+# (InjectHTTPHeaders / extractTracing, tracing/tracing.go:22-26,
+# http/handler.go:226-234)
 current_trace_id: contextvars.ContextVar[Optional[str]] = contextvars.ContextVar(
     "pilosa_trace_id", default=None)
 
+# the node's Tracer for the request being served (several servers share a
+# process in tests, each with a ring of its own); None outside a request
+current_tracer: contextvars.ContextVar[Optional["Tracer"]] = \
+    contextvars.ContextVar("pilosa_tracer", default=None)
+
+# the span open in this context: the parent of the next one
+current_span: contextvars.ContextVar[Optional["Span"]] = \
+    contextvars.ContextVar("pilosa_span", default=None)
+
+# sink b's switch: DeviceProfiler.capture holds it for a capture's
+# duration. Off a capture a span pays one read of it.
+_capturing = False
+
+
+def set_capturing(on: bool) -> None:
+    global _capturing
+    _capturing = bool(on)
+
+
+def capturing() -> bool:
+    return _capturing
+
 
 def new_trace_id() -> str:
-    """Mint a fresh trace id (same PRNG scheme as Tracer.start_span —
-    uniqueness, not cryptographic strength). Used by the API layer to give
-    an untraced query one id for the whole request, so the slow-query log,
-    /debug/query-history and exported spans all join on it."""
+    """Mint a fresh trace id, one for a whole request, so the slow-query
+    log, /debug/query-history and exported spans all join on it."""
     return f"{_trace_rng.getrandbits(64):016x}"
 
 
-class Span:
-    __slots__ = ("tracer", "name", "trace_id", "start", "end", "tags",
-                 "start_wall")
+def _covered(intervals: list, lo: float, hi: float) -> float:
+    """Length of [lo, hi] the (start, end) intervals cover, overlaps
+    counted once: children on pool threads run side by side."""
+    total = 0.0
+    edge = lo
+    for s, e in sorted(intervals):
+        s, e = max(s, edge), min(e, hi)
+        if e > s:
+            total += e - s
+            edge = e
+    return total
 
-    def __init__(self, tracer, name: str, trace_id: str):
+
+class Span:
+    __slots__ = ("tracer", "name", "trace_id", "span_id", "parent", "start",
+                 "end", "start_wall", "tags", "self_ms", "cpu_ms",
+                 "launches", "_cpu0", "_kids", "_token", "_annotation")
+
+    def __init__(self, tracer, name: str, trace_id: Optional[str] = None,
+                 tags: Optional[dict] = None):
+        parent = current_span.get()
         self.tracer = tracer
         self.name = name
-        self.trace_id = trace_id
-        self.start = time.monotonic()
-        self.start_wall = time.time()  # wall clock for export timestamps
+        self.parent = parent
+        self.trace_id = (trace_id or current_trace_id.get()
+                         or (parent.trace_id if parent is not None
+                             else new_trace_id()))
+        self.span_id = _trace_rng.getrandbits(64)
+        self.tags: dict = tags if tags is not None else {}
         self.end: Optional[float] = None
-        self.tags: dict = {}
+        self.self_ms = 0.0
+        self.cpu_ms = 0.0
+        self.launches = 0  # device programs enqueued under this span
+        self._kids: list = []  # (start, end) of finished child spans
+        self._token = None
+        self._annotation = None
+        self.start_wall = time.time()  # wall clock for export timestamps
+        self._cpu0 = time.thread_time()
+        self.start = time.perf_counter()
 
     def set_tag(self, key: str, value) -> None:
         self.tags[key] = value
 
-    def finish(self) -> None:
-        self.end = time.monotonic()
-        self.tracer._record(self)
-
-    def duration(self) -> float:
-        return (self.end if self.end is not None else time.monotonic()) - self.start
-
     def __enter__(self):
+        self._token = current_span.set(self)
+        if _capturing:
+            import jax
+            self._annotation = jax.profiler.TraceAnnotation(
+                "pilosa." + self.name, trace_id=self.trace_id)
+            self._annotation.__enter__()
         return self
 
     def __exit__(self, *exc):
         self.finish()
 
+    def finish(self) -> None:
+        """Close the span (once: a second call is a no-op) on the thread
+        that opened it, and report it to the sinks."""
+        if self.end is not None:
+            return
+        end = self.end = time.perf_counter()
+        self.cpu_ms = (time.thread_time() - self._cpu0) * 1e3
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
+        if self._token is not None:
+            current_span.reset(self._token)
+            self._token = None
+        wall_ms = (end - self.start) * 1e3
+        kids = self._kids
+        self.self_ms = wall_ms - (_covered(kids, self.start, end) * 1e3
+                                  if kids else 0.0)
+        if self.parent is not None:
+            self.parent._kids.append((self.start, end))
+        if self.launches:
+            self.tags["dispatches"] = self.launches
+        if _telemetry_on():
+            spans.add(self.name, wall_ms, self.self_ms, self.cpu_ms)
+        prof = qprofile.current_profile.get()
+        if prof is not None:
+            prof.record_stage(self)
+        if self.tracer is not None:
+            self.tracer._record(self)
 
-class SpanExporter:
-    """Batched JSON-over-HTTP span shipper — the export backend the
-    reference configures through its Jaeger agent settings
-    (tracing/opentracing/opentracing.go:21-39, server/config.go:96-104).
-    Jaeger-thrift egress isn't available here, so the wire format is a
-    Jaeger-JSON-shaped batch POSTed to `endpoint`:
+    def duration(self) -> float:
+        return (self.end if self.end is not None
+                else time.perf_counter()) - self.start
 
-        {"process": {"serviceName": "pilosa-tpu"},
-         "spans": [{"traceID", "operationName", "startTimeMicros",
-                    "durationMicros", "tags"}]}
+    @property
+    def ms(self) -> float:
+        return self.duration() * 1e3
 
-    Spans buffer in memory and flush on a background timer or when the
-    buffer reaches `batch_size`. Export failures drop the batch (tracing
-    must never block or break the serving path)."""
 
-    def __init__(self, endpoint: str, batch_size: int = 64,
-                 flush_interval: float = 2.0, service_name: str = "pilosa-tpu"):
-        self.endpoint = endpoint
-        self.batch_size = batch_size
-        self.flush_interval = flush_interval
-        self.service_name = service_name
-        self._buf: list[dict] = []
+def span(name: str, trace_id: Optional[str] = None, **tags) -> Span:
+    """A span under the one open in this context, reporting to this
+    request's tracer: `with tracing.span("plan"): ...`."""
+    return Span(current_tracer.get(), name, trace_id, tags)
+
+
+def note_launch() -> None:
+    """One device program enqueued (counted_jit / record_dispatch): the
+    open span's `dispatches` tag."""
+    sp = current_span.get()
+    if sp is not None:
+        sp.launches += 1
+
+
+def _telemetry_on() -> bool:
+    # telemetry.enabled()'s switch, read here because utils/telemetry.py
+    # imports this module
+    return os.environ.get("PILOSA_TPU_TELEMETRY", "1") != "0"
+
+
+class SpanStats:
+    """Sink a: per span name, how many finished and their summed wall,
+    self and thread-CPU milliseconds, with log2 buckets of the wall time
+    (the pattern of telemetry.kernels). A layer's metric is a delta of
+    two snapshots: self time partitions a request's wall, so the names'
+    selfMs sum to the roots' wallMs."""
+
+    def __init__(self):
         self._lock = threading.Lock()
-        self._timer: Optional[threading.Timer] = None
-        self._flush_pending = False  # at most one batch-full flusher thread
-        self._closed = False
-        self.exported = 0  # total spans successfully shipped
-        self._schedule()
+        self._by_name: dict[str, dict] = {}
 
-    def _schedule(self) -> None:
-        if self._closed or self.flush_interval <= 0:
-            return
-        self._timer = threads.ctx_timer(self.flush_interval, self._tick)
-        self._timer.start()
-
-    def _tick(self) -> None:
-        try:
-            self.flush()
-        finally:
-            self._schedule()
-
-    def export(self, span: "Span") -> None:
-        rec = {
-            "traceID": span.trace_id,
-            "operationName": span.name,
-            "startTimeMicros": int(span.start_wall * 1e6),
-            "durationMicros": int(span.duration() * 1e6),
-            "tags": {k: str(v) for k, v in span.tags.items()},
-        }
+    def add(self, name: str, wall_ms: float, self_ms: float,
+            cpu_ms: float) -> None:
+        b = _pow2_bucket(wall_ms)
         with self._lock:
-            self._buf.append(rec)
-            # hand the POST to one background thread: Span.finish runs on
-            # the serving path and must never block on a slow collector,
-            # and a slow collector must not fan out unbounded threads
-            spawn = (len(self._buf) >= self.batch_size
-                     and not self._flush_pending)
-            if spawn:
-                self._flush_pending = True
-        if spawn:
-            threads.spawn(self._bg_flush)
+            e = self._by_name.get(name)
+            if e is None:
+                e = self._by_name[name] = {
+                    "n": 0, "wallMs": 0.0, "selfMs": 0.0, "cpuMs": 0.0,
+                    "buckets": {}}
+            e["n"] += 1
+            e["wallMs"] += wall_ms
+            e["selfMs"] += self_ms
+            e["cpuMs"] += cpu_ms
+            e["buckets"][b] = e["buckets"].get(b, 0) + 1
 
-    def _bg_flush(self) -> None:
-        try:
-            self.flush()
-        finally:
-            with self._lock:
-                self._flush_pending = False
-
-    def flush(self) -> None:
+    def snapshot(self) -> dict:
+        """The /debug/vars `spans` block. `nowMs` is this process's
+        perf_counter, the clock a delta of two snapshots is taken over."""
         with self._lock:
-            batch, self._buf = self._buf, []
-        if not batch:
-            return
-        import json
-        import urllib.request
-        body = json.dumps({"process": {"serviceName": self.service_name},
-                           "spans": batch}).encode()
-        req = urllib.request.Request(
-            self.endpoint, data=body, method="POST",
-            headers={"Content-Type": "application/json"})
-        try:
-            with urllib.request.urlopen(req, timeout=2.0):
-                pass
-            self.exported += len(batch)
-        except Exception:
-            pass  # drop the batch: never let tracing break serving
+            by_name = {name: {**e, "buckets": dict(e["buckets"])}
+                       for name, e in sorted(self._by_name.items())}
+        return {"enabled": _telemetry_on(),
+                "nowMs": time.perf_counter() * 1e3, "byName": by_name}
 
-    def close(self) -> None:
-        self._closed = True
-        if self._timer is not None:
-            self._timer.cancel()
-        self.flush()
+    def metrics_view(self) -> dict:
+        """Timings in StatsClient key syntax: the pilosa_spanMs{span=...}
+        histogram family of /metrics."""
+        with self._lock:
+            return {f"spanMs,span:{name}": {
+                        "count": e["n"], "sum": e["wallMs"],
+                        "buckets": dict(e["buckets"])}
+                    for name, e in self._by_name.items()}
+
+    def reset(self) -> None:
+        with self._lock:
+            self._by_name.clear()
+
+
+# process-global, like telemetry.kernels: every span adds to it, and
+# /debug/vars, /metrics and the benchmark's readers read it
+spans = SpanStats()
 
 
 def trace_export_enabled() -> bool:
@@ -184,15 +274,16 @@ def profile_to_spans(profile: dict) -> list[dict]:
     ("" = root), operationName, startTimeMicros, durationMicros, tags.
 
     Structure: one root `pilosa.query` span per profile node; child spans
-    for executor calls, per-shard-group fan-out RPCs, and batched-dispatch
+    for the node's stage tree (or, from a peer without one, its executor
+    calls), per-shard-group fan-out RPCs, and batched-dispatch
     shares; remote profile fragments recurse under the fan-out span of
     their node (falling back to the root when the RPC record is absent —
     e.g. a hedge winner whose primary record sealed late)."""
     spans: list[dict] = []
 
     def emit(trace_id: str, name: str, start_us: int, dur_us: int,
-             parent: str, tags: dict) -> str:
-        sid = _new_span_id()
+             parent: str, tags: dict, sid: str = "") -> str:
+        sid = sid or _new_span_id()
         spans.append({
             "traceID": trace_id, "spanID": sid, "parentSpanID": parent,
             "operationName": name,
@@ -209,9 +300,24 @@ def profile_to_spans(profile: dict) -> list[dict]:
                     float(node.get("elapsedMs") or 0.0) * 1e3, parent,
                     {"node": node.get("node"), "index": node.get("index"),
                      "pql": node.get("pql")})
-        for c in node.get("calls", []):
-            emit(trace_id, f"call.{c.get('call', '?')}", start_us,
-                 float(c.get("ms") or 0.0) * 1e3, root, {})
+        stages = node.get("stages") or []
+        stage_ids = {st.get("id"): _new_span_id() for st in stages}
+        for st in stages:
+            # the request's stage tree (Span -> QueryProfile.record_stage):
+            # each at its own start, under the stage that opened it
+            emit(trace_id, st.get("name", "?"),
+                 start_us + float(st.get("startMs") or 0.0) * 1e3,
+                 float(st.get("ms") or 0.0) * 1e3,
+                 stage_ids.get(st.get("parent"), root),
+                 {"selfMs": st.get("selfMs"), **(st.get("tags") or {})},
+                 sid=stage_ids[st.get("id")])
+        if not stages:
+            # a peer that records no stages: its calls, stamped with the
+            # root's start (their own is not known); with stages the
+            # `executor.<Call>` stage is the call
+            for c in node.get("calls", []):
+                emit(trace_id, f"call.{c.get('call', '?')}", start_us,
+                     float(c.get("ms") or 0.0) * 1e3, root, {})
         fanout_span_by_node: dict[str, str] = {}
         for fo in node.get("fanout", []):
             kind = fo.get("kind")
@@ -303,10 +409,11 @@ class TraceExporter:
     file (one JSON batch per line — ship with any log forwarder) or an
     HTTP collector endpoint ([metric] trace-export = off|file|http).
 
+    The one exporter: [tracing] agent-host-port builds it in http mode
+    with the Jaeger format, [metric] trace-export in the mode it names.
     Feeds from two sources: the recording tracer's finished spans (wired
-    as Tracer.exporter — flat spans) and finished cross-node profile
-    trees (export_profile — parent/child-linked spans via
-    profile_to_spans). Sampling is deterministic per trace id (crc32,
+    as Tracer.exporter) and finished cross-node profile trees
+    (export_profile), both parent/child-linked. Sampling is deterministic per trace id (crc32,
     the Tracer._sampled scheme) so every node of one trace agrees; the
     `PILOSA_TPU_TRACE_EXPORT=0` kill switch and any I/O failure drop
     batches — export must never block or break serving."""
@@ -358,13 +465,15 @@ class TraceExporter:
     # -- ingestion ----------------------------------------------------------
 
     def export(self, span: "Span") -> None:
-        """Recording-tracer hook (the SpanExporter interface): one flat
-        finished span. Tracer._sampled already gated it."""
+        """Recording-tracer hook: one finished span, linked to its
+        parent. Tracer._sampled already gated it."""
         if not trace_export_enabled():
             return
+        parent = span.parent
         self._push([{
-            "traceID": span.trace_id, "spanID": _new_span_id(),
-            "parentSpanID": "",
+            "traceID": span.trace_id, "spanID": f"{span.span_id:016x}",
+            "parentSpanID": (f"{parent.span_id:016x}"
+                             if parent is not None else ""),
             "operationName": span.name,
             "startTimeMicros": int(span.start_wall * 1e6),
             "durationMicros": int(span.duration() * 1e6),
@@ -457,7 +566,7 @@ class TraceExporter:
 
 
 class Tracer:
-    """Recording tracer; keeps the last `limit` finished spans.
+    """A node's recording tracer; keeps the last `limit` finished spans.
 
     `sampler_type`/`sampler_param` mirror the reference's Jaeger sampler
     config (server/config.go:96-104): "const" with param>=1 samples
@@ -465,23 +574,19 @@ class Tracer:
     samples nothing (recording still happens for slow-query logging; the
     sampler only gates *export*)."""
 
-    def __init__(self, limit: int = 1000, exporter: Optional[SpanExporter] = None,
+    def __init__(self, limit: int = 1000,
+                 exporter: Optional[TraceExporter] = None,
                  sampler_type: str = "const", sampler_param: float = 1.0):
-        self.limit = limit
         self._lock = threading.Lock()
-        self.spans: list[Span] = []
+        self.spans: "collections.deque[Span]" = collections.deque(
+            maxlen=limit)
         self.exporter = exporter
         self.sampler_type = sampler_type
         self.sampler_param = sampler_param
 
     def start_span(self, name: str, trace_id: Optional[str] = None) -> Span:
-        # random.getrandbits, not uuid4: a fresh trace id is minted on
-        # EVERY traced query without an inherited id, and uuid4 costs an
-        # os.urandom syscall per call (visible in serving-path profiles);
-        # trace ids need uniqueness, not cryptographic strength
-        return Span(self, name,
-                    trace_id or current_trace_id.get()
-                    or f"{_trace_rng.getrandbits(64):016x}")
+        """A span that reports to THIS tracer, whatever the context's is."""
+        return Span(self, name, trace_id)
 
     def _sampled(self, span: Span) -> bool:
         if self.exporter is None or self.sampler_type == "off":
@@ -498,8 +603,6 @@ class Tracer:
     def _record(self, span: Span) -> None:
         with self._lock:
             self.spans.append(span)
-            if len(self.spans) > self.limit:
-                self.spans = self.spans[-self.limit:]
         if self._sampled(span):
             self.exporter.export(span)
 
@@ -513,37 +616,3 @@ class Tracer:
 
     def extract_trace_id(self, headers) -> Optional[str]:
         return headers.get(TRACE_HEADER)
-
-
-class NopSpan:
-    def set_tag(self, key, value): pass
-    def finish(self): pass
-    def duration(self): return 0.0
-    def __enter__(self): return self
-    def __exit__(self, *exc): pass
-
-
-class NopTracer:
-    """tracing/tracing.go:38 nop default."""
-
-    def start_span(self, name, trace_id=None):
-        return NopSpan()
-
-    def finished(self, name=None):
-        return []
-
-    def inject_headers(self, span, headers): pass
-    def extract_trace_id(self, headers): return None
-
-
-# global tracer (tracing.GlobalTracer)
-global_tracer = NopTracer()
-
-
-def set_global_tracer(t) -> None:
-    global global_tracer
-    global_tracer = t
-
-
-def start_span(name: str, trace_id=None):
-    return global_tracer.start_span(name, trace_id)

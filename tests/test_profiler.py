@@ -139,12 +139,19 @@ def test_query_batch_installs_per_entry_trace(tmp_path):
         jpost(s.uri, "/index/i", {})
         jpost(s.uri, "/index/i/field/f", {})
         jpost(s.uri, "/index/i/query", raw=b"Set(5, f=1)")
-        out = s.api.query_batch([
-            {"index": "i", "query": "Count(Row(f=1))", "remote": True,
-             "traceId": "envelope-trace-1"},
-            {"index": "i", "query": "Count(Row(f=1))", "remote": True,
-             "traceId": "envelope-trace-2"},
-        ])
+        # the API is entered below the HTTP layer, which is what installs
+        # the node's tracer for a request
+        from pilosa_tpu.utils import tracing
+        tok = tracing.current_tracer.set(s.tracer)
+        try:
+            out = s.api.query_batch([
+                {"index": "i", "query": "Count(Row(f=1))", "remote": True,
+                 "traceId": "envelope-trace-1"},
+                {"index": "i", "query": "Count(Row(f=1))", "remote": True,
+                 "traceId": "envelope-trace-2"},
+            ])
+        finally:
+            tracing.current_tracer.reset(tok)
         assert [r for r, *_ in out] == [[1], [1]]
         got = {sp.trace_id for sp in s.tracer.finished("executor.Count")}
         # BOTH entries' spans carry their own caller's trace id — the
