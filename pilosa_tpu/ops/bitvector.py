@@ -265,17 +265,22 @@ def xor_range(x: jax.Array, mask: jax.Array) -> jax.Array:
 # ---------------------------------------------------------------------------
 # Hybrid sparse containers: padded sorted-index rows for low-cardinality
 # operands (the roaring array-container idea ported to XLA; arXiv:1402.6407
-# container taxonomy, arXiv:1401.6399 galloping intersection of sorted
-# integer sets). A sparse row leaf is int32[..., K]: sorted shard-local
-# column ids, padded with SPARSE_SENTINEL — K slots of 4 bytes instead of
-# a 128 KiB dense plane, so resident capacity scales with CARDINALITY, not
-# shard width. Kernels broadcast over leading axes like the dense algebra
-# (one row [S, K], or anything stacked above it); every kernel returns
-# sorted sentinel-padded output, so compositions chain freely. The planner
-# chooses representation per operand (pilosa_tpu/planner.py
-# choose_representation) and eval_hybrid() below evaluates a mixed
-# sparse/dense program tree, materializing to dense only where an op
-# demands a plane (Not, wide unions, GroupBy slabs, BSI).
+# container taxonomy). A sparse row leaf is int32[..., K]: sorted
+# shard-local column ids, padded with SPARSE_SENTINEL — K slots of 4 bytes
+# instead of a 128 KiB dense plane, so resident capacity scales with
+# CARDINALITY, not shard width. Kernels broadcast over leading axes like
+# the dense algebra (one row [S, K], or anything stacked above it); every
+# kernel returns sorted sentinel-padded output, so compositions chain
+# freely. Every sparse×sparse kernel is a MERGE: one sort of the
+# concatenated operands and a compare with the neighbour — no gather, no
+# scatter, no loop. On the chip a sort of [64, 8192] costs a fraction of
+# a millisecond while a gather costs ~10 ns an element, so a binary-search
+# probe (log2(K)+1 dependent gathers, what sparse ∩ / − ran until PR 27)
+# cost seventy times a merge (PERF.md §6). The planner chooses
+# representation per operand (pilosa_tpu/planner.py choose_representation)
+# and eval_hybrid() below evaluates a mixed sparse/dense program tree,
+# materializing to dense only where an op demands a plane (Not, wide
+# unions, GroupBy slabs, BSI).
 # ---------------------------------------------------------------------------
 
 # one past the last legal column offset; sorts after every real entry.
@@ -291,10 +296,12 @@ SPARSE_UNION_CAP = 1 << 14
 
 def _member_in_sorted(vals: jax.Array, ref: jax.Array) -> jax.Array:
     """Membership of vals[..., Kv] in sorted ref[..., Kr], elementwise
-    bool. One binary probe per value of the SMALLER operand into the
-    larger — the galloping/skewed-intersection regime of 1401.6399 (cost
-    Kv·log Kr, sub-linear in the large side). Sentinel padding never
-    matches (pads in ref are excluded by the value test on vals)."""
+    bool, by one binary probe per value — the galloping/skewed regime of
+    arXiv:1401.6399 (cost Kv·log Kr). Serves patch_sparse_rows only (a
+    handful of removes against a whole row); sparse ∩ / − merge instead
+    (_in_both), because each probe step is a dependent gather. Sentinel
+    padding never matches (pads in ref are excluded by the value test on
+    vals)."""
     kv, kr = vals.shape[-1], ref.shape[-1]
     v2 = vals.reshape(-1, kv)
     r2 = ref.reshape(-1, kr)
@@ -317,21 +324,37 @@ def sparse_count(sp: jax.Array) -> jax.Array:
     return jnp.sum((sp < SPARSE_SENTINEL).astype(jnp.int32), axis=-1)
 
 
+def _in_both(a: jax.Array, b: jax.Array):
+    """(vals[..., Ka+Kb], mine, paired): the merge that answers "which of
+    a's entries does b hold". Keys a<<1 and (b<<1)|1 (values and the
+    sentinel stay below 2^21) sort a's copy of a shared value directly
+    before b's; inputs are sorted-unique per row, so an entry of a is in
+    b iff the next key is its key|1. `mine` marks a's live entries: pad
+    slots of a and b pair up like any shared value, so they are masked
+    here, once."""
+    keys = jnp.sort(jnp.concatenate([a << 1, (b << 1) | 1], axis=-1),
+                    axis=-1)
+    edge = jnp.full(keys.shape[:-1] + (1,), -1, dtype=keys.dtype)
+    nxt = jnp.concatenate([keys[..., 1:], edge], axis=-1)
+    vals = keys >> 1
+    mine = ((keys & 1) == 0) & (vals < SPARSE_SENTINEL)
+    return vals, mine, nxt == (keys | 1)
+
+
 @counted_jit("sparse")
 def sparse_intersect(a: jax.Array, b: jax.Array) -> jax.Array:
-    """sparse ∩ sparse -> sparse[..., min(Ka, Kb)]. Probes the smaller
-    operand's values into the larger (orientation is static — padded
-    widths are trace-time constants), the skewed-cardinality fast path."""
-    if a.shape[-1] > b.shape[-1]:
-        a, b = b, a
-    return _resort(a, _member_in_sorted(a, b))
+    """sparse ∩ sparse -> sparse[..., min(Ka, Kb)]: a's entries that the
+    merge pairs with one of b's."""
+    vals, mine, paired = _in_both(a, b)
+    return _resort(vals, mine & paired)[..., :min(a.shape[-1], b.shape[-1])]
 
 
 @counted_jit("sparse")
 def sparse_difference(a: jax.Array, b: jax.Array) -> jax.Array:
-    """sparse &~ sparse -> sparse[..., Ka]: a's entries absent from b."""
-    keep = ~_member_in_sorted(a, b) & (a < SPARSE_SENTINEL)
-    return _resort(a, keep)
+    """sparse &~ sparse -> sparse[..., Ka]: a's entries the merge leaves
+    unpaired."""
+    vals, mine, paired = _in_both(a, b)
+    return _resort(vals, mine & ~paired)[..., :a.shape[-1]]
 
 
 def _dense_bit_test(sp: jax.Array, dense: jax.Array) -> jax.Array:
@@ -665,7 +688,8 @@ def eval_hybrid(program, leaves: list, kinds: list,
     """Evaluate a nested-tuple bitmap program over MIXED dense/sparse/run
     leaves -> (kind, device array). The representation flows bottom-up:
     intersections keep the cheapest faithful representation (sparse∩* is
-    sparse via galloping probes, run∩run stays run via interval merge,
+    sparse: a merge against a sparse side, gather-and-test against a dense
+    one; run∩run stays run via interval merge,
     run∩dense materializes the fused run mask), differences keep the left
     operand's kind where a dedicated kernel exists, unions of two small
     sparse rows stay sparse until SPARSE_UNION_CAP, and Not — whose

@@ -32,7 +32,7 @@ row_cardinality; per-row write generations, fragment.py row_generation):
     cardinalities drive the hybrid sparse/dense container decision
     (choose_representation below): rows at or below [query]
     sparse-threshold bits per shard upload as padded sorted-index arrays
-    with galloping/gather-test kernels (ops/bitvector.py), dense rows
+    with merge/gather-test kernels (ops/bitvector.py), dense rows
     keep full planes — recorded on the plan node like the ICI route.
   * **Key the cross-query plan cache.** subtree_cache_key() canonicalizes
     a planned subtree to (index, PQL text, shard set, per-leaf fragment

@@ -4,7 +4,7 @@ Three layers under test:
 
 * the sparse kernel family (ops/bitvector.py): padded sorted-index
   algebra vs a numpy set-algebra oracle, including sentinel padding,
-  empty rows and the galloping orientation;
+  empty rows, both operand orders and every padded width pair;
 * the HybridManager (parallel/residency.py): threshold choice,
   promote/demote hysteresis, heat-informed demotion, kill switches;
 * the executor integration: sparse leaves in the residency manager with
@@ -91,6 +91,74 @@ def test_sparse_kernels_empty_rows():
     assert _as_set(bv.sparse_difference(full, empty)) == {1, 5, 9}
     assert int(np.asarray(bv.sparse_count(empty))[0]) == 0
     assert np.asarray(bv.sparse_to_dense(empty, W)).sum() == 0
+
+
+_MERGE_WIDTHS = (128, 4096, 8192, 12288)
+
+
+def _merge_case_rows(ka, kb, rng):
+    """One (set_a, set_b) per shard row: the shapes the merge has to get
+    right, at every width pair."""
+    small = min(ka, kb)
+    ragged_a = set(rng.choice(SHARD_WIDTH, ka // 3, replace=False).tolist())
+    ragged_b = set(rng.choice(SHARD_WIDTH, kb // 2, replace=False).tolist())
+    # share a third of a's values so the intersection is not empty by luck
+    ragged_b |= set(sorted(ragged_a)[::3][:kb - len(ragged_b)])
+    same = set(rng.choice(SHARD_WIDTH, small, replace=False).tolist())
+    evens = set(range(0, 2 * ka, 2))
+    odds = set(range(1, 2 * kb, 2))
+    last = SHARD_WIDTH - 1
+    return [
+        (ragged_a, ragged_b),                   # ragged, overlapping
+        (set(), ragged_b),                      # a all-pad
+        (ragged_a, set()),                      # b all-pad
+        (set(), set()),                         # pad meets pad
+        ({0, 7, last}, {7, last}),              # the last legal column
+        ({last}, set(range(kb))),               # ... against a full b
+        (same, same),                           # identical operands
+        (evens, odds),                          # full rows, disjoint
+        (set(range(ka)), set(range(kb))),       # full rows, one inside
+    ]
+
+
+@pytest.mark.parametrize("kb", _MERGE_WIDTHS)
+@pytest.mark.parametrize("ka", _MERGE_WIDTHS)
+def test_sparse_merge_kernels_match_set_oracle(ka, kb):
+    """sparse ∩ / − over every padded width pair, both orders, with a
+    leading shard axis: set equality with Python sets, the
+    sorted-sentinel contract and the output widths callers rely on."""
+    rows = _merge_case_rows(ka, kb, np.random.default_rng(ka * 31 + kb))
+    a = jnp.asarray(np.stack([bv.sparse_from_columns(
+        np.asarray(sorted(sa), dtype=np.int64), ka) for sa, _ in rows]))
+    b = jnp.asarray(np.stack([bv.sparse_from_columns(
+        np.asarray(sorted(sb), dtype=np.int64), kb) for _, sb in rows]))
+    for got, want_of, width in (
+            (bv.sparse_intersect(a, b), lambda x, y: x & y, min(ka, kb)),
+            (bv.sparse_difference(a, b), lambda x, y: x - y, ka)):
+        got = np.asarray(got)
+        assert got.shape == (len(rows), width) and got.dtype == np.int32
+        assert (np.diff(got, axis=-1) >= 0).all()
+        assert got.min() >= 0 and got.max() <= SENT
+        for row, (sa, sb) in zip(got, rows):
+            live = row[row < SENT]
+            assert live.size == np.unique(live).size
+            assert set(live.tolist()) == want_of(sa, sb)
+
+
+@pytest.mark.parametrize("kernel", ["sparse_intersect", "sparse_difference"])
+def test_sparse_merge_kernels_lower_without_gather_or_loop(kernel):
+    """The two kernels are sorts and elementwise compares. A binary-search
+    probe would bring a `while` of dependent `gather`s back: on the chip
+    that cost 37-40 ms a call against 0.55 for the merge (PERF.md §5)."""
+    import re
+
+    import jax
+
+    spec = jax.ShapeDtypeStruct((64, 4096), jnp.int32)
+    lowered = getattr(bv, kernel).lower(spec, spec)
+    ops = set(re.findall(r"stablehlo\.(\w+)", lowered.as_text()))
+    assert "sort" in ops
+    assert not ops & {"gather", "scatter", "while"}, sorted(ops)
 
 
 def test_eval_hybrid_mixed_tree():
