@@ -1,23 +1,25 @@
-"""One general data generator. A configuration's `fields` (its config.json)
-say what each field holds; this module makes it from the seed with numpy,
-in bulk, and hands every row out in the two forms the rest of the
-benchmark needs: per-shard pieces for the roaring wire writer, and packed
-uint64 words over all shards for the plain reference.
+"""The data of a configuration, from the seed. A configuration's `fields`
+(its config.json) say what each field holds; each field's `kind` names the
+law it is drawn by, a file of its own under lib/data_kinds/ (absent means
+`zipf`, what upstream's `pi bench zipf` leaves behind), and this module
+hands every row out in the two forms the rest of the benchmark needs:
+per-shard pieces for the roaring wire writer, and packed uint64 words over
+all shards for the plain reference.
 
-A field is what upstream's own benchmark tool leaves behind
-(`pi bench zipf` of github.com/pilosa/tools, written down from memory in
-configs/segmentation/config.json): `set_bits_per_shard` x shards times it
-sets one bit, at a row and a column each drawn from a Zipf-Mandelbrot law
-P(k) ~ (v + k) ** -exponent over ranks k = 0 .. n-1. The offset v is not
-given but the `ratio` of the least likely rank's probability to the most
-likely one's: v = z (n - 1) / (1 - z) with z = ratio ** (1 / exponent),
-as the tool's getZipfOffset has it. Ranks are scattered over the ids by a
-permutation (the tool's PermutationGenerator): rows by a shuffle from the
-seed, columns by the bijection rank -> (rank * A + b) mod n_columns. A bit
-set twice is one bit.
+A data kind is one function,
 
-Every row draws from a generator of its own, keyed by (seed, field, row),
-so the bytes do not depend on how the work is spread over threads.
+    make_field(seed, fi, spec, n_shards, pool) -> {row_id: Row}
+
+`fi` the field's place in the configuration, `spec` its entry of `fields`,
+`pool` a thread pool to spread the numpy work over. It draws from
+generators keyed by the seed, the field and a row or a shard, never by
+what a thread happened to do first, so the bytes do not depend on how the
+work is spread. `zipf_offset` and `rank_weights` below are the
+Zipf-Mandelbrot law that more than one kind draws from: P(k) ~ (v + k) **
+-exponent over ranks k = 0 .. n-1, the offset v not given but the `ratio`
+of the least likely rank's probability to the most likely one's: v = z (n
+- 1) / (1 - z) with z = ratio ** (1 / exponent), as the tool's
+getZipfOffset has it.
 """
 
 from __future__ import annotations
@@ -27,9 +29,10 @@ import os
 
 import numpy as np
 
+from . import byfile
+
 SHARD_WIDTH = 1 << 20
 WORDS_PER_SHARD = SHARD_WIDTH // 64
-_PRIME = 2654435761   # prime, so coprime to any column count below it
 
 
 def pack_columns(cols: np.ndarray, n_shards: int) -> np.ndarray:
@@ -98,51 +101,16 @@ def rank_weights(n: int, exponent: float, ratio: float) -> np.ndarray:
     return w / w.sum()
 
 
-def draw_ranks(rng, size: int, n: int, exponent: float,
-               ratio: float) -> np.ndarray:
-    """`size` ranks in [0, n) from the same law, by inverting the
-    continuous distribution function (n is tens of millions here, and the
-    offset a third of it, so a rank's probability differs from the discrete
-    law's by parts in 10**8)."""
-    v = zipf_offset(n, exponent, ratio)
-    a = 1.0 - exponent
-    lo, hi = v ** a, (v + n) ** a
-    x = (lo - rng.random(size) * (lo - hi)) ** (1.0 / a) - v
-    return np.minimum(x.astype(np.int64), n - 1)
-
-
-def _row(seed, fi, rank, n_draws, n_shards, spec, shift) -> Row:
-    rng = np.random.default_rng([seed, 0xDA7A, fi, rank])
-    n_cols = n_shards * SHARD_WIDTH
-    ranks = draw_ranks(rng, n_draws, n_cols, spec["column_exponent"],
-                       spec["column_ratio"]).astype(np.uint64)
-    cols = (ranks * np.uint64(_PRIME) + np.uint64(shift)) % np.uint64(n_cols)
-    return Row(n_shards, np.unique(cols.astype(np.uint32)))
-
-
 def make(config: dict, seed: int, shards: int | None = None) -> Data:
     """The configuration's data from the seed. `shards` overrides the
     configuration's scale (the CPU rehearsal and the tests use 2)."""
     n_shards = int(shards or config["shards"])
     data = Data(n_shards)
-    jobs = []
     with concurrent.futures.ThreadPoolExecutor(
             max_workers=min(8, os.cpu_count() or 1)) as pool:
         for fi, spec in enumerate(config["fields"]):
-            name, n_rows = spec["name"], spec["rows"]
-            data.fields[name] = {}
-            data.options[name] = spec.get("options", {})
-            rng = np.random.default_rng([seed, 0xDA7A, fi])
-            per_row = rng.multinomial(
-                spec["set_bits_per_shard"] * n_shards,
-                rank_weights(n_rows, spec["row_exponent"],
-                             spec["row_ratio"]))
-            ids = rng.permutation(n_rows) + spec.get("first_id", 0)
-            shift = int(rng.integers(0, n_shards * SHARD_WIDTH))
-            for rank in range(n_rows):
-                jobs.append((name, int(ids[rank]), pool.submit(
-                    _row, seed, fi, rank, int(per_row[rank]), n_shards,
-                    spec, shift)))
-        for name, row_id, fut in jobs:
-            data.fields[name][row_id] = fut.result()
+            kind = byfile.load("lib/data_kinds", spec.get("kind"))
+            data.options[spec["name"]] = spec.get("options", {})
+            data.fields[spec["name"]] = kind.make_field(
+                seed, fi, spec, n_shards, pool)
     return data

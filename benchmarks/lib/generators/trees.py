@@ -1,7 +1,8 @@
-"""One general traffic generator. A mix is a data file under
-benchmarks/traffic/ (JSON); this module turns it, the configuration's data
-and the seed into a stream of requests. A later PR adds a mix by adding a
-file, not code.
+"""The request generator `trees` (the default): Count over random set-
+operator trees, as upstream's `pi bench random-query` makes them. A mix is
+a data file under benchmarks/traffic/ (JSON); this module turns it, the
+configuration's data and the seed into a stream of requests. A later PR
+adds a mix by adding a file, not code.
 
 A mix is a fixed population of query templates, as a TPC flight is: the
 templates are made once from the mix's own `template_seed`, and a run's
@@ -33,22 +34,18 @@ Keys of a mix:
              this mix for a while when it is measured
   check_sample, check_min  how many answers the reference recomputes, and
              the fewest that make a run's comparison count
-Every request is Count(<tree>).
+Every request is Count(<tree>); its `label`, what the result line's
+`extra.by_leaves` groups latencies by, is the class of its template's
+leaf count (1-1, 2-3, 4-7, 8-15, 16-31).
 """
 
 from __future__ import annotations
 
-import json
 import threading
 
 import numpy as np
 
-from . import query
-
-
-def load_mix(path: str) -> dict:
-    with open(path) as fh:
-        return json.load(fh)
+from lib import query
 
 
 def make_template(rng, tree: dict, depth: int):
@@ -68,12 +65,19 @@ def n_leaves(template) -> int:
     return sum(n_leaves(c) for c in template[1])
 
 
+def label(template) -> str:
+    k = min(n_leaves(template), 16).bit_length()
+    return f"{1 << (k - 1)}-{(1 << k) - 1}"
+
+
 def size_class(row) -> int:
     """The power of two over the row's fullest shard."""
     return max(int(row.bits_per_shard().max()) - 1, 0).bit_length()
 
 
 class Traffic:
+    label_key = "by_leaves"
+
     def __init__(self, mix: dict, data, seed: int):
         self.mix = mix
         self.seed = seed
@@ -103,7 +107,7 @@ class Traffic:
             for i in rng.permutation(len(self.templates)):
                 ast = ("count", self._fill(rng, self.templates[i]))
                 yield {"pql": query.to_pql(ast), "ast": ast,
-                       "leaves": n_leaves(self.templates[i])}
+                       "label": label(self.templates[i])}
 
     def warmup(self) -> list:
         stream = self._stream(np.random.default_rng([self.seed, 0x7AFF, 2]))

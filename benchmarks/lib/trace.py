@@ -17,24 +17,42 @@ the chip.
               shapes of one function count as one) where the trace has
               that line, else the operations
   idle_gaps   the ten longest gaps between operations on the first device,
-              each named "unattributed": the program writes no host spans
-              into the trace yet
+              each named by what the host was doing: the program's own
+              `pilosa.<span>` event (utils/tracing.py writes every span
+              into the trace, on its clock) on the thread that launched
+              the program the gap ends at, and of the spans nested there
+              the one in whose own time most of the gap lies;
+              "unattributed" only where that thread is not known or no
+              span of it reaches into the gap
   op_sum_s    summed (not united) operation time, mean over devices
 
 A device plane is one whose name starts with "/device:" and is not a
 "/device:CUSTOM" or host plane; its operation events are those of the line
-named "XLA Ops" (every line, where no line has that name).
+named "XLA Ops" (every line, where no line has that name). The host's
+threads are the lines of the plane "/host:CPU". The thread that launched
+the program a gap ends at is found by the profiler's own link where the
+trace has it: the program's event on the "XLA Modules" line and the
+runtime's "...Execute..." events on the launching thread carry the same
+`run_id`. Where it has not, a launch is a host event "PjitFunction(<fn>)",
+which the runtime writes on the thread that calls a jitted function, and
+the launch looked for is the last one that began before the gap's end, of
+that program's own function ("jit_<fn>") where the device plane names it.
 """
 
 from __future__ import annotations
 
+import bisect
 import json
 import re
 import sys
 
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
+HOST_PLANE = "/host:CPU"
+SPAN_PREFIX = "pilosa."
+UNATTRIBUTED = "unattributed"
 _FINGERPRINT = re.compile(r"\(\d+\)$")
+_LAUNCH = re.compile(r"^PjitFunction\((.*)\)$")
 
 
 def _union(intervals: list) -> tuple:
@@ -56,6 +74,67 @@ def _union(intervals: list) -> tuple:
     return total, gaps
 
 
+def _launch_thread(launches: list, gap_end: float, fn: str | None):
+    """The thread of the last launch that began before `gap_end`: of the
+    function `fn` where one is, else of any."""
+    best = {}
+    for start, thread, name in launches:
+        if start > gap_end:
+            break
+        best[None] = thread
+        best[name] = thread
+    return best.get(fn, best.get(None))
+
+
+def _span_of_gap(spans: list, lo: float, hi: float) -> str:
+    """Of one thread's (start, end, name) spans, properly nested, the name
+    in whose own time (its time less its children's) most of [lo, hi]
+    lies."""
+    inside = [(max(s, lo), min(e, hi), e - s, name)
+              for s, e, name in spans if s < hi and e > lo]
+    if not inside:
+        return UNATTRIBUTED
+    cuts = sorted({lo, hi} | {t for s, e, _, _ in inside for t in (s, e)})
+    own: dict = {}
+    for a, b in zip(cuts, cuts[1:]):
+        over = [(length, name) for s, e, length, name in inside
+                if s <= a and e >= b]
+        if over:   # the shortest span over a stretch is the innermost
+            name = min(over)[1]
+            own[name] = own.get(name, 0.0) + (b - a)
+    return max(own.items(), key=lambda kv: kv[1])[0]
+
+
+def _stat(ev, key: str):
+    for k, v in ev.stats:
+        if k == key:
+            return v
+    return None
+
+
+def name_gaps(gaps: list, starts_at: dict, launches: list,
+              spans: dict, thread_of_run: dict | None = None) -> list:
+    """[name] for (gap start, gap length) pairs. `starts_at` maps the
+    device time at which a program starts to (its function's name, its
+    run_id or None), `thread_of_run` a run_id to the host thread that
+    launched it, `launches` is sorted (start, thread, fn), `spans` maps a
+    thread to its pilosa.* events."""
+    out = []
+    program_starts = sorted(starts_at)
+    for start, length in gaps:
+        end = start + length
+        k = bisect.bisect_left(program_starts, end - 1.0)   # to the ns
+        fn, run = (starts_at[program_starts[k]]
+                   if k < len(program_starts)
+                   and program_starts[k] <= end + 1.0 else (None, None))
+        thread = (thread_of_run or {}).get(run)
+        if thread is None:
+            thread = _launch_thread(launches, end, fn)
+        out.append(_span_of_gap(spans.get(thread, []), start, end)
+                   if thread is not None else UNATTRIBUTED)
+    return out
+
+
 def reduce_trace(path: str) -> dict:
     from jax.profiler import ProfileData
 
@@ -63,6 +142,7 @@ def reduce_trace(path: str) -> dict:
     start = stop = None
     lo, hi = float("inf"), float("-inf")
     devices = []
+    launches, spans, thread_of_run = [], {}, {}
     for plane in space.planes:
         if plane.name == "Task Environment":
             stats = dict(plane.stats)
@@ -73,14 +153,24 @@ def reduce_trace(path: str) -> dict:
                      and not plane.name.startswith("/device:CUSTOM"))
         named = [ln.name for ln in lines if ln.name == OPS_LINE]
         ops, modules = [], []
-        for ln in lines:
+        for thread, ln in enumerate(lines):
             for ev in ln.events:
                 s, d = float(ev.start_ns), float(ev.duration_ns)
                 lo, hi = min(lo, s), max(hi, s + d)
+                if plane.name == HOST_PLANE:
+                    if ev.name.startswith(SPAN_PREFIX):
+                        spans.setdefault(thread, []).append(
+                            (s, s + d, ev.name))
+                    elif launch := _LAUNCH.match(ev.name):
+                        launches.append((s, thread, launch.group(1)))
+                    elif "Execute" in ev.name:
+                        run = _stat(ev, "run_id")
+                        if run is not None:
+                            thread_of_run.setdefault(run, thread)
                 if is_device and (ln.name in named or not named):
                     ops.append((s, s + d, ev.name))
                 if is_device and ln.name == MODULES_LINE:
-                    modules.append((s, s + d, ev.name))
+                    modules.append((s, s + d, ev.name, _stat(ev, "run_id")))
         if is_device and ops:
             devices.append((plane.name, sorted(ops), modules))
     if start is not None and stop is not None and stop > start:
@@ -90,19 +180,28 @@ def reduce_trace(path: str) -> dict:
     else:
         window_ns = 0.0
     by_name: dict = {}
-    busy, op_sum, first_gaps = [], [], []
+    busy, op_sum, first_gaps, starts_at = [], [], [], {}
     for i, (_, ops, modules) in enumerate(devices):
         united, gaps = _union([(s, e) for s, e, _ in ops])
         busy.append(united)
         op_sum.append(sum(e - s for s, e, _ in ops))
         if i == 0:
             first_gaps = gaps
-        for s, e, name in modules or ops:
+            # a program's first operation starts with the program
+            for s, e, name, run in sorted(modules, key=lambda m: m[0]):
+                fn = _FINGERPRINT.sub("", name)
+                fn = fn[4:] if fn.startswith("jit_") else fn
+                k = bisect.bisect_left(ops, (s,))
+                if k < len(ops) and ops[k][0] < e:
+                    starts_at[ops[k][0]] = (fn, run)
+        for s, e, name, *_ in modules or ops:
             name = _FINGERPRINT.sub("", name)[:120]
             by_name[name] = by_name.get(name, 0.0) + (e - s)
     n = max(1, len(devices))
     top = sorted(by_name.items(), key=lambda kv: -kv[1])[:10]
     gaps = sorted(first_gaps, key=lambda g: -g[1])[:10]
+    names = name_gaps(gaps, starts_at, sorted(launches), spans,
+                      thread_of_run)
     return {
         "window_s": window_ns / 1e9,
         "busy_s": sum(busy) / n / 1e9,
@@ -114,7 +213,8 @@ def reduce_trace(path: str) -> dict:
         "profile_start_ns": start,
         "profile_stop_ns": stop,
         "device_ops": [[name, ns / 1e9] for name, ns in top],
-        "idle_gaps": [["unattributed", ns / 1e9] for _, ns in gaps],
+        "idle_gaps": [[name, ns / 1e9]
+                      for name, (_, ns) in zip(names, gaps)],
     }
 
 

@@ -31,11 +31,12 @@ T_START = time.perf_counter()  # process start, as near as Python gives it
 
 import argparse  # noqa: E402
 import base64  # noqa: E402
+import collections  # noqa: E402
 import concurrent.futures  # noqa: E402
 import glob  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
+import re  # noqa: E402
 import shutil  # noqa: E402
 import subprocess  # noqa: E402
 import sys  # noqa: E402
@@ -48,8 +49,8 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(HERE)
 sys.path.insert(0, HERE)
 
-from lib import control, datagen, loadgen, peaks, proc, roaring_wire  # noqa: E402
-from lib import reference, stats, traffic, work  # noqa: E402
+from lib import byfile, control, datagen, loadgen, peaks, proc  # noqa: E402
+from lib import query, reference, roaring_wire, stats, work  # noqa: E402
 
 READBACK_FIELD = "readback"   # a field of its own: no query of a mix names it
 RUN_LIMIT_S = 1150.0          # the contract's 1200 s for a run that compiles
@@ -73,8 +74,8 @@ def load_cell(name: str) -> tuple:
     cfg_entry = {c["name"]: c for c in bench["configs"]}[cell["config"]]
     with open(os.path.join(REPO, cfg_entry["file"])) as fh:
         config = json.load(fh)
-    mix = traffic.load_mix(os.path.join(HERE, "traffic",
-                                        f"{cell['traffic']}.json"))
+    with open(os.path.join(HERE, "traffic", f"{cell['traffic']}.json")) as fh:
+        mix = json.load(fh)
     return bench, cell, config, mix
 
 
@@ -170,23 +171,35 @@ def reduce_trace(capture: dict) -> dict | None:
 
 
 def read_layer_metric(name: str, ctx: dict):
-    path = os.path.join(HERE, "layer_metrics", f"{name}.py")
-    spec = importlib.util.spec_from_file_location(f"layer_metric_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read(ctx)
+    return byfile.load("layer_metrics", name).read(ctx)
 
 
 def compare(sample: list, ref: reference.Reference) -> list:
     """Indices of the sampled requests whose answer is not the
-    reference's. The same text is evaluated once; numpy's passes over a
-    row's words run outside the interpreter's lock, so a few threads share
-    the work."""
+    reference's, each call by its own rule (lib/calls/). The same text is
+    evaluated once; numpy's passes over a row's words run outside the
+    interpreter's lock, so a few threads share the work."""
     asts = {s.req["pql"]: s.req["ast"] for s in sample}
     with concurrent.futures.ThreadPoolExecutor(max_workers=8) as pool:
-        want = dict(zip(asts, pool.map(ref.answer, asts.values())))
+        want = dict(zip(asts, pool.map(
+            lambda ast: query.answer(ref, ast), asts.values())))
     return [i for i, s in enumerate(sample)
-            if s.got != [want[s.req["pql"]]]]
+            if not query.same(s.req["ast"], s.got, want[s.req["pql"]])]
+
+
+def by_label(sent: list) -> dict:
+    """Latencies by the label the generator gave each request."""
+    groups: dict = {}
+    for s in sent:
+        groups.setdefault(s.req["label"], []).append(s.ms)
+
+    def natural(label: str) -> list:
+        return [int(t) if t.isdigit() else t
+                for t in re.split(r"(\d+)", label)]
+    return {label: {"n": len(v), "p50_ms": stats.percentile(v, 50),
+                    "p95_ms": stats.percentile(v, 95)}
+            for label, v in sorted(groups.items(),
+                                   key=lambda kv: natural(kv[0]))}
 
 
 def run_cell(args, make_server_argv=server_argv) -> int:
@@ -259,7 +272,8 @@ def _run(args, bench, cell, config, mix, want_platform, tmp, kids,
         f"in {load_s:.1f}s")
     readback_gap = readback(http, index, data.n_shards, args.seed)
 
-    gen = traffic.Traffic(mix, data, args.seed)
+    gen = byfile.load("lib/generators", mix.get("generator")).Traffic(
+        mix, data, args.seed)
     t0 = time.perf_counter()
     it = iter(gen.warmup())
     lock = threading.Lock()
@@ -323,7 +337,7 @@ def _run(args, bench, cell, config, mix, want_platform, tmp, kids,
     sample = [answered[i] for i in sorted(picks)]
     t0 = time.perf_counter()
     count_fn = (control.sampled_count(data.n_shards) if args.control
-                else reference.popcount)
+                else reference.exact_count)
     wrong = compare(sample, reference.Reference(data, count_fn))
     check_s = time.perf_counter() - t0
     compared = {
@@ -355,6 +369,10 @@ def _run(args, bench, cell, config, mix, want_platform, tmp, kids,
                    "setup": {"load_s": load_s, "warmup_s": warm_s,
                              "payload_bytes": loaded["bytes"]},
                    "check_s": check_s, "window_requests": len(sent),
+                   "checked_by_label": dict(collections.Counter(
+                       s.req["label"] for s in sample)),
+                   "wrong_by_label": dict(collections.Counter(
+                       sample[i].req["label"] for i in wrong)),
                    "window_compiles": window_compiles,
                    "window_compiles_by_family": compiled}
     if not args.trace:
@@ -364,15 +382,7 @@ def _run(args, bench, cell, config, mix, want_platform, tmp, kids,
             values["query_p95_ms"] = stats.percentile(ms, 95)
         values["queries_per_s"] = (len(done_in_window) - len(wrong)) / window_s
         values["setup_s"] = setup_s
-        by_size: dict = {}
-        for s in sent:
-            by_size.setdefault(min(s.req["leaves"], 16).bit_length(),
-                               []).append(s.ms)
-        extra["by_leaves"] = {
-            f"{1 << (k - 1)}-{(1 << k) - 1}": {
-                "n": len(v), "p50_ms": stats.percentile(v, 50),
-                "p95_ms": stats.percentile(v, 95)}
-            for k, v in sorted(by_size.items())}
+        extra[gen.label_key] = by_label(sent)
         wanted = metrics_of(bench, cell, "end_to_end")
     else:
         trace = reduce_trace(capture.get("doc"))
@@ -387,7 +397,7 @@ def _run(args, bench, cell, config, mix, want_platform, tmp, kids,
             hi = trace["profile_stop_ns"] / 1e9
             need = work.Work(data)
             ctx["traced_bytes_needed"] = sum(
-                need.bytes_needed(s.req["ast"]) for s in answered
+                query.bytes_needed(need, s.req["ast"]) for s in answered
                 if lo <= s.wall_send + s.ms / 1e3 <= hi)
         wanted = metrics_of(bench, cell, "per_layer")
         for m in wanted:

@@ -1,14 +1,28 @@
 """The configuration's data: the same bytes for the same seed, and the
-law its file states."""
+law its file states, for each data kind. The digests pinned here were
+taken on the parent tree (b998f37), before the law became a file of its
+own: the refactor leaves every byte of `segmentation` where it was."""
 
+import concurrent.futures
 import hashlib
 import json
 import os
 
 import numpy as np
+import pytest
 
 from conftest import BENCH
-from lib import datagen, roaring_wire
+from lib import byfile, datagen, roaring_wire
+
+zipf = byfile.load("lib/data_kinds", "zipf")
+categorical = byfile.load("lib/data_kinds", "categorical")
+
+# sha256 over every (field, shard)'s import-roaring payload, shards=2
+PARENT_DIGESTS = {
+    2_800_000_011:
+        "830fcce8d5b5a907dd493759fb0235931a8a62cade9cd49266aeb0ca50c688fa",
+    41: "e074125313c5554a675ff3b63555af6c185fab6779f7c044e8ff829c9495982d",
+}
 
 SHARDS = 2
 
@@ -26,6 +40,12 @@ def digest(data) -> str:
             h.update(roaring_wire.fragment_payload(
                 [(r, rows[r].shard_piece(s)) for r in sorted(rows)]))
     return h.hexdigest()
+
+
+@pytest.mark.parametrize("seed", sorted(PARENT_DIGESTS))
+def test_segmentation_is_byte_for_byte_the_parents(seed):
+    data = datagen.make(config("segmentation"), seed, shards=SHARDS)
+    assert digest(data) == PARENT_DIGESTS[seed]
 
 
 def test_same_seed_same_bytes():
@@ -49,7 +69,7 @@ def test_offset_follows_from_the_ratio():
 def test_column_ranks_follow_the_law():
     rng = np.random.default_rng(3)
     n = 1 << 22
-    ranks = datagen.draw_ranks(rng, 4_000_000, n, 1.01, 0.25)
+    ranks = zipf.draw_ranks(rng, 4_000_000, n, 1.01, 0.25)
     assert ranks.min() >= 0 and ranks.max() < n
     got = np.bincount(ranks * 8 // n, minlength=8) / ranks.size
     v = datagen.zipf_offset(n, 1.01, 0.25)
@@ -98,3 +118,70 @@ def test_row_forms_agree():
     assert np.array_equal(back, cols)
     assert datagen.Row(SHARDS, cols).count() == cols.size
     assert int(np.bitwise_count(words).sum()) == cols.size
+
+
+CATEGORICAL = {"shards": SHARDS, "fields": [
+    {"name": "cab", "kind": "categorical", "rows": 3, "first_id": 1,
+     "weights": [6, 3, 1]},
+    {"name": "cell", "kind": "categorical", "rows": 500,
+     "value_exponent": 1.2, "value_ratio": 0.01, "present": 0.75},
+    {"name": "seg", "rows": 5, "set_bits_per_shard": 50_000,
+     "row_exponent": 1.01, "row_ratio": 0.5,
+     "column_exponent": 1.01, "column_ratio": 0.25}]}
+
+
+def test_categorical_every_column_holds_one_value():
+    data = datagen.make(CATEGORICAL, 3_000_000_007)
+    n_cols = SHARDS << 20
+    cab, cell = data.fields["cab"], data.fields["cell"]
+    assert sorted(cab) == [1, 2, 3] and sorted(cell) == list(range(500))
+    for rows, present in ((cab, 1.0), (cell, 0.75)):
+        cols = np.concatenate([r.cols for r in rows.values()])
+        assert cols.dtype == np.uint32
+        assert np.unique(cols).size == cols.size       # never two values
+        assert abs(cols.size / n_cols - present) < 0.002
+        for r in rows.values():
+            assert np.all(np.diff(r.cols.astype(np.int64)) > 0)
+            assert sum(r.shard_piece(s).size for s in range(SHARDS)) \
+                == r.cols.size
+    assert sum(r.count() for r in cab.values()) == n_cols   # and never none
+    # the stated weights, id by id
+    for row_id, share in zip((1, 2, 3), (0.6, 0.3, 0.1)):
+        assert abs(cab[row_id].count() / n_cols - share) < 0.002
+    # the stated law over ranks, scattered over the ids
+    sizes = np.sort([r.count() for r in cell.values()])[::-1]
+    want = datagen.rank_weights(500, 1.2, 0.01) * 0.75 * n_cols
+    assert np.all(np.abs(sizes - want) < 5 * np.sqrt(want) + 1)
+    by_id = np.array([cell[i].count() for i in range(500)])
+    assert not np.array_equal(np.argsort(-by_id), np.arange(500))
+    # the zipf field beside them is made as ever
+    assert len(data.fields["seg"]) == 5
+
+
+def test_categorical_bytes_do_not_depend_on_threads():
+    spec = CATEGORICAL["fields"][1]
+    made = []
+    for workers in (1, 8):
+        with concurrent.futures.ThreadPoolExecutor(workers) as pool:
+            rows = categorical.make_field(77, 1, spec, 4, pool)
+        h = hashlib.sha256()
+        for r in sorted(rows):
+            h.update(rows[r].cols.tobytes())
+        made.append(h.hexdigest())
+    assert made[0] == made[1]
+    with concurrent.futures.ThreadPoolExecutor(2) as pool:
+        other = categorical.make_field(78, 1, spec, 4, pool)
+    assert any(not np.array_equal(other[r].cols, rows[r].cols) for r in rows)
+
+
+def test_categorical_refuses_weights_that_do_not_fit():
+    with concurrent.futures.ThreadPoolExecutor(1) as pool:
+        with pytest.raises(ValueError):
+            categorical.make_field(1, 0, {"name": "f", "rows": 3,
+                                          "weights": [1, 2]}, 1, pool)
+
+
+def test_an_unknown_kind_names_the_file_it_wants():
+    with pytest.raises(FileNotFoundError, match="lib/data_kinds/nosuch.py"):
+        datagen.make({"shards": 1, "fields": [
+            {"name": "f", "kind": "nosuch"}]}, 1)
