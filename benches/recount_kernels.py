@@ -1,0 +1,187 @@
+#!/usr/bin/env python3
+"""Timings that chose the kernel of the TopN recount from sorted columns
+(PR 29; PERF.md section 6 has the numbers). One resident entry of a field
+is, per shard, the concatenated sorted columns of its small rows with the
+row's rank beside each; the recount is counts[R] = sum over the entry's
+bits of the filter plane's bit at that column. Variants timed here:
+
+  gather            the bit test alone: take_along_axis of the filter's
+                    word at every stored column (what every gather variant
+                    has to pay)
+  gather+scatter    bit test, then jax.ops.segment_sum by rank
+  gather+cumsum     bit test, a cumsum along the entry and a gather of the
+                    running sum at every row's end (ranks are sorted)
+  gather+onehot     bit test, then the histogram by rank as a product of
+                    two one-hot matrices on the matrix unit
+  bycolumn+onehot   no gather: the entry laid out by column (one rank a
+                    column, -1 where none), the filter's bits unpacked in
+                    place, the same one-hot product. Only a field whose
+                    columns hold at most one row of the entry allows it
+  shipped           ops/bitvector.py pairs_count as the executor calls it,
+                    on the entry by pairs (gather+onehot) and by column:
+                    both inside one scan step, so that nothing of the
+                    entry's size is materialized beside it
+
+    chiprun -- python3 benches/recount_kernels.py            # the chip
+    python3 benches/recount_kernels.py --shards 2 --slots 4096 --rows 300
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import time
+
+import numpy as np
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+W = 1 << 15
+C = 1 << 20
+
+
+def make(seed, S, K, R, fill, density):
+    rng = np.random.default_rng(seed)
+    w = (5.0 + np.arange(R)) ** -1.1
+    w /= w.sum()
+    cols = np.full((S, K), C, np.int32)
+    rank = np.full((S, K), R, np.int32)
+    bycol = np.full((S, C), -1, np.int32)
+    ends = np.zeros((S, R), np.int32)
+    n = int(K * fill)
+    for s in range(S):
+        c = rng.permutation(C)[:n].astype(np.int32)
+        r = rng.choice(R, size=n, p=w).astype(np.int32)
+        order = np.lexsort((c, r))
+        cols[s, :n], rank[s, :n] = c[order], r[order]
+        bycol[s, c] = r
+        ends[s] = np.cumsum(np.bincount(r, minlength=R))
+    src = rng.integers(0, 1 << 32, size=(S, W), dtype=np.uint32)
+    keep = rng.random((S, W, 32)) < density
+    mask = (keep * (1 << np.arange(32, dtype=np.uint64))).sum(-1)
+    src &= mask.astype(np.uint32)
+    return cols, rank, bycol, ends, src
+
+
+def oracle(cols, rank, src, R):
+    out = np.zeros(R + 1, np.int64)
+    for s in range(cols.shape[0]):
+        ok = cols[s] < C
+        c = cols[s][ok]
+        bit = (src[s][c >> 5] >> (c & 31).astype(np.uint32)) & 1
+        out += np.bincount(rank[s][ok], weights=bit, minlength=R + 1
+                           ).astype(np.int64)
+    return out[:R]
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--shards", type=int, default=32)
+    ap.add_argument("--slots", type=int, default=1 << 20)
+    ap.add_argument("--rows", type=int, default=9966)
+    ap.add_argument("--reps", type=int, default=5)
+    args = ap.parse_args()
+    import jax
+    import jax.numpy as jnp
+    S, K, R = args.shards, args.slots, args.rows
+    H = 1
+    while H * 128 < R + 1:
+        H *= 2
+    chunk = min(K, 1 << 15)
+
+    def bit_test(cols, src):
+        safe = jnp.minimum(cols, C - 1)
+        w = jnp.take_along_axis(src, safe >> 5, axis=-1)
+        return ((w >> (safe & 31).astype(jnp.uint32)) & 1).astype(
+            jnp.int32) * (cols < C)
+
+    @jax.jit
+    def gather(cols, src):
+        return jnp.sum(bit_test(cols, src))
+
+    @jax.jit
+    def gather_scatter(cols, rank, src):
+        bit = bit_test(cols, src).reshape(-1)
+        return jax.ops.segment_sum(bit, rank.reshape(-1), R + 1)[:R]
+
+    @jax.jit
+    def gather_cumsum(cols, ends, src):
+        run = jnp.cumsum(bit_test(cols, src), axis=-1)
+        run = jnp.concatenate([jnp.zeros((S, 1), jnp.int32), run], axis=-1)
+        at = jnp.take_along_axis(run, ends, axis=-1)          # [S, R]
+        per = at - jnp.concatenate(
+            [jnp.zeros((S, 1), jnp.int32), at[:, :-1]], axis=-1)
+        return jnp.sum(per, axis=0)
+
+    def hist(rank, bit):
+        """rank [n, chunk] int32, bit [n, chunk] bool -> int32[H * 128]."""
+        hi_ids = jnp.arange(H, dtype=jnp.int32)
+        lo_ids = jnp.arange(128, dtype=jnp.int32)
+
+        def step(acc, rb):
+            r, b = rb
+            hi = ((r >> 7)[:, None] == hi_ids[None]) & b[:, None]
+            lo = (r & 127)[:, None] == lo_ids[None]
+            got = jnp.dot(hi.astype(jnp.bfloat16).T, lo.astype(jnp.bfloat16),
+                          preferred_element_type=jnp.float32)
+            return acc + got.astype(jnp.int32), None
+
+        acc, _ = jax.lax.scan(step, jnp.zeros((H, 128), jnp.int32),
+                              (rank, bit))
+        return acc.reshape(-1)
+
+    @jax.jit
+    def gather_onehot(cols, rank, src):
+        bit = bit_test(cols, src) != 0
+        return hist(rank.reshape(-1, chunk), bit.reshape(-1, chunk))[:R]
+
+    @jax.jit
+    def bycolumn_onehot(bycol, src):
+        bits = ((src[:, :, None] >> jnp.arange(32, dtype=jnp.uint32)) & 1
+                ).reshape(S, C) != 0
+        ok = bits & (bycol >= 0)
+        return hist(jnp.maximum(bycol, 0).reshape(-1, chunk),
+                    ok.reshape(-1, chunk))[:R]
+
+    from pilosa_tpu.ops import bitvector as bv
+
+    def shipped(pairs, src):
+        return bv.pairs_count(pairs, src, bv.pairs_count_slots(R))[:R]
+
+    cols, rank, bycol, ends, src = make(29, S, K, R, 0.73, 0.05)
+    want = oracle(cols, rank, src, R)
+    dev = {k: jax.device_put(v) for k, v in dict(
+        cols=cols, rank=rank, bycol=bycol, ends=ends, src=src,
+        pairs=np.stack([cols, rank])).items()}
+    out = {"device": jax.devices()[0].device_kind, "shards": S, "slots": K,
+           "rows": R, "bits": int((cols < C).sum())}
+    runs = {
+        "gather": (gather, ("cols", "src"), None),
+        "gather+scatter": (gather_scatter, ("cols", "rank", "src"), want),
+        "gather+cumsum": (gather_cumsum, ("cols", "ends", "src"), want),
+        "gather+onehot": (gather_onehot, ("cols", "rank", "src"), want),
+        "bycolumn+onehot": (bycolumn_onehot, ("bycol", "src"), want),
+        "shipped by pairs": (shipped, ("pairs", "src"), want),
+        "shipped by column": (shipped, ("bycol", "src"), want),
+    }
+    for name, (fn, keys, check) in runs.items():
+        a = [dev[k] for k in keys]
+        t0 = time.perf_counter()
+        got = np.asarray(fn(*a))
+        first = time.perf_counter() - t0
+        ok = None if check is None else bool((got == check).all())
+        ts = []
+        for _ in range(args.reps):
+            t0 = time.perf_counter()
+            np.asarray(fn(*a))
+            ts.append((time.perf_counter() - t0) * 1e3)
+        out[name] = {"ms_min": min(ts), "ms_median": sorted(ts)[len(ts) // 2],
+                     "first_s": first, "agrees": ok}
+        print(name, json.dumps(out[name]), flush=True)
+    print(json.dumps(out))
+
+
+if __name__ == "__main__":
+    main()

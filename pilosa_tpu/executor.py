@@ -72,6 +72,17 @@ class ExecutionError(ValueError):
     pass
 
 
+class PairsEntry:
+    """The small rows of one field, resident: `dev` int32[2, S', K] on the
+    device (ops/bitvector.py pairs_count), `ids` the rows in rank order
+    (ascending), `stored` the bits each holds over all shards. Residency
+    charges it `nbytes`, like any leaf."""
+
+    def __init__(self, dev, ids: np.ndarray, stored: np.ndarray):
+        self.dev, self.ids, self.stored = dev, ids, stored
+        self.nbytes = dev.nbytes
+
+
 class Pairs(list):
     """TopN result: [(row_id, count)] (reference Pairs, cache.go:317).
     `row_keys` holds the translated row keys, index-aligned with the
@@ -134,6 +145,15 @@ class Executor:
         # rows materialized for TopN recounts — observability for the
         # threshold-pruning walk (tests assert ≪ total rows; /debug/vars)
         self.topn_recount_rows = 0
+        # the recount from sorted columns (_pairs_entry, _pairs_recount):
+        # launches of the pairs kernel; the bytes they had to read, from
+        # the data and the request (4 a stored bit of the rows recounted,
+        # 128 KiB a shard for the filter plane), not from what was
+        # uploaded; entries built and the bytes they hold on the device
+        self.topn_pairs_recounts = 0
+        self.topn_pairs_bytes = 0
+        self.pairs_entries_built = 0
+        self.pairs_entry_bytes = 0
         # host syncs performed by GroupBy's device path — the pipelined
         # level loop promises at most ONE blocking fetch per cross-product
         # level (tests assert it, like topn_recount_rows; /debug/vars)
@@ -1364,13 +1384,15 @@ class Executor:
         if (pc is not None and pc.enabled
                 and call.name in _planner.BITMAP_CALLS
                 and not _planner.is_empty_call(call)):
-            key = _planner.subtree_cache_key(self, index, call, shards)
+            with tracing.span("plan"):
+                key = _planner.subtree_cache_key(self, index, call, shards)
         heat_on = self.heat is not None and self.heat.enabled
         epoch = 0
         if key is not None:
-            epoch = pc.epoch
-            hit = pc.get(key)
-            _planner.record_cache_event(call, hit is not None)
+            with tracing.span("plan"):
+                epoch = pc.epoch
+                hit = pc.get(key)
+                _planner.record_cache_event(call, hit is not None)
             if hit is not None:
                 if heat_on:
                     # a cached read still HEATS its operands: the hit
@@ -1382,7 +1404,9 @@ class Executor:
         acct = accounting.current_account.get()
         t0 = _time.perf_counter() if (acct is not None or heat_on) else 0.0
         program, leaves, kinds = self._compile(index, call, shards)
-        dev = self._eval_program_dense(program, leaves, kinds)
+        # asynchronous launches: the consumer's fetch waits for them
+        with tracing.span("dispatch"):
+            dev = self._eval_program_dense(program, leaves, kinds)
         if acct is not None or heat_on:
             # the composed-subtree evaluation is per-query device work the
             # batchers never see — charged as wall time of the compile +
@@ -1901,23 +1925,26 @@ class Executor:
                 pairs = self._exact_counts(index, f, shards, ids,
                                            src_dense, tanimoto)
         else:
-            cand_ids, cand_counts = self._topn_candidate_arrays(
-                index, f, shards)
-            if allowed is not None:
-                keep = np.fromiter(
-                    (f.row_attrs.attrs(int(r)).get(attr_name) in allowed
-                     for r in cand_ids), bool, cand_ids.size)
-                cand_ids, cand_counts = cand_ids[keep], cand_counts[keep]
-            if threshold:
-                # cached counts bound the final count from above (they are
-                # full row counts; intersection can only shrink them), so
-                # rows under the floor can be dropped before any recount
-                keep = cand_counts >= threshold
-                cand_ids, cand_counts = cand_ids[keep], cand_counts[keep]
+            with tracing.span("topn.candidates"):
+                cand_ids, cand_counts = self._topn_candidate_arrays(
+                    index, f, shards)
+                if allowed is not None:
+                    keep = np.fromiter(
+                        (f.row_attrs.attrs(int(r)).get(attr_name) in allowed
+                         for r in cand_ids), bool, cand_ids.size)
+                    cand_ids, cand_counts = cand_ids[keep], cand_counts[keep]
+                if threshold:
+                    # cached counts bound the final count from above (they
+                    # are full row counts; intersection can only shrink
+                    # them), so rows under the floor can be dropped before
+                    # any recount
+                    keep = cand_counts >= threshold
+                    cand_ids, cand_counts = cand_ids[keep], cand_counts[keep]
             if src_dense is not None:
-                pairs = self._topn_src_walk(index, f, shards, cand_ids,
-                                            cand_counts, src_dense, n,
-                                            tanimoto)
+                with tracing.span("topn.recount", field=f.name):
+                    pairs = self._topn_src_walk(index, f, shards, cand_ids,
+                                                cand_counts, src_dense, n,
+                                                tanimoto)
             else:
                 # cached counts are exact per-shard (write-maintained,
                 # view.py:141-147) but a row can be missing from a shard's
@@ -1930,12 +1957,13 @@ class Executor:
                 winner_ids = cand_ids[:n] if n is not None else cand_ids
                 pairs = self._host_row_counts(
                     index, f, shards, winner_ids.tolist())
-        if threshold:
-            pairs = [(i, c) for i, c in pairs if c >= threshold]
-        merged = merge_pairs([pairs])
-        if n is not None and ids_arg is None:
-            merged = merged[:n]
-        return Pairs((i, c) for i, c in merged if c > 0)
+        with tracing.span("reduce"):
+            if threshold:
+                pairs = [(i, c) for i, c in pairs if c >= threshold]
+            merged = merge_pairs([pairs])
+            if n is not None and ids_arg is None:
+                merged = merged[:n]
+            return Pairs((i, c) for i, c in merged if c > 0)
 
     def _topn_candidate_arrays(self, index: Index, f, shards):
         """Merged (ids, cached_counts) int64 arrays from per-shard rank
@@ -1981,23 +2009,136 @@ class Executor:
                 self._topn_merge_memo.popitem(last=False)
         return ids, counts
 
+    def _pairs_entry(self, index: Index, f, shards):
+        """The field's resident pairs entry for this shard set, or None
+        where the hybrid representation is off: every row whose fullest
+        shard holds no more bits than the sparse threshold (the number
+        planner.choose_representation goes by; no hysteresis, the entry is
+        rebuilt with its generations), as sorted columns with the row's
+        rank beside each or, where no column holds two of the rows and
+        that is no larger, as one rank a column (ops/bitvector.py
+        pairs_count takes either). One entry a
+        (field, view, shard set, fragment generations), built once however
+        many threads ask (DeviceResidency.leaf is single-flight), charged
+        to the residency budget like any leaf; a write to the field bumps
+        a fragment's generation, so the next TopN builds anew and the old
+        entry ages out. A field whose small rows would take more than a
+        quarter of the budget gets an entry of no rows, and its TopN the
+        dense walk."""
+        hyb = self.hybrid
+        view = f.view(VIEW_STANDARD)
+        if hyb is None or not hyb.active() or view is None:
+            return None
+        frags = [view.fragment(s) for s in shards]
+        gens = tuple(0 if fr is None else fr.generation for fr in frags)
+        key = ("pairs", index.name, f.name, VIEW_STANDARD, tuple(shards),
+               gens)
+        tracker = self.heat
+        if tracker is not None and tracker.enabled:
+            tracker.touch_many([(index.name, f.name, VIEW_STANDARD, s)
+                                for s in shards], reads=1)
+
+        built: dict = {}   # make() -> put(): the entry's host-side half
+
+        def make():
+            from pilosa_tpu.ops import bitvector as bv
+            with tracing.span("pairs.build"):
+                bits = [(np.empty(0, np.int32),) * 2 if fr is None
+                        else fr.rows_columns() for fr in frags]
+                # the fullest shard of every row, from the same pass: a
+                # shard's rows are sorted, so its counts are one diff
+                per = []
+                for rows, _ in bits:
+                    cut = np.flatnonzero(np.diff(rows, prepend=-1))
+                    per.append((rows[cut], np.diff(cut, append=rows.size)))
+                ids = np.unique(np.concatenate([u for u, _ in per]))
+                fullest = np.zeros(ids.size, np.int64)
+                for uids, counts in per:
+                    at = np.searchsorted(ids, uids)
+                    fullest[at] = np.maximum(fullest[at], counts)
+                ids = ids[fullest <= hyb.threshold]
+                kept = []
+                for rows, cols in bits:
+                    at = np.searchsorted(ids, rows)
+                    hit = at < ids.size
+                    hit[hit] = ids[at[hit]] == rows[hit]
+                    kept.append((cols[hit], at[hit].astype(np.int32)))
+                slots = hyb.pad_slots(max(
+                    [c.size for c, _ in kept] + [1]))
+                if len(shards) * slots * 8 > self.residency.budget // 4:
+                    ids, kept, slots = ids[:0], [], hyb.pad_slots(1)
+                # by column where the data allows it (no column holds two
+                # of the rows) and it is no larger than the pairs
+                by_column = 2 * slots >= SHARD_WIDTH and all(
+                    np.bincount(cols, minlength=1).max(initial=0) <= 1
+                    for cols, _ in kept)
+                arr = (np.full((len(shards), SHARD_WIDTH), -1, np.int32)
+                       if by_column else
+                       np.full((2, len(shards), slots), bv.SPARSE_SENTINEL,
+                               np.int32))
+                stored = np.zeros(ids.size, np.int64)
+                for i, (cols, rank) in enumerate(kept):
+                    if by_column:
+                        arr[i, cols] = rank
+                    else:
+                        arr[0, i, :cols.size] = cols
+                        arr[1, i, :rank.size] = rank
+                    stored += np.bincount(rank, minlength=ids.size)
+                built["ids"], built["stored"] = ids.astype(np.int64), stored
+                return arr
+
+        def put(arr):
+            hyb.record_upload("sparse", arr.nbytes)
+            entry = PairsEntry(self.runner.put_pairs(arr), built["ids"],
+                               built["stored"])
+            self.pairs_entries_built += 1
+            self.pairs_entry_bytes += entry.nbytes
+            return entry
+
+        return self.residency.leaf(key, make, put=put)
+
+    def _pairs_recount(self, entry, n_shards: int, row_ids: np.ndarray,
+                       src_dense):
+        """Launch the recount of the entry's rows under the filter plane
+        (one program, whatever the rows hold); returns fetch() -> int64
+        counts of `row_ids`, which all have to be rows of the entry."""
+        from pilosa_tpu.ops.bitvector import pairs_count_slots
+        at = np.searchsorted(entry.ids, row_ids)
+        with tracing.span("dispatch"):
+            handle = self.runner.pairs_count(
+                entry.dev, src_dense, pairs_count_slots(entry.ids.size))
+        self.topn_pairs_recounts += 1
+        self.topn_pairs_bytes += (4 * int(entry.stored[at].sum())
+                                  + n_shards * WORDS * 4)
+
+        def fetch() -> np.ndarray:
+            with tracing.span("device.wait"):
+                counts = np.asarray(handle)
+            return counts[at].astype(np.int64)
+
+        return fetch
+
     def _topn_src_walk(self, index: Index, f, shards,
                        cand_ids: np.ndarray, cand_counts: np.ndarray,
                        src_dense, n, tanimoto: int) -> list[tuple[int, int]]:
         """Phase-1 intersection ranking with the reference's threshold walk
-        (fragment.go:1121-1136): walk candidates in count-desc blocks,
-        recount |row ∩ src| on device (ops/topn.top_rows_intersect /
-        tanimoto kernels), and stop once the next cached count — an upper
-        bound on every remaining intersection count — cannot beat the
-        current n-th best."""
+        (fragment.go:1121-1136): recount |row ∩ src| on the device and stop
+        once the next cached count — an upper bound on every remaining
+        intersection count — cannot beat the current n-th best. Rows above
+        the sparse threshold are walked in count-desc blocks of planes,
+        counted where they lie (ops/topn kernels); the rows below it are
+        recounted all at once from the field's pairs entry, in one launch,
+        unless the n-th best so far already beats the largest cached count
+        among them. The two count vectors meet in one heap: count
+        descending, id ascending, ties as the dense walk alone gives
+        them."""
         import heapq
 
         import jax.numpy as jnp
 
-        from pilosa_tpu.ops.bitvector import intersect_count, popcount
-        from pilosa_tpu.ops.topn import tanimoto_counts_packed
+        from pilosa_tpu.ops.bitvector import popcount
+        from pilosa_tpu.ops.topn import leaves_counts_packed
 
-        src_flat = src_dense.reshape(-1)
         scount = 0
         if tanimoto:
             # Tanimoto count bounds (fragment.go:1043-1060):
@@ -2008,65 +2149,30 @@ class Executor:
             # row evicted from one shard's cache undercounts in the merge
             # (executor.py _execute_topn recount rationale) and a stale
             # band test would drop rows whose true tanimoto qualifies.
-            scount = int(jnp.sum(popcount(src_flat)))
+            with tracing.span("dispatch"):
+                handle = jnp.sum(popcount(src_dense))
+            with tracing.span("device.wait"):
+                scount = int(handle)
             lo = scount * tanimoto / 100
             hi = scount * 100 / tanimoto
             exact = self._host_row_count_arr(index, f, shards, cand_ids)
             keep = (exact > lo) & (exact < hi)
             cand_ids, cand_counts = cand_ids[keep], exact[keep]
-        sparse = self._topn_src_sparse(index, f, shards, cand_ids,
-                                       cand_counts, src_dense, n,
-                                       tanimoto, scount)
-        if sparse is not None:
-            return sparse
-        pairs = list(zip(cand_ids.tolist(), cand_counts.tolist()))
+        with tracing.span("leaves"):
+            entry = self._pairs_entry(index, f, shards)
+        small = (np.isin(cand_ids, entry.ids) if entry is not None
+                 else np.zeros(cand_ids.size, bool))
+        pairs = list(zip(cand_ids[~small].tolist(),
+                         cand_counts[~small].tolist()))
         # min-heap of (count, -row_id): evicts lowest count, then largest id,
         # preserving Pairs order (count desc, id asc) at the boundary
         heap: list[tuple[int, int]] = []
         out: list[tuple[int, int]] = []
-        CHUNK = _recount_chunk(len(shards))
-        for start in range(0, len(pairs), CHUNK):
-            qctx.check()  # abort between walk blocks
-            block = pairs[start:start + CHUNK]
-            if (n is not None and len(heap) >= n
-                    and block[0][1] < heap[0][0]):
-                break  # threshold prune: no remaining row can reach top n
-            slab = jnp.stack([
-                self._row_leaf_dev(index, f.name, VIEW_STANDARD, shards, rid)
-                for rid, _ in block])
-            self.topn_recount_rows += len(block)
-            flat = slab.reshape(len(block), -1)
-            # single-dispatch packed counts (XLA or the Pallas blocked
-            # kernel behind PILOSA_TPU_PALLAS): one pass over the slab,
-            # one host fetch, instead of tanimoto_counts' three popcounts
-            pack_fn = tanimoto_counts_packed
-            if self.runner.use_pallas:
-                from pilosa_tpu.ops import pallas_kernels
-                pack_fn = pallas_kernels.topn_counts_packed
-            if tanimoto:
-                packed = np.asarray(pack_fn(flat, src_flat))
-                inter, rcounts = packed[0], packed[1]
-                scount = int(packed[2, 0])
-                # the strict reference mask (ops/topn.tanimoto_mask) on
-                # the fetched counts: 100·inter > T·(union)
-                keep = (100 * inter.astype(np.int64)
-                        > tanimoto * (rcounts.astype(np.int64)
-                                      + scount - inter))
-                counts = np.where(keep, inter, 0)
-            elif self.runner.use_pallas:
-                # all block counts come back (B int32s — trivial transfer)
-                # rather than a device top_k: lax.top_k breaks ties by
-                # position (= cached-count order), which would cut a tied
-                # smaller row id and violate Pairs order; the host heap's
-                # (count, -id) key keeps tie-breaking exact
-                counts = np.asarray(pack_fn(flat, src_flat))[0]
-            else:
-                counts = np.asarray(intersect_count(flat, src_flat[None]))
-            block_pairs = [(block[i][0], int(counts[i]))
-                           for i in range(len(block))]
+
+        def offer(block_pairs) -> None:
             if n is None:
                 out.extend(block_pairs)
-                continue
+                return
             for rid, c in block_pairs:
                 if c <= 0:
                     continue
@@ -2075,57 +2181,77 @@ class Executor:
                     heapq.heappush(heap, item)
                 elif item > heap[0]:
                     heapq.heapreplace(heap, item)
+
+        CHUNK = _recount_chunk(len(shards))
+        for start in range(0, len(pairs), CHUNK):
+            qctx.check()  # abort between walk blocks
+            block = pairs[start:start + CHUNK]
+            if (n is not None and len(heap) >= n
+                    and block[0][1] < heap[0][0]):
+                break  # threshold prune: no remaining row can reach top n
+            with tracing.span("leaves"):
+                leaves = self._stackable(
+                    [self._row_leaf_dev(index, f.name, VIEW_STANDARD, shards,
+                                        rid) for rid, _ in block])
+            self.topn_recount_rows += len(block)
+            # one dispatch, one host fetch of the packed counts: over the
+            # leaves where they lie (XLA), or over a slab of them for the
+            # Pallas blocked kernel behind PILOSA_TPU_PALLAS
+            with tracing.span("dispatch"):
+                if self.runner.use_pallas:
+                    from pilosa_tpu.ops import pallas_kernels
+                    handle = pallas_kernels.topn_counts_packed(
+                        jnp.stack(leaves).reshape(len(leaves), -1),
+                        src_dense.reshape(-1))
+                else:
+                    handle = leaves_counts_packed(leaves, src_dense)
+            with tracing.span("device.wait"):
+                packed = np.asarray(handle)[:, :len(block)]
+            with tracing.span("reduce"):
+                # all block counts come back (B int32s — trivial transfer)
+                # rather than a device top_k: lax.top_k breaks ties by
+                # position (= cached-count order), which would cut a tied
+                # smaller row id and violate Pairs order; the host heap's
+                # (count, -id) key keeps tie-breaking exact
+                counts = packed[0]
+                if tanimoto:
+                    inter, rcounts = packed[0], packed[1]
+                    scount = int(packed[2, 0])
+                    # the strict reference mask (ops/topn.tanimoto_mask) on
+                    # the fetched counts: 100·inter > T·(union)
+                    keep = (100 * inter.astype(np.int64)
+                            > tanimoto * (rcounts.astype(np.int64)
+                                          + scount - inter))
+                    counts = np.where(keep, inter, 0)
+                offer([(block[i][0], int(counts[i]))
+                       for i in range(len(block))])
+        if small.any() and not (
+                n is not None and len(heap) >= n
+                and int(cand_counts[small].max()) < heap[0][0]):
+            # the entry as a whole under the same prune: no launch where
+            # no row of it can reach the top n
+            qctx.check()
+            ids = cand_ids[small]
+            inter = self._pairs_recount(entry, len(shards), ids,
+                                        src_dense)()
+            with tracing.span("reduce"):
+                if tanimoto:
+                    # cand_counts are exact row counts here (the band
+                    # recounted them); STRICT like the dense mask
+                    keep = 100 * inter > tanimoto * (
+                        cand_counts[small] + scount - inter)
+                    inter = np.where(keep, inter, 0)
+                offer(list(zip(ids.tolist(), inter.tolist())))
         if n is None:
             return out
         return [(-nrid, c) for c, nrid in heap]
 
-    def _topn_src_sparse(self, index: Index, f, shards,
-                         cand_ids: np.ndarray, cand_counts: np.ndarray,
-                         src_dense, n, tanimoto: int, scount: int = 0):
-        """Sparse host path for the Src intersection ranking: batched
-        |row ∩ src| from the frozen stores' flat arrays — linear in the
-        candidates' STORED bits, not candidates × dense shard width (the
-        regime of the reference's chemical-similarity showcase, where
-        uniform fingerprint cardinalities defeat count-bound pruning and
-        every cached row must be intersected). Returns None when any
-        fragment can't take the vectorized path (mutable store / mutated
-        candidates) — the dense device walk handles those."""
-        import heapq
-
-        view = f.view(VIEW_STANDARD)
-        if view is None or cand_ids.size == 0:
-            return []
-        src_host = np.asarray(src_dense)  # [S', W] (pad shards are zero)
-        totals = np.zeros(cand_ids.size, dtype=np.int64)
-        for i, s in enumerate(shards):
-            qctx.check()  # abort between shard passes, like the dense walk
-            frag = view.fragment(s)
-            if frag is None:
-                continue
-            bits = np.unpackbits(src_host[i].view(np.uint8),
-                                 bitorder="little")
-            src_cols = np.flatnonzero(bits).astype(np.int64)
-            got = frag.rows_intersection_counts(cand_ids, src_cols)
-            if got is None:
-                return None  # fall back to the dense walk
-            totals += got
-        self.topn_recount_rows += int(cand_ids.size)
-        # array-native filter + rank (a Python tuple loop over 100k+
-        # candidates was a measurable share of the walk)
-        keep = totals > 0
-        if tanimoto:
-            # scount arrives from the caller; cand_counts are EXACT here
-            # (the band recounted them). STRICT, like the dense
-            # tanimoto_mask (reference fragment.go:1096-1100 drops
-            # equality-at-threshold rows)
-            keep &= 100 * totals > tanimoto * (cand_counts + scount
-                                               - totals)
-        ids, counts = cand_ids[keep], totals[keep]
-        if n is not None and ids.size > n:
-            # top n by (count desc, id asc) — matches the dense walk
-            order = np.lexsort((ids, -counts))[:n]
-            ids, counts = ids[order], counts[order]
-        return list(zip(ids.tolist(), counts.tolist()))
+    @staticmethod
+    def _stackable(leaves: list) -> tuple:
+        """The leaves of one recount block as a program's operands, padded
+        to a multiple of eight with the last one so that blocks of about
+        one size share a program (the counts past the block are dropped)."""
+        return tuple(leaves + leaves[-1:] * (-len(leaves) % 8))
 
     def _host_row_count_arr(self, index: Index, f, shards,
                             row_ids) -> np.ndarray:
@@ -2148,36 +2274,62 @@ class Executor:
 
     def _exact_counts(self, index: Index, f, shards, row_ids: list[int],
                       src_dense, tanimoto: int):
-        """Batched device recount: HBM-resident row leaves stacked on device
-        in chunks -> exact counts; only int32 count vectors leave the chip
+        """Batched device recount of exactly these rows (`ids=`, the
+        distributed phase 2): the rows of the field's pairs entry in one
+        launch from their sorted columns, the others as HBM-resident row
+        leaves counted in chunks; only int32 count vectors leave the chip
         (src_dense, if given, is already a device array [S', W])."""
-        from pilosa_tpu.ops.bitvector import popcount, intersect_count
+        from pilosa_tpu.ops.bitvector import popcount
+        from pilosa_tpu.ops.topn import leaves_counts_packed
         import jax.numpy as jnp
 
-        pairs = []
+        ids_arr = np.asarray(row_ids, dtype=np.int64)
+        got: dict[int, int] = {}
+        fetch_small = None
+        if src_dense is not None and ids_arr.size:
+            with tracing.span("leaves"):
+                entry = self._pairs_entry(index, f, shards)
+            small = (np.isin(ids_arr, entry.ids) if entry is not None
+                     else np.zeros(ids_arr.size, bool))
+            if small.any():
+                small_ids = np.unique(ids_arr[small])
+                fetch_small = self._pairs_recount(
+                    entry, len(shards), small_ids, src_dense)
+                row_ids = ids_arr[~small].tolist()
+        scount = None
         CHUNK = _recount_chunk(len(shards))
         for start in range(0, len(row_ids), CHUNK):
             qctx.check()  # abort between recount chunks
             chunk = row_ids[start : start + CHUNK]
-            slab = jnp.stack([
-                self._row_leaf_dev(index, f.name, VIEW_STANDARD, shards, rid)
-                for rid in chunk
-            ])  # [R, S', W] on device
+            with tracing.span("leaves"):
+                leaves = self._stackable(
+                    [self._row_leaf_dev(index, f.name, VIEW_STANDARD, shards,
+                                        rid) for rid in chunk])
             self.topn_recount_rows += len(chunk)
             if src_dense is not None:
-                inter = np.asarray(intersect_count(slab, src_dense[None]))  # [R, S']
-                counts = inter.sum(axis=1)
+                packed = np.asarray(leaves_counts_packed(
+                    leaves, src_dense))[:, :len(chunk)].astype(np.int64)
+                counts, scount = packed[0], int(packed[2, 0])
                 if tanimoto:
-                    rcounts = np.asarray(popcount(slab)).sum(axis=1)
-                    scount = int(np.asarray(popcount(src_dense)).sum())
-                    # STRICT like tanimoto_mask / the sparse walk: the
+                    # STRICT like tanimoto_mask / the pairs recount: the
                     # distributed phase-2 recount must agree with phase 1
-                    keep = 100 * counts > tanimoto * (rcounts + scount - counts)
+                    keep = 100 * counts > tanimoto * (packed[1] + scount
+                                                      - counts)
                     counts = np.where(keep, counts, 0)
             else:
-                counts = np.asarray(popcount(slab)).sum(axis=1)  # [R]
-            pairs.extend((rid, int(c)) for rid, c in zip(chunk, counts))
-        return pairs
+                counts = np.asarray(popcount(jnp.stack(leaves))).sum(axis=1)
+            got.update(zip(chunk, (int(c) for c in counts)))
+        if fetch_small is not None:
+            inter = fetch_small()
+            if tanimoto:
+                if scount is None:
+                    scount = int(np.asarray(popcount(src_dense)).sum())
+                rcounts = self._host_row_count_arr(index, f, shards,
+                                                   small_ids)
+                keep = 100 * inter > tanimoto * (rcounts + scount - inter)
+                inter = np.where(keep, inter, 0)
+            got.update(zip(small_ids.tolist(), inter.tolist()))
+        return [(int(rid), got[int(rid)]) for rid in ids_arr.tolist()]
 
     # ------------------------------------------------------- Rows / GroupBy
 
@@ -2259,10 +2411,6 @@ class Executor:
         limited final level probes its lex-first chunk before fanning out
         the rest, keeping the old early-exit's compute bound (a probe miss
         costs one extra sync for the remaining chunks)."""
-        import jax
-        import jax.numpy as jnp
-        from pilosa_tpu.ops.bitvector import popcount
-
         shards = self._query_shards(index, shards)
         limit = call.uint_arg("limit")
         rows_calls = [c for c in call.children if c.name == "Rows"]
@@ -2284,6 +2432,17 @@ class Executor:
 
         # per Rows call: (field, [row_ids], device slab [R, S', W])
         axes = []
+        with tracing.span("leaves"):
+            empty = self._groupby_axes(index, rows_calls, shards, axes)
+        if empty:
+            return GroupCounts([])
+        return self._groupby_levels(axes, filter_dev, limit)
+
+    def _groupby_axes(self, index: Index, rows_calls, shards,
+                      axes: list) -> bool:
+        """Resolve every Rows axis to its resident slab, appending (field,
+        row ids, slab) to `axes`; True where an axis has no row, so the
+        result is empty."""
         for rc in rows_calls:
             fname = rc.args.get("_field") or rc.args.get("field")
             f = index.field(fname)
@@ -2291,7 +2450,7 @@ class Executor:
                 raise ExecutionError(f"field not found: {fname}")
             row_ids = list(self._execute_rows(index, rc, shards))
             if not row_ids:
-                return GroupCounts([])
+                return True
             # the stacked [R, S', W] axis slab is itself residency-cached
             # (gen-keyed like its component leaves): repeat GroupBys skip
             # the R-operand host→device upload. Built from HOST rows
@@ -2311,6 +2470,16 @@ class Executor:
                                   for s in shards])
                         for rid in rids])))
             axes.append((fname, row_ids, slab))
+        return False
+
+    def _groupby_levels(self, axes: list, filter_dev, limit):
+        """The cross product level by level over the resolved axis slabs
+        (see _execute_group_by): launches under `dispatch`, each level's
+        one fetch under `device.wait`, the host's bookkeeping between them
+        and the result's assembly under `reduce`."""
+        import jax
+        import jax.numpy as jnp
+        from pilosa_tpu.ops.bitvector import popcount
 
         # prefixes per dispatch: the [chunk, R, S, W] intermediate is fused
         # into the popcount reduction (never hits HBM), so chunking is
@@ -2327,7 +2496,8 @@ class Executor:
         # only level whose slab is ever materialized beyond the axis leaves)
         fname0, rows0, slab0 = axes[0]
         if filter_dev is not None:
-            slab0 = jnp.bitwise_and(slab0, filter_dev[None])
+            with tracing.span("dispatch"):
+                slab0 = jnp.bitwise_and(slab0, filter_dev[None])
         axis_slabs = [slab0] + [a[2] for a in axes[1:]]
 
         # comb: one index array per axis consumed so far; row-major order of
@@ -2335,7 +2505,10 @@ class Executor:
         comb = [np.arange(len(rows0))]
         if len(axes) == 1:
             # one fused dispatch + one fetch of the [R0] count vector
-            counts = np.asarray(jnp.sum(popcount(slab0), axis=-1))
+            with tracing.span("dispatch"):
+                handle = jnp.sum(popcount(slab0), axis=-1)
+            with tracing.span("device.wait"):
+                counts = np.asarray(handle)
             self.groupby_host_syncs += 1
             live = np.nonzero(counts)[0]
             comb, counts = [live], counts[live]
@@ -2346,7 +2519,12 @@ class Executor:
                 last = li == len(axes) - 1
                 limited_last = last and limit is not None
                 P, R = len(comb[0]), len(row_ids)
-                p_chunk = chunk_for(slab)
+                # no wider than the prefixes there are, rounded up to a
+                # power of two so that shapes repeat: the gathered prefix
+                # slab is [p_chunk, S, W] whatever P is, and nine prefixes
+                # padded to 256 were 1 GiB a request at 32 shards
+                p_chunk = min(chunk_for(slab),
+                              max(16, 1 << (P - 1).bit_length()))
                 bound = max(1, min(p_chunk * R, self._groupby_live_cap))
                 if limited_last:
                     # the result is a lexicographic prefix, so no chunk
@@ -2382,12 +2560,15 @@ class Executor:
                     if not wave or (limited_last and found >= limit):
                         continue
                     pending = []
-                    for st in wave:
-                        qctx.check()  # abort between dispatches (no sync)
-                        pending.append(dispatch(st))
+                    with tracing.span("dispatch"):
+                        for st in wave:
+                            qctx.check()  # abort between dispatches (no sync)
+                            pending.append(dispatch(st))
                     # the wave's single host sync: one batched fetch of
                     # every chunk's (n_live, flat indices, counts) triple
-                    fetched = jax.device_get([o for (_, _, o) in pending])
+                    with tracing.span("device.wait"):
+                        fetched = jax.device_get(
+                            [o for (_, _, o) in pending])
                     self.groupby_host_syncs += 1
                     for (st, idx, _), (n_live, flat_idx, cvals) in zip(
                             pending, fetched):
@@ -2397,9 +2578,10 @@ class Executor:
                             # dense chunk overflowed the prune bound:
                             # refetch its full count matrix (extra sync,
                             # counted; no group is ever silently dropped)
-                            cmat = np.asarray(self.runner.groupby_cmat(
-                                axis_slabs[:li], idx, slab,
-                                jnp.int32(min(st + p_chunk, P) - st)))
+                            with tracing.span("device.wait"):
+                                cmat = np.asarray(self.runner.groupby_cmat(
+                                    axis_slabs[:li], idx, slab,
+                                    jnp.int32(min(st + p_chunk, P) - st)))
                             self.groupby_host_syncs += 1
                             lp, lr = np.nonzero(cmat)
                             cv = cmat[lp, lr]
@@ -2423,18 +2605,19 @@ class Executor:
                 counts = np.concatenate(count_parts)
                 comb = [ci[live_p] for ci in comb] + [live_r]
 
-        results = []
-        axis_rows = [rows0] + [a[1] for a in axes[1:]]
-        axis_names = [fname0] + [a[0] for a in axes[1:]]
-        for k in range(len(counts)):
-            if limit is not None and len(results) >= limit:
-                break  # before append: limit=0 yields [] (old recursion)
-            results.append({
-                "group": [{"field": axis_names[a],
-                           "rowID": int(axis_rows[a][comb[a][k]])}
-                          for a in range(len(comb))],
-                "count": int(counts[k]),
-            })
+        with tracing.span("reduce"):
+            results = []
+            axis_rows = [rows0] + [a[1] for a in axes[1:]]
+            axis_names = [fname0] + [a[0] for a in axes[1:]]
+            for k in range(len(counts)):
+                if limit is not None and len(results) >= limit:
+                    break  # before append: limit=0 yields [] (old recursion)
+                results.append({
+                    "group": [{"field": axis_names[a],
+                               "rowID": int(axis_rows[a][comb[a][k]])}
+                              for a in range(len(comb))],
+                    "count": int(counts[k]),
+                })
         return GroupCounts(results)
 
     # -------------------------------------------------------------- writes

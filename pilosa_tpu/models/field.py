@@ -77,6 +77,9 @@ class Field:
         # two concurrent first-writes must not both construct a View for
         # the same name: each would open (flock) the same fragment files
         self._view_mu = threading.Lock()
+        # guards available_shards, shards_version and the file they are
+        # saved to
+        self._shards_mu = threading.Lock()
         self.available_shards = Bitmap()
         # bumped on every available-shards change: Index.available_shards
         # memoizes its union on the tuple of field versions (the query
@@ -174,18 +177,28 @@ class Field:
     # -- shard tracking -----------------------------------------------------
 
     def add_available_shard(self, shard: int, quiet: bool = False) -> None:
-        if not self.available_shards.contains(shard):
+        # the cheap test first, unlocked (every Set passes here); the test,
+        # the add, the version and the save again under the lock: two
+        # imports of different shards at once used to lose one of them
+        # (Bitmap.add from two threads, and a save of the older bitmap
+        # over the newer one), an acknowledged shard no query then served
+        if self.available_shards.contains(shard):
+            return
+        with self._shards_mu:
+            if self.available_shards.contains(shard):
+                return
             self.available_shards.add(shard)
             self.shards_version += 1
             self._save_available_shards()
-            if self.on_shard_added is not None and not quiet:
-                self.on_shard_added(self.index, self.name, shard)
+        if self.on_shard_added is not None and not quiet:
+            self.on_shard_added(self.index, self.name, shard)
 
     def remove_available_shard(self, shard: int) -> None:
-        if self.available_shards.contains(shard):
-            self.available_shards.remove(shard)
-            self.shards_version += 1
-            self._save_available_shards()
+        with self._shards_mu:
+            if self.available_shards.contains(shard):
+                self.available_shards.remove(shard)
+                self.shards_version += 1
+                self._save_available_shards()
 
     def shards(self) -> list[int]:
         return [int(s) for s in self.available_shards.slice()]

@@ -434,6 +434,88 @@ def sparse_to_dense(sp: jax.Array, n_words: int) -> jax.Array:
     return jax.vmap(one)(flat).reshape(*lead, n_words)
 
 
+# -- the small rows of one field, recounted under a filter in one launch -----
+# A `pairs` entry (executor._pairs_entry) holds every row of a field whose
+# fullest shard has no more bits than the sparse threshold, in one of two
+# layouts. By pairs, int32[2, S, K]: per shard the rows' concatenated
+# sorted columns (plane 0, sentinel-padded) and beside each the row's rank
+# in the entry's id list (plane 1). By column, int32[S, 2^20]: the rank of
+# the one row that holds the column, -1 where none does; only a field
+# whose columns hold at most one of those rows allows it (a record's
+# attribute: one value a column), and the executor takes it where it is no
+# larger than the pairs. A filtered TopN recounts all of the rows at once:
+# the filter plane's bit at every stored column, summed by rank — where
+# the dense walk stacks one [S, W] plane a candidate row whatever it holds.
+# By pairs the bit test is a gather (10 ns an element on the chip); by
+# column the filter's words are unpacked in place and nothing is gathered.
+
+# entry slots one histogram step covers: its two one-hot operands are
+# [PAIRS_STEP, H] and [PAIRS_STEP, 128] bfloat16, 16 MiB at H = 128
+PAIRS_STEP = 1 << 15
+
+
+def pairs_count_slots(n_rows: int) -> int:
+    """Length of the count vector for `n_rows` entry rows: 128 · H, H a
+    power of two, so that entries of about one size share a program."""
+    h = 1
+    while h * 128 < n_rows:
+        h <<= 1
+    return h * 128
+
+
+def pairs_count_local(pairs: jax.Array, src: jax.Array,
+                      n_slots: int) -> jax.Array:
+    """counts int32[n_slots] of one block of shards: pairs int32[2, S, K]
+    or, by column, int32[S, 2^20]; src uint32[S, W]. One scan over steps of
+    PAIRS_STEP slots, each within one shard: the bit of every slot (by
+    pairs `_dense_bit_test`'s gather from that shard's plane, by column
+    the plane's own words unpacked), then the sum by rank as a product of
+    two one-hot matrices on the matrix unit (rank = 128 · hi + lo;
+    counts[hi, lo] = Σ_k bit_k · [hi_k = hi] · [lo_k = lo]), exact in
+    float32 for a step of 2^15 slots. Nothing of the entry's size is
+    materialized beside it: sixteen request threads may have this program
+    in flight at once. Timings of the alternatives:
+    benches/recount_kernels.py, PERF.md section 6."""
+    by_column = pairs.ndim == 2
+    n_shards, k = pairs.shape[-2], pairs.shape[-1]
+    step = min(PAIRS_STEP, k)
+    per_shard = k // step
+    h = n_slots // 128
+    hi_ids = jnp.arange(h, dtype=jnp.int32)
+    lo_ids = jnp.arange(128, dtype=jnp.int32)
+    bit_ids = jnp.arange(WORD_BITS, dtype=jnp.uint32)
+
+    def one(acc, i):
+        s, at = i // per_shard, (i % per_shard) * step
+        if by_column:
+            r = lax.dynamic_slice(pairs, (s, at), (1, step))[0]
+            words = lax.dynamic_slice(src, (s, at // WORD_BITS),
+                                      (1, step // WORD_BITS))[0]
+            b = ((((words[:, None] >> bit_ids[None]) & 1) != 0)
+                 .reshape(step) & (r >= 0))
+        else:
+            blk = lax.dynamic_slice(pairs, (0, s, at), (2, 1, step))
+            plane = lax.dynamic_index_in_dim(src, s, axis=0, keepdims=False)
+            r = blk[1, 0]
+            b = _dense_bit_test(blk[0, 0], plane)
+        hi = ((r >> 7)[:, None] == hi_ids[None]) & b[:, None]
+        lo = (r & 127)[:, None] == lo_ids[None]
+        got = jnp.dot(hi.astype(jnp.bfloat16).T, lo.astype(jnp.bfloat16),
+                      preferred_element_type=jnp.float32)
+        return acc + got.astype(jnp.int32), None
+
+    acc, _ = lax.scan(one, jnp.zeros((h, 128), jnp.int32),
+                      jnp.arange(n_shards * per_shard, dtype=jnp.int32))
+    return acc.reshape(-1)
+
+
+@counted_jit("sparse", static_argnames=("n_slots",))
+def pairs_count(pairs: jax.Array, src: jax.Array, n_slots: int) -> jax.Array:
+    """|row ∩ src| for every row of a pairs entry, summed over shards on
+    the device: one launch and one fetch of n_slots int32 a TopN."""
+    return pairs_count_local(pairs, src, n_slots)
+
+
 def sparse_from_columns(columns: np.ndarray, slots: int) -> np.ndarray:
     """Host-side builder: sorted shard-local offsets -> one padded sparse
     row int32[slots] (the dense_from_columns analog)."""

@@ -77,6 +77,24 @@ def tanimoto_counts_packed(rows: jax.Array, src: jax.Array) -> jax.Array:
 
 
 @counted_jit("topn")
+def leaves_counts_packed(leaves: tuple, src: jax.Array) -> jax.Array:
+    """tanimoto_counts_packed over row leaves as they lie in residency: a
+    tuple of [S, W] planes and the filter plane [S, W] -> int32[3, R],
+    summed over shards. Nothing is stacked: a slab of the leaves is a copy
+    of every plane a request (and `jnp.stack` outside a program two more,
+    one reshape and one broadcast a leaf), alive until the device gets to
+    it, and sixteen requests' worth of them was most of a chip's memory;
+    here every plane is read once where it lies."""
+    def total(x):
+        return jnp.sum(popcount(x))
+
+    inter = jnp.stack([total(jnp.bitwise_and(l, src)) for l in leaves])
+    rcounts = jnp.stack([total(l) for l in leaves])
+    return jnp.stack(
+        [inter, rcounts, jnp.broadcast_to(total(src), inter.shape)], axis=0)
+
+
+@counted_jit("topn")
 def tanimoto_mask(inter: jax.Array, rcounts: jax.Array, scount: jax.Array,
                   threshold: jax.Array) -> jax.Array:
     """Boolean keep-mask: 100·inter > threshold·(rcounts + scount − inter).

@@ -28,10 +28,13 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from pilosa_tpu.ops.bitvector import (
+    SPARSE_SENTINEL,
     chunk_count_matrix,
     groupby_chunk_live,
     groupby_chunk_matrix,
     live_from_matrix,
+    pairs_count,
+    pairs_count_local,
     popcount,
 )
 from pilosa_tpu.analysis import lockwitness
@@ -495,6 +498,29 @@ def groupby_chunk_matrix_mesh(mesh: Mesh, axis_slabs: tuple, idx: tuple,
         tuple(axis_slabs), tuple(idx), axis, n_valid)
 
 
+# -- TopN recount from sorted columns, mesh form ------------------------------
+
+
+@functools.lru_cache(maxsize=None)
+def _pairs_count_mesh_fn(mesh: Mesh, n_slots: int, by_column: bool):
+    """Per-mesh, per-size shard_map program of ops/bitvector.pairs_count:
+    every device counts over its own shard slots (the entry, in either
+    layout, and the filter are both sharded on the shard axis), one psum
+    on the interconnect, the count vector replicated."""
+    entry = P(SHARD_AXIS, None) if by_column else P(None, SHARD_AXIS, None)
+
+    @jax.jit
+    @functools.partial(
+        jax.shard_map, mesh=mesh,
+        in_specs=(entry, P(SHARD_AXIS, None)),
+        out_specs=P(), check_vma=False)
+    def pairs_count_mesh(pairs, src):
+        return jax.lax.psum(pairs_count_local(pairs, src, n_slots),
+                            SHARD_AXIS)
+
+    return pairs_count_mesh
+
+
 class DeviceRunner:
     """Executes shard-slab programs, optionally over a mesh.
 
@@ -573,6 +599,29 @@ class DeviceRunner:
         sparse leaves [S, K] place the same way (axis 0 shards) with
         `fill` set to the sparse sentinel."""
         return self._put_shard_padded(rows, 0, fill=fill)
+
+    def put_pairs(self, pairs: np.ndarray) -> jax.Array:
+        """Place one pairs entry (ops/bitvector.py), by pairs int32[2, S, K]
+        or by column int32[S, 2^20]: the shard axis padded with what reads
+        as no bit (the sparse sentinel, no rank) and sharded like a
+        leaf's."""
+        if pairs.ndim == 2:
+            return self._put_shard_padded(pairs, 0, fill=-1)
+        return self._put_shard_padded(pairs, 1, fill=SPARSE_SENTINEL)
+
+    def pairs_count(self, pairs: jax.Array, src: jax.Array,
+                    n_slots: int) -> jax.Array:
+        """int32[n_slots] device counts of a pairs entry's rows under the
+        filter plane `src` [S', W], launched and not fetched. With a mesh
+        the explicit shard_map + psum form, never the jit form on sharded
+        operands (whose GSPMD all-reduce deadlocks under concurrent
+        request threads)."""
+        if self.mesh is not None:
+            record_dispatch("ici_program", self.mesh, "pairs", n_slots,
+                            pairs, src)
+            return _pairs_count_mesh_fn(self.mesh, n_slots,
+                                        pairs.ndim == 2)(pairs, src)
+        return pairs_count(pairs, src, n_slots)
 
     def put_plane_slab(self, planes: np.ndarray) -> jax.Array:
         """Place a [depth, S, W] BSI plane slab on device(s), shard-axis

@@ -47,6 +47,7 @@ class DeviceResidency:
         self.misses = 0
         self.evictions = 0
         self.epoch = 0  # bumped by clear(); fences in-flight misses
+        self._building: dict = {}  # key -> Event of the thread building it
         # fragment heat map (utils/heat.py HeatTracker, set by the
         # Executor; None = untracked): uploads/evictions and h2d reload
         # bytes are charged per fragment coordinate, and `eviction =
@@ -68,23 +69,47 @@ class DeviceResidency:
         `make()` may return a host array (uploaded via the runner) or a
         jax.Array already composed on device (e.g. a BSI comparison mask) —
         the latter is cached as-is, avoiding a device->host->device round
-        trip. `put`, when given, replaces the runner's default placement
-        for host arrays (sparse hybrid leaves pad with the sentinel, not
-        zero — parallel/mesh.py put_leaf's fill parameter)."""
+        trip. What is cached needs only `nbytes` (a pairs entry is an
+        object around its device array). `put`, when given, replaces the
+        runner's default placement for host arrays (sparse hybrid leaves
+        pad with the sentinel, not zero — parallel/mesh.py put_leaf's fill
+        parameter)."""
         prof = qprofile.current_profile.get()  # None = profiling off
-        with self._lock:
-            arr = self._lru.get(key)
+        while True:
+            with self._lock:
+                arr = self._lru.get(key)
+                building = None
+                if arr is not None:
+                    self._lru.move_to_end(key)
+                    self.hits += 1
+                else:
+                    # single-flight: one thread builds a missing key, the
+                    # others wait for it and then find it resident (a
+                    # plane takes milliseconds to build and a field's
+                    # pairs entry seconds; thirty request threads used to
+                    # build the same one at once)
+                    building = self._building.get(key)
+                    if building is None:
+                        self._building[key] = threading.Event()
+                epoch = self.epoch
             if arr is not None:
-                self._lru.move_to_end(key)
-                self.hits += 1
-            epoch = self.epoch
-        if arr is not None:
-            # recorded OUTSIDE the LRU lock: the hit path is the hottest
-            # section in here and must not also serialize on the
-            # profile's own lock while holding it
-            if prof is not None:
-                prof.record_residency(hit=True)
-            return arr
+                # recorded OUTSIDE the LRU lock: the hit path is the
+                # hottest section in here and must not also serialize on
+                # the profile's own lock while holding it
+                if prof is not None:
+                    prof.record_residency(hit=True)
+                return arr
+            if building is None:
+                break
+            building.wait()  # then look again: resident, or ours to build
+        try:
+            return self._build(key, make, put, prof, epoch)
+        finally:
+            with self._lock:
+                self._building.pop(key).set()
+
+    def _build(self, key: tuple, make, put, prof, epoch: int):
+        """The miss path of leaf(): build, upload, account, insert."""
         with tracing.span("leaf.build"):
             host = make()
         uploaded = not isinstance(host, jax.Array)
@@ -124,7 +149,7 @@ class DeviceResidency:
                 # but never cache it, or a recreated field reaching an
                 # identical generation tuple could read deleted data
                 return arr
-            # concurrent HTTP threads can race the same miss: account for
+            # patch_entries can have put this key meanwhile: account for
             # the entry this insert displaces or bytes drift upward forever
             displaced = self._lru.pop(key, None)
             if displaced is not None:

@@ -579,59 +579,16 @@ class Fragment:
                 total += c.n
         return total
 
-    def rows_intersection_counts(self, row_ids,
-                                 src_cols: np.ndarray):
-        """Batched |row ∩ src| for many rows against a sorted shard-local
-        column set — pure array math over the frozen store's flat layout
-        (one gather + one searchsorted + one segment sum for ALL rows).
-        This is what makes similarity search (TopN with a Src row,
-        fragment.go:1090 opt.Src.intersectionCount per candidate) linear
-        in the candidates' STORED bits instead of candidates × dense
-        shard width. Returns int64[len(row_ids)], or None when this
-        fragment cannot take the vectorized path (mutable store, or
-        candidate rows touched by the COW overlay) — caller falls back
-        to the dense device walk."""
-        store = self.storage.containers
-        if not getattr(store, "VECTORIZED_STORE", False):
-            return None
-        kpr = CONTAINERS_PER_SHARD
-        rids = np.asarray(row_ids, dtype=np.int64)
-        if src_cols.size == 0:  # src empty in this shard: all zeros
-            return np.zeros(rids.size, dtype=np.int64)
-        if store._overlay or store._deleted:
-            touched = {k // kpr for k in store._overlay} | \
-                      {k // kpr for k in store._deleted}
-            if touched.intersection(rids.tolist()):
-                return None
-        keys, starts, ends = store._keys, store._starts, store._ends
-        lo = np.searchsorted(keys, rids * kpr)
-        hi = np.searchsorted(keys, (rids + 1) * kpr)
-        n_conts = hi - lo  # containers per row
-        if int(n_conts.sum()) == 0:
-            return np.zeros(rids.size, dtype=np.int64)
-        # container-level expansion: index of every container of every row
-        cont_idx = (np.arange(int(n_conts.sum()), dtype=np.int64)
-                    + np.repeat(lo - np.concatenate(
-                        [[0], np.cumsum(n_conts)[:-1]]), n_conts))
-        cont_row = np.repeat(np.arange(rids.size), n_conts)
-        # element-level expansion of those containers' value slices
-        c_starts = starts[cont_idx]
-        c_lens = (ends - starts)[cont_idx]
-        total = int(c_lens.sum())
-        if total == 0:
-            return np.zeros(rids.size, dtype=np.int64)
-        elem_idx = (np.arange(total, dtype=np.int64)
-                    + np.repeat(c_starts - np.concatenate(
-                        [[0], np.cumsum(c_lens)[:-1]]), c_lens))
-        elem_row = np.repeat(cont_row, c_lens)
-        # shard-local column of each element: (key % kpr) << 16 | low
-        cols = (((keys[cont_idx] % kpr) << 16).repeat(c_lens)
-                | store._lows[elem_idx].astype(np.int64))
-        pos = np.searchsorted(src_cols, cols)
-        pos_c = np.minimum(pos, max(src_cols.size - 1, 0))
-        member = (src_cols.size > 0) & (src_cols[pos_c] == cols)
-        return np.bincount(elem_row, weights=member,
-                           minlength=rids.size).astype(np.int64)
+    def rows_columns(self) -> tuple:
+        """(rows, cols) int32 arrays of every set bit, in (row, column)
+        order, cols shard-local. One pass over the store's sorted
+        positions — flat arrays on a frozen store, a concatenation of
+        containers otherwise — not one walk a row: what a field's pairs
+        entry (executor._pairs_entry) is built from."""
+        pos = self.storage.positions()
+        shift = np.uint64(SHARD_WIDTH.bit_length() - 1)
+        return ((pos >> shift).astype(np.int32),
+                (pos & np.uint64(SHARD_WIDTH - 1)).astype(np.int32))
 
     @staticmethod
     def _frozen_row_arrays(store, kpr: int):
@@ -1012,9 +969,21 @@ class Fragment:
     @_locked
     def import_roaring(self, data: bytes, clear: bool = False) -> None:
         """Union (or clear) a pre-built roaring bitmap into storage in one op
-        (importRoaring, fragment.go:1659-1706)."""
-        other = Bitmap.from_bytes(data)
-        if clear:
+        (importRoaring, fragment.go:1659-1706). The first import into an
+        empty fragment costs no Python per container: the payload is
+        parsed with numpy into the flat store (Bitmap.flat_store_from_bytes)
+        and the snapshot below is written from its arrays, with the same
+        durability (answered after the snapshot, read back on restart,
+        later Sets to the WAL). Later imports and clear=true keep the
+        container path."""
+        flat = None
+        if not clear and not self.storage.any():
+            flat = Bitmap.flat_store_from_bytes(data)
+        if flat is not None:
+            # the Bitmap object stays, and with it the writer state
+            self.storage.containers = flat
+        elif clear:
+            other = Bitmap.from_bytes(data)
             store = self.storage.containers
             if getattr(store, "VECTORIZED_STORE", False):
                 # frozen storage: difference() would materialize + copy
@@ -1041,7 +1010,7 @@ class Fragment:
             # k-way in-place merge — the import hot path (fragment.go:1670
             # unions the incoming bitmap straight into storage); writer
             # state (including a frozen load's detached WAL) is preserved
-            self.storage.union_in_place(other)
+            self.storage.union_in_place(Bitmap.from_bytes(data))
         self.generation += 1
         self._row_gen.clear()  # all rows considered dirty
         self._bulk_gen = self.generation

@@ -1088,6 +1088,34 @@ class Bitmap:
         return cls._replay_ops(b, data, ops_offset, recover=recover_wal,
                                verify=verify)
 
+    @staticmethod
+    def flat_store_from_bytes(data):
+        """A Pilosa-format payload as the flat array-backed store
+        (storage/frozen.py), parsed with numpy: header and array payloads
+        as views of `data`, bitmap and run containers one by one into the
+        store's overlay. None where this parser is not the one to use —
+        another format, an empty bitmap, bytes after the containers (an op
+        log to replay), anything malformed: the caller then takes the
+        container path, which also says what is wrong. What a bulk import
+        into an empty fragment is parsed by (Fragment.import_roaring): a
+        field of 10,000 rows is 150,000 array containers of six or seven
+        values a shard, and one Python object each was 9.6 s a shard."""
+        from pilosa_tpu.storage.frozen import parse_pilosa_frozen
+
+        if len(data) < HEADER_BASE_SIZE:
+            return None
+        magic, version, key_n = struct.unpack_from("<HHI", data, 0)
+        off_off = HEADER_BASE_SIZE + key_n * 12
+        if (magic != MAGIC_NUMBER or version != STORAGE_VERSION
+                or key_n == 0 or off_off + key_n * 4 > len(data)):
+            return None
+        try:
+            store, end = parse_pilosa_frozen(data, key_n, HEADER_BASE_SIZE,
+                                             off_off)
+        except ValueError:
+            return None
+        return store if end == len(data) else None
+
     @classmethod
     def _verify_trailer(cls, data, ops_offset: int,
                         verify: bool = True) -> int:

@@ -154,6 +154,9 @@ def leaf_frag_keys(key) -> list[tuple]:
             _, index, field, _depth, shards, _gens = key[:6]
             return [(index, field, _BSI_VIEW_PREFIX + field, int(s))
                     for s in shards]
+        if kind == "pairs" and len(key) >= 6:
+            _, index, field, view, shards, _gens = key[:6]
+            return [(index, field, view, int(s)) for s in shards]
         if kind == "rows_slab" and len(key) >= 7:
             _, index, field, view, shards, _rows, _gens = key[:7]
             return [(index, field, view, int(s)) for s in shards]

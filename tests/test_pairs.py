@@ -1,0 +1,297 @@
+"""The recount of a field's small rows from their sorted columns: the
+kernel (ops/bitvector.py pairs_count, its shard_map form in
+parallel/mesh.py) against a numpy oracle, and the resident entry
+(executor._pairs_entry) on a field of 10,000 rows: no plane stacked for a
+row below the sparse threshold, one build however many threads ask, and
+the cases that fall to the dense walk whole (the hybrid representation
+off; an entry that would not fit a quarter of the residency budget)."""
+
+import threading
+
+import jax
+import numpy as np
+import pytest
+
+from pilosa_tpu.constants import SHARD_WIDTH
+from pilosa_tpu.executor import Executor
+from pilosa_tpu.models import FieldOptions, Holder
+from pilosa_tpu.ops import bitvector as bv
+from pilosa_tpu.parallel.mesh import DeviceRunner, make_mesh
+
+W = SHARD_WIDTH // 32
+
+# ------------------------------------------------------------------ kernel
+
+
+def entry_of(rows_by_shard: list, n_rows: int, slots: int) -> np.ndarray:
+    """[{rank: sorted columns}] a shard -> int32[2, S, slots], as
+    executor._pairs_entry lays it out."""
+    arr = np.full((2, len(rows_by_shard), slots), bv.SPARSE_SENTINEL,
+                  np.int32)
+    for s, rows in enumerate(rows_by_shard):
+        at = 0
+        for rank in sorted(rows):
+            cols = np.sort(np.asarray(rows[rank], np.int32))
+            arr[0, s, at:at + cols.size] = cols
+            arr[1, s, at:at + cols.size] = rank
+            at += cols.size
+    return arr
+
+
+def oracle(rows_by_shard: list, src: np.ndarray, n_rows: int) -> np.ndarray:
+    out = np.zeros(n_rows, np.int64)
+    for s, rows in enumerate(rows_by_shard):
+        for rank, cols in rows.items():
+            cols = np.asarray(cols, np.int64)
+            out[rank] += int(((src[s][cols >> 5] >> (cols & 31)) & 1).sum())
+    return out
+
+
+def random_entry(seed, n_shards, n_rows, slots, empty_shards=()):
+    rng = np.random.default_rng(seed)
+    shards = []
+    for s in range(n_shards):
+        rows = {}
+        if s not in empty_shards:
+            left = slots - 2
+            for rank in rng.permutation(n_rows):
+                # ragged: most rows a few bits, some none, one or two many
+                n = int(min(left, rng.choice([0, 1, 3, 17, 200])))
+                if n:
+                    rows[int(rank)] = rng.choice(SHARD_WIDTH, n,
+                                                 replace=False)
+                    left -= n
+        shards.append(rows)
+    # the last column of a shard, in the filter and in a row
+    shards[0].setdefault(0, np.empty(0, np.int64))
+    shards[0][0] = np.union1d(shards[0][0][:5], [SHARD_WIDTH - 1, 0])
+    src = rng.integers(0, 1 << 32, size=(n_shards, W), dtype=np.uint32)
+    src[0, -1] |= np.uint32(1 << 31)
+    return shards, src
+
+
+KERNEL_CASES = [
+    # shards, rows, slots, shards left all-pad
+    (1, 5, 8, ()),
+    (2, 130, 1 << 11, ()),
+    (3, 700, 1 << 13, (1,)),
+    (4, 300, 1 << 16, (0, 3)),          # more than one histogram step
+    (2, 9966, 1 << 14, ()),             # a grid field's count vector
+]
+
+
+@pytest.mark.parametrize("n_shards,n_rows,slots,empty", KERNEL_CASES)
+def test_pairs_count_equals_oracle(n_shards, n_rows, slots, empty):
+    shards, src = random_entry(n_rows, n_shards, n_rows, slots, empty)
+    pairs = entry_of(shards, n_rows, slots)
+    n_slots = bv.pairs_count_slots(n_rows)
+    assert n_slots >= n_rows and n_slots % 128 == 0
+    got = np.asarray(bv.pairs_count(pairs, src, n_slots))
+    want = oracle(shards, src, n_rows)
+    assert (got[:n_rows] == want).all()
+    assert not got[n_rows:].any()
+    assert want[0] >= 1  # column 2^20 - 1 counted
+
+
+def column_entry(seed, n_shards, n_rows, fill, empty_shards=()):
+    """An entry whose columns hold at most one row each, in both layouts:
+    ([{rank: columns}] a shard, int32[S, 2^20] of rank or -1, the filter)."""
+    rng = np.random.default_rng(seed)
+    shards = []
+    by_col = np.full((n_shards, SHARD_WIDTH), -1, np.int32)
+    for s in range(n_shards):
+        rows: dict = {}
+        if s not in empty_shards:
+            cols = rng.permutation(SHARD_WIDTH)[:int(fill * SHARD_WIDTH)]
+            cols = np.union1d(cols, [0, SHARD_WIDTH - 1])
+            rank = rng.integers(0, n_rows, cols.size)
+            rank[-1] = 0                      # the last column, in row 0
+            by_col[s, cols] = rank
+            rows = {int(r): cols[rank == r] for r in np.unique(rank)}
+        shards.append(rows)
+    src = rng.integers(0, 1 << 32, size=(n_shards, W), dtype=np.uint32)
+    src[0, -1] |= np.uint32(1 << 31)
+    return shards, by_col, src
+
+
+@pytest.mark.parametrize("n_shards,n_rows,fill,empty", [
+    (1, 3, 0.001, ()), (2, 9966, 0.7, ()), (3, 500, 0.2, (0, 2))])
+def test_pairs_count_by_column_equals_oracle(n_shards, n_rows, fill, empty):
+    shards, by_col, src = column_entry(n_rows, n_shards, n_rows, fill, empty)
+    n_slots = bv.pairs_count_slots(n_rows)
+    got = np.asarray(bv.pairs_count(by_col, src, n_slots))
+    want = oracle(shards, src, n_rows)
+    assert (got[:n_rows] == want).all() and not got[n_rows:].any()
+    if 0 not in empty:
+        assert want[0] >= 1  # column 2^20 - 1 counted
+        # and the two layouts of one entry count alike
+        slots = 8
+        while slots < max(sum(c.size for c in r.values()) for r in shards):
+            slots *= 2
+        pairs = entry_of(shards, n_rows, slots)
+        assert (np.asarray(bv.pairs_count(pairs, src, n_slots)) == got).all()
+
+
+def test_all_pad_entry_counts_nothing():
+    pairs = np.full((2, 2, 8), bv.SPARSE_SENTINEL, np.int32)
+    src = np.full((2, W), 0xFFFFFFFF, np.uint32)
+    assert not np.asarray(bv.pairs_count(pairs, src, 128)).any()
+
+
+@pytest.mark.parametrize("n_devices,n_shards", [(4, 4), (4, 6), (8, 3)])
+def test_mesh_form_agrees(n_devices, n_shards):
+    """The shard_map + psum form on the CPU's forced devices: the entry
+    and the filter padded to the mesh and sharded on the shard axis, the
+    counts the single-device kernel's."""
+    mesh = make_mesh(jax.devices()[:n_devices])
+    runner = DeviceRunner(mesh)
+    shards, src = random_entry(7 + n_shards, n_shards, 300, 1 << 12)
+    pairs = entry_of(shards, 300, 1 << 12)
+    n_slots = bv.pairs_count_slots(300)
+    dev_pairs, dev_src = runner.put_pairs(pairs), runner.put_leaf(src)
+    assert dev_pairs.shape[1] % n_devices == 0
+    got = np.asarray(runner.pairs_count(dev_pairs, dev_src, n_slots))
+    assert (got[:300] == oracle(shards, src, 300)).all()
+    single = np.asarray(DeviceRunner().pairs_count(pairs, src, n_slots))
+    assert (got == single).all()
+    # by column: sharded on its first axis, pad shards hold no rank
+    shards, by_col, src = column_entry(n_shards, n_shards, 300, 0.05)
+    dev = runner.put_pairs(by_col)
+    assert dev.shape[0] % n_devices == 0
+    got = np.asarray(runner.pairs_count(dev, runner.put_leaf(src), n_slots))
+    assert (got[:300] == oracle(shards, src, 300)).all()
+
+
+# ------------------------------------------------------- the resident entry
+
+N_ROWS = 10_000
+N_DENSE = 3
+
+
+@pytest.fixture(scope="module")
+def wide(tmp_path_factory):
+    """One field of 10,000 rows over 2 shards: three above the sparse
+    threshold, the others 3 to 40 bits a shard; a filter field of one row
+    holding every third column."""
+    rng = np.random.default_rng(2929)
+    h = Holder(str(tmp_path_factory.mktemp("wide") / "d")).open()
+    idx = h.create_index("w", track_existence=False)
+    sets: dict = {}
+    rows_l, cols_l = [], []
+    for r in range(N_ROWS):
+        n = 9000 if r < N_DENSE else int(rng.integers(6, 80))
+        c = np.unique(rng.integers(0, 2 * SHARD_WIDTH, n))
+        sets[r] = c
+        rows_l.append(np.full(c.size, r, np.uint64))
+        cols_l.append(c.astype(np.uint64))
+    g = idx.create_field("g", FieldOptions(cache_size=50000))
+    g.import_bits(np.concatenate(rows_l), np.concatenate(cols_l))
+    filt = np.arange(0, 2 * SHARD_WIDTH, 3, dtype=np.uint64)
+    idx.create_field("f").import_bits(np.zeros(filt.size, np.uint64), filt)
+    want = sorted(((int((sets[r] % 3 == 0).sum()), -r)
+                   for r in range(N_ROWS)), reverse=True)
+    try:
+        yield h, [(-nr, c) for c, nr in want if c > 0]
+    finally:
+        h.close()
+
+
+def test_no_plane_for_a_row_below_the_threshold(wide):
+    h, want = wide
+    ex = Executor(h)
+    # n above the dense rows' reach: the prune cannot stop before the
+    # small rows, whose cached counts (6-80) are all above the 25th best
+    (got,) = ex.execute("w", "TopN(g, Row(f=0), n=25)")
+    assert [tuple(p) for p in got] == want[:25]
+    assert ex.topn_recount_rows == N_DENSE
+    assert ex.topn_pairs_recounts == 1
+    kinds = ex.residency.snapshot()["by_kind"]
+    assert kinds["pairs"]["entries"] == 1
+    assert kinds["row"]["entries"] == N_DENSE + 1       # and the filter
+    # rows that share columns: the entry is pairs, 2 x 4 B a stored bit
+    # rounded up to a power of two, not one rank a column
+    assert kinds["pairs"]["bytes"] == 2 * 2 * (1 << 18) * 4
+    # 4 bytes a stored bit of the rows recounted, 128 KiB a shard
+    stored = sum(int(np.unique(ex.holder.index("w").field("g").view(
+        "standard").fragment(s).rows_columns()[0], return_counts=True
+    )[1][N_DENSE:].sum()) for s in range(2))
+    assert ex.topn_pairs_bytes == 4 * stored + 2 * W * 4
+    # where the n-th best beats every small row's cached count, no launch
+    (got,) = ex.execute("w", "TopN(g, Row(f=0), n=2)")
+    assert [tuple(p) for p in got] == want[:2]
+    assert ex.topn_pairs_recounts == 1
+
+
+def test_an_attribute_field_lies_by_column(tmp_path):
+    """One value a column, 10,000 values, seven columns in ten filled: no
+    column holds two rows and the sorted columns would take 2^20 slots a
+    shard, so the entry is one rank a column (4 MiB a shard, half the
+    pairs) and the recount gathers nothing; same Pairs as brute force."""
+    rng = np.random.default_rng(31)
+    h = Holder(str(tmp_path / "d")).open()
+    try:
+        idx = h.create_index("a", track_existence=False)
+        cols = np.flatnonzero(rng.random(2 * SHARD_WIDTH) < 0.7)
+        vals = rng.integers(0, N_ROWS, cols.size)
+        vals[:9000] = 7                       # one row above the threshold
+        idx.create_field("g", FieldOptions(cache_size=50000)).import_bits(
+            vals.astype(np.uint64), cols.astype(np.uint64))
+        filt = np.arange(0, 2 * SHARD_WIDTH, 5, dtype=np.uint64)
+        idx.create_field("f").import_bits(np.zeros(filt.size, np.uint64),
+                                          filt)
+        ex = Executor(h)
+        (got,) = ex.execute("a", "TopN(g, Row(f=0), n=40)")
+        under = np.bincount(vals[cols % 5 == 0], minlength=N_ROWS)
+        want = sorted(((int(c), -r) for r, c in enumerate(under) if c),
+                      reverse=True)[:40]
+        assert [tuple(p) for p in got] == [(-nr, c) for c, nr in want]
+        assert ex.topn_recount_rows == 1 and ex.topn_pairs_recounts == 1
+        (key,) = [k for k, _ in ex.residency.entries_snapshot()
+                  if k[0] == "pairs"]
+        assert ex.residency.peek(key).dev.shape == (2, SHARD_WIDTH)
+    finally:
+        h.close()
+
+
+def test_sixteen_threads_build_the_entry_once(wide):
+    h, want = wide
+    ex = Executor(h)
+    out, errs = [None] * 16, []
+    gate = threading.Barrier(16)
+
+    def one(i):
+        try:
+            gate.wait()
+            (got,) = ex.execute("w", f"TopN(g, Row(f=0), n={30 + i})")
+            out[i] = [tuple(p) for p in got]
+        except Exception as e:  # noqa: BLE001
+            errs.append(e)
+
+    threads = [threading.Thread(target=one, args=(i,)) for i in range(16)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert not errs, errs
+    assert all(out[i] == want[:30 + i] for i in range(16))
+    assert ex.pairs_entries_built == 1
+    assert ex.residency.snapshot()["by_kind"]["pairs"]["entries"] == 1
+    # and the three dense rows and the filter were each uploaded once
+    assert ex.residency.misses == N_DENSE + 2
+
+
+@pytest.mark.parametrize("why", ["hybrid-off", "entry-over-a-quarter"])
+def test_cases_that_fall_to_the_dense_walk(wide, why):
+    """Same answer, every candidate through stacked planes."""
+    h, want = wide
+    ex = Executor(h)
+    if why == "hybrid-off":
+        ex.hybrid.threshold = 0
+    else:
+        ex.residency.budget = 1 << 20     # the entry alone is 4 MiB
+    (got,) = ex.execute("w", "TopN(g, Row(f=0), ids=[0, 1, 5000, 9999])")
+    by_id = dict(want)
+    assert sorted(tuple(p) for p in got) == sorted(
+        (r, by_id[r]) for r in (0, 1, 5000, 9999) if r in by_id)
+    assert ex.topn_pairs_recounts == 0
+    assert ex.topn_recount_rows == 4

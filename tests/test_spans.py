@@ -205,6 +205,47 @@ def test_served_count_yields_one_tree(server, case, pql, rows, want):
         assert up.tags["rep"] == "sparse"
 
 
+# stage spans under the calls that are not Count, with Count's names
+OTHER_CALLS = [
+    # the filter's plan / leaves / dispatch, the rank caches' merge, then
+    # the recount: the dense rows 100-101 as stacked planes, rows 0-9 in
+    # one launch from the field's pairs entry (built on this first touch)
+    ("TopN", "TopN(f, Row(f=100), n=20)",
+     {"plan", "leaves", "dispatch", "device.wait", "reduce",
+      "topn.candidates", "topn.recount", "leaf.build", "pairs.build"},
+     "topn.recount"),
+    ("GroupBy", "GroupBy(Rows(field=f), filter=Row(f=101))",
+     {"plan", "leaves", "dispatch", "device.wait", "reduce"}, None),
+]
+
+
+@pytest.mark.parametrize("call,pql,want,recount", OTHER_CALLS,
+                         ids=[c[0] for c in OTHER_CALLS])
+def test_served_topn_and_groupby_have_stage_spans(server, call, pql, want,
+                                                  recount):
+    trace_id = f"spans-{call}"
+    out = post(server.uri, "/index/i/query", pql.encode(),
+               headers={tracing.TRACE_HEADER: trace_id})
+    assert out["results"][0]
+    spans = tree_of(server, trace_id)
+    names = {sp.name for sp in spans}
+    assert want <= names, want - names
+    root = next(sp for sp in spans if sp.parent is None)
+    assert abs(sum(sp.self_ms for sp in spans) - root.ms) < 1.0
+    top = next(sp for sp in spans if sp.name == f"executor.{call}")
+    for sp in spans:
+        if sp.name in ("dispatch", "device.wait"):
+            # under the call itself or under its recount, never loose
+            assert sp.parent is top or sp.parent.name == recount
+    if recount:
+        walk = next(sp for sp in spans if sp.name == recount)
+        assert walk.parent is top and walk.tags["field"] == "f"
+        build = next(sp for sp in spans if sp.name == "pairs.build")
+        assert build.parent.name == "leaf.build"
+        assert sum(sp.launches for sp in spans
+                   if sp.name == "dispatch" and sp.parent is walk) >= 2
+
+
 def test_non_work_routes_are_not_http_request(server):
     before = tracing.spans.snapshot()["byName"]
     get(server.uri, "/status")
