@@ -1,9 +1,10 @@
 #!/usr/bin/env python3
 """Timings that chose the kernel of the TopN recount from sorted columns
-(PR 29; PERF.md section 6 has the numbers). One resident entry of a field
-is, per shard, the concatenated sorted columns of its small rows with the
-row's rank beside each; the recount is counts[R] = sum over the entry's
-bits of the filter plane's bit at that column. Variants timed here:
+(PR 29 the layouts, PR 30 the sum by rank; PERF.md section 6 has the
+numbers). One resident entry of a field is, per shard, the concatenated
+sorted columns of its small rows with the row's rank beside each; the
+recount is counts[R] = sum over the entry's bits of the filter plane's bit
+at that column. Variants timed here:
 
   gather            the bit test alone: take_along_axis of the filter's
                     word at every stored column (what every gather variant
@@ -12,23 +13,40 @@ bits of the filter plane's bit at that column. Variants timed here:
   gather+cumsum     bit test, a cumsum along the entry and a gather of the
                     running sum at every row's end (ranks are sorted)
   gather+onehot     bit test, then the histogram by rank as a product of
-                    two one-hot matrices on the matrix unit
+                    two one-hot matrices on the matrix unit, the slot index
+                    on the major axis of both ([step, H] and [step, 128])
   bycolumn+onehot   no gather: the entry laid out by column (one rank a
                     column, -1 where none), the filter's bits unpacked in
                     place, the same one-hot product. Only a field whose
                     columns hold at most one row of the entry allows it
+  slot-major one-hot (PR 29), by pairs / by column
+                    what PR 29 shipped: bit test and slot-major product
+                    inside one scan step of 2^15 slots, the entry by
+                    column in column order (int32[S, 2^20], bit minor)
+  lane-major one-hot, XLA
+                    PR 30's step: the entry by column laid bit-major
+                    (int32[1, S, 32, W]), the one-hots transposed ([H,
+                    slots] and [128, slots], slots on the lane axis), the
+                    product contracting the last axis of both, left to XLA
+                    in a scan (--steps sweeps the slots a step)
+  lane-major one-hot, Pallas
+                    the same step written as a Pallas kernel: both
+                    operands made and consumed in VMEM, one accumulator
+                    (--lanes sweeps its tile, --explore other shapes);
+                    tried in PR 30, no faster than what XLA makes of the
+                    scan, and not shipped
   shipped           ops/bitvector.py pairs_count as the executor calls it,
-                    on the entry by pairs (gather+onehot) and by column:
-                    both inside one scan step, so that nothing of the
-                    entry's size is materialized beside it
+                    by pairs and by column; with the bytes of temporaries
+                    the compiled program allocates
 
     chiprun -- python3 benches/recount_kernels.py            # the chip
-    python3 benches/recount_kernels.py --shards 2 --slots 4096 --rows 300
+    python3 benches/recount_kernels.py --shards 2 --slots 8192 --rows 300
 """
 
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -82,6 +100,15 @@ def main():
     ap.add_argument("--slots", type=int, default=1 << 20)
     ap.add_argument("--rows", type=int, default=9966)
     ap.add_argument("--reps", type=int, default=5)
+    ap.add_argument("--steps", type=int, nargs="*", default=[],
+                    help="slots a scan step of the XLA body, to time "
+                    "beside the shipped PAIRS_STEP")
+    ap.add_argument("--lanes", type=int, nargs="*", default=[1 << 10],
+                    help="tiles of the Pallas step to time")
+    ap.add_argument("--explore", action="store_true",
+                    help="also the shapes tried and not shipped")
+    ap.add_argument("--only", default="",
+                    help="time only the variants whose name holds this")
     args = ap.parse_args()
     import jax
     import jax.numpy as jnp
@@ -145,16 +172,146 @@ def main():
         return hist(jnp.maximum(bycol, 0).reshape(-1, chunk),
                     ok.reshape(-1, chunk))[:R]
 
+    from jax.experimental import pallas as pl
+
     from pilosa_tpu.ops import bitvector as bv
 
+    @jax.jit
+    def slot_major(pairs, src):
+        """PR 29's pairs_count_local, as it was."""
+        by_column = pairs.ndim == 2
+        k = pairs.shape[-1]
+        step = min(1 << 15, k)
+        per_shard = k // step
+        hi_ids = jnp.arange(H, dtype=jnp.int32)
+        lo_ids = jnp.arange(128, dtype=jnp.int32)
+        bit_ids = jnp.arange(32, dtype=jnp.uint32)
+
+        def one(acc, i):
+            s, at = i // per_shard, (i % per_shard) * step
+            if by_column:
+                r = jax.lax.dynamic_slice(pairs, (s, at), (1, step))[0]
+                words = jax.lax.dynamic_slice(src, (s, at // 32),
+                                              (1, step // 32))[0]
+                b = ((((words[:, None] >> bit_ids[None]) & 1) != 0)
+                     .reshape(step) & (r >= 0))
+            else:
+                blk = jax.lax.dynamic_slice(pairs, (0, s, at), (2, 1, step))
+                plane = jax.lax.dynamic_index_in_dim(src, s, axis=0,
+                                                     keepdims=False)
+                r = blk[1, 0]
+                b = bv._dense_bit_test(blk[0, 0], plane)
+            hi = ((r >> 7)[:, None] == hi_ids[None]) & b[:, None]
+            lo = (r & 127)[:, None] == lo_ids[None]
+            got = jnp.dot(hi.astype(jnp.bfloat16).T, lo.astype(jnp.bfloat16),
+                          preferred_element_type=jnp.float32)
+            return acc + got.astype(jnp.int32), None
+
+        acc, _ = jax.lax.scan(one, jnp.zeros((H, 128), jnp.int32),
+                              jnp.arange(S * per_shard, dtype=jnp.int32))
+        return acc.reshape(-1)[:R]
+
+    def rank_sum_kernel(hp, unpack, *refs):
+        """The step as a Pallas kernel, one grid step: r_ref int32[rows,
+        lanes], each row a lane vector of ranks (-1 counts nothing); with
+        `unpack`, row j counts only under bit j of w_ref uint32[1, lanes].
+        Both one-hots live and die in VMEM; out_ref int32[hp, 128] stays
+        there over the whole grid."""
+        r_ref, out_ref = refs[0], refs[-1]
+        rows, lanes = r_ref.shape
+        hi_ids = jax.lax.broadcasted_iota(jnp.int32, (hp, lanes), 0)
+        lo_ids = jax.lax.broadcasted_iota(jnp.int32, (128, lanes), 0)
+
+        def row(j, acc):
+            r = r_ref[pl.ds(j, 1), :]
+            if unpack:
+                bit = jax.lax.shift_right_logical(
+                    refs[1][...], jnp.full((1, lanes), j, jnp.uint32)) & 1
+                r = jnp.where(bit != 0, r, -1)
+            hi_t = (hi_ids == (r >> 7)).astype(jnp.bfloat16)
+            lo_t = (lo_ids == (r & 127)).astype(jnp.bfloat16)
+            return acc + jax.lax.dot_general(
+                hi_t, lo_t, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+        acc = jax.lax.fori_loop(0, rows, row,
+                                jnp.zeros((hp, 128), jnp.float32))
+
+        @pl.when((pl.program_id(0) == 0) & (pl.program_id(1) == 0))
+        def _init():
+            out_ref[...] = jnp.zeros_like(out_ref)
+
+        out_ref[...] += acc.astype(jnp.int32)
+
+    def rank_sum(ranks, words, h, lanes):
+        """int32[128 h] counts of ranks int32[B, rows, L]; grid (B, L /
+        lanes), one accumulator, written to HBM once."""
+        b, rows, length = ranks.shape
+        hp = -(-h // 16) * 16
+        a = [ranks]
+        specs = [pl.BlockSpec((None, rows, lanes), lambda i, j: (i, 0, j))]
+        if words is not None:
+            a.append(words.reshape(b, 1, length))
+            specs.append(
+                pl.BlockSpec((None, 1, lanes), lambda i, j: (i, 0, j)))
+        got = pl.pallas_call(
+            functools.partial(rank_sum_kernel, hp, words is not None),
+            grid=(b, length // lanes), in_specs=specs,
+            out_specs=pl.BlockSpec((hp, 128), lambda i, j: (0, 0)),
+            out_shape=jax.ShapeDtypeStruct((hp, 128), jnp.int32),
+            interpret=jax.default_backend() != "tpu")(*a)
+        return got[:h].reshape(-1)
+
+    def lane_major_pallas(h, lanes):
+        return jax.jit(lambda bitmajor, src: rank_sum(
+            bitmajor[0], src, h, min(lanes, W))[:R])
+
+    @jax.jit
+    def whole_gather_pallas(pairs, src):
+        r = jnp.where(bv._dense_bit_test(pairs[0], src), pairs[1], -1)
+        return rank_sum(r.reshape(-1, min(8, S), K), None, H,
+                        min(1 << 10, K))[:R]
+
+    @jax.jit
+    def gather_by_eight_pallas(pairs, src):
+        def one(acc, at):
+            blk = jax.lax.dynamic_slice(pairs, (0, at, 0), (2, 8, K))
+            planes = jax.lax.dynamic_slice(src, (at, 0), (8, W))
+            r = jnp.where(bv._dense_bit_test(blk[0], planes), blk[1], -1)
+            return acc + rank_sum(r[None], None, H, min(1 << 10, K)), None
+
+        acc, _ = jax.lax.scan(one, jnp.zeros(H * 128, jnp.int32),
+                              jnp.arange(0, S, 8, dtype=jnp.int32))
+        return acc[:R]
+
+    def lane_major_xla(step, n_slots):
+        """ops/bitvector.py's body traced under another PAIRS_STEP or
+        count vector (the global is read when the program is traced, at
+        the first call)."""
+        fn = jax.jit(lambda pairs, src: bv.pairs_count_local(
+            pairs, src, n_slots)[:R])
+
+        def call(pairs, src):
+            was, bv.PAIRS_STEP = bv.PAIRS_STEP, step
+            try:
+                return fn(pairs, src)
+            finally:
+                bv.PAIRS_STEP = was
+
+        return call
+
+    n_slots = bv.pairs_count_slots(R)
+
     def shipped(pairs, src):
-        return bv.pairs_count(pairs, src, bv.pairs_count_slots(R))[:R]
+        return bv.pairs_count(pairs, src, n_slots)[:R]
 
     cols, rank, bycol, ends, src = make(29, S, K, R, 0.73, 0.05)
     want = oracle(cols, rank, src, R)
+    kept = [(cols[s][cols[s] < C], rank[s][cols[s] < C]) for s in range(S)]
     dev = {k: jax.device_put(v) for k, v in dict(
         cols=cols, rank=rank, bycol=bycol, ends=ends, src=src,
-        pairs=np.stack([cols, rank])).items()}
+        pairs=np.stack([cols, rank]),
+        bitmajor=bv.pairs_by_column(kept)).items()}
     out = {"device": jax.devices()[0].device_kind, "shards": S, "slots": K,
            "rows": R, "bits": int((cols < C).sum())}
     runs = {
@@ -163,15 +320,45 @@ def main():
         "gather+cumsum": (gather_cumsum, ("cols", "ends", "src"), want),
         "gather+onehot": (gather_onehot, ("cols", "rank", "src"), want),
         "bycolumn+onehot": (bycolumn_onehot, ("bycol", "src"), want),
-        "shipped by pairs": (shipped, ("pairs", "src"), want),
-        "shipped by column": (shipped, ("bycol", "src"), want),
+        "slot-major one-hot (PR 29), by pairs": (
+            slot_major, ("pairs", "src"), want),
+        "slot-major one-hot (PR 29), by column": (
+            slot_major, ("bycol", "src"), want),
     }
+    for step in args.steps:
+        for layout in ("pairs", "column"):
+            runs[f"lane-major one-hot, XLA, by {layout}, step {step}"] = (
+                lane_major_xla(step, n_slots),
+                ("pairs" if layout == "pairs" else "bitmajor", "src"), want)
+    for lanes in args.lanes:
+        runs[f"lane-major one-hot, Pallas, by column, {lanes} lanes"] = (
+            lane_major_pallas(H, lanes), ("bitmajor", "src"), want)
+    if args.explore:
+        # H a multiple of 16 in place of a power of two; by pairs, the
+        # gather of the whole entry, or of eight shards a scan step,
+        # before the Pallas step
+        h16 = -(-R // 2048) * 16
+        runs[f"lane-major one-hot, XLA, by column, H = {h16}"] = (
+            lane_major_xla(bv.PAIRS_STEP, h16 * 128), ("bitmajor", "src"),
+            want)
+        runs[f"lane-major one-hot, Pallas, by column, H = {h16}"] = (
+            lane_major_pallas(h16, 1 << 10), ("bitmajor", "src"), want)
+        runs["whole gather, then Pallas"] = (
+            whole_gather_pallas, ("pairs", "src"), want)
+        if S % 8 == 0:
+            runs["gather by eight shards, then Pallas"] = (
+                gather_by_eight_pallas, ("pairs", "src"), want)
+    runs["shipped by pairs"] = (shipped, ("pairs", "src"), want)
+    runs["shipped by column"] = (shipped, ("bitmajor", "src"), want)
     for name, (fn, keys, check) in runs.items():
+        if args.only not in name:
+            continue
         a = [dev[k] for k in keys]
         t0 = time.perf_counter()
         got = np.asarray(fn(*a))
         first = time.perf_counter() - t0
         ok = None if check is None else bool((got == check).all())
+        np.asarray(fn(*a))     # a fresh Pallas binary's second run is slow too
         ts = []
         for _ in range(args.reps):
             t0 = time.perf_counter()
@@ -179,6 +366,10 @@ def main():
             ts.append((time.perf_counter() - t0) * 1e3)
         out[name] = {"ms_min": min(ts), "ms_median": sorted(ts)[len(ts) // 2],
                      "first_s": first, "agrees": ok}
+        if fn is shipped:
+            mem = bv.pairs_count.lower(*a, n_slots).compile(
+                ).memory_analysis()
+            out[name]["temp_bytes"] = mem.temp_size_in_bytes
         print(name, json.dumps(out[name]), flush=True)
     print(json.dumps(out))
 

@@ -73,8 +73,9 @@ class ExecutionError(ValueError):
 
 
 class PairsEntry:
-    """The small rows of one field, resident: `dev` int32[2, S', K] on the
-    device (ops/bitvector.py pairs_count), `ids` the rows in rank order
+    """The small rows of one field, resident: `dev` int32[2, S', K] or, by
+    column, int32[1, S', 32, W] on the device (ops/bitvector.py
+    pairs_count), `ids` the rows in rank order
     (ascending), `stored` the bits each holds over all shards. Residency
     charges it `nbytes`, like any leaf."""
 
@@ -2016,7 +2017,7 @@ class Executor:
         planner.choose_representation goes by; no hysteresis, the entry is
         rebuilt with its generations), as sorted columns with the row's
         rank beside each or, where no column holds two of the rows and
-        that is no larger, as one rank a column (ops/bitvector.py
+        that is no larger, as one rank a column, bit-major (ops/bitvector.py
         pairs_count takes either). One entry a
         (field, view, shard set, fragment generations), built once however
         many threads ask (DeviceResidency.leaf is single-flight), charged
@@ -2066,23 +2067,17 @@ class Executor:
                 slots = hyb.pad_slots(max(
                     [c.size for c, _ in kept] + [1]))
                 if len(shards) * slots * 8 > self.residency.budget // 4:
-                    ids, kept, slots = ids[:0], [], hyb.pad_slots(1)
+                    ids, slots = ids[:0], hyb.pad_slots(1)
+                    kept = [(np.empty(0, np.int32),) * 2] * len(shards)
                 # by column where the data allows it (no column holds two
                 # of the rows) and it is no larger than the pairs
                 by_column = 2 * slots >= SHARD_WIDTH and all(
                     np.bincount(cols, minlength=1).max(initial=0) <= 1
                     for cols, _ in kept)
-                arr = (np.full((len(shards), SHARD_WIDTH), -1, np.int32)
-                       if by_column else
-                       np.full((2, len(shards), slots), bv.SPARSE_SENTINEL,
-                               np.int32))
+                arr = (bv.pairs_by_column(kept) if by_column
+                       else bv.pairs_by_pairs(kept, slots))
                 stored = np.zeros(ids.size, np.int64)
-                for i, (cols, rank) in enumerate(kept):
-                    if by_column:
-                        arr[i, cols] = rank
-                    else:
-                        arr[0, i, :cols.size] = cols
-                        arr[1, i, :rank.size] = rank
+                for _, rank in kept:
                     stored += np.bincount(rank, minlength=ids.size)
                 built["ids"], built["stored"] = ids.astype(np.int64), stored
                 return arr

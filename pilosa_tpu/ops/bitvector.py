@@ -437,21 +437,32 @@ def sparse_to_dense(sp: jax.Array, n_words: int) -> jax.Array:
 # -- the small rows of one field, recounted under a filter in one launch -----
 # A `pairs` entry (executor._pairs_entry) holds every row of a field whose
 # fullest shard has no more bits than the sparse threshold, in one of two
-# layouts. By pairs, int32[2, S, K]: per shard the rows' concatenated
-# sorted columns (plane 0, sentinel-padded) and beside each the row's rank
-# in the entry's id list (plane 1). By column, int32[S, 2^20]: the rank of
-# the one row that holds the column, -1 where none does; only a field
-# whose columns hold at most one of those rows allows it (a record's
-# attribute: one value a column), and the executor takes it where it is no
-# larger than the pairs. A filtered TopN recounts all of the rows at once:
-# the filter plane's bit at every stored column, summed by rank — where
-# the dense walk stacks one [S, W] plane a candidate row whatever it holds.
-# By pairs the bit test is a gather (10 ns an element on the chip); by
-# column the filter's words are unpacked in place and nothing is gathered.
+# layouts, the shard axis second in both. By pairs, int32[2, S, K]: per
+# shard the rows' concatenated sorted columns (plane 0, sentinel-padded)
+# and beside each the row's rank in the entry's id list (plane 1). By
+# column, int32[1, S, 32, W], bit-major: at [0, s, b, w] the rank of the
+# one row that holds column 32 · w + b of shard s, -1 where none does;
+# only a field whose columns hold at most one of those rows allows it (a
+# record's attribute: one value a column), and the executor takes it
+# where it is no larger than the pairs. A filtered TopN recounts all of
+# the rows at once: the filter plane's bit at every stored column, summed
+# by rank — where the dense walk stacks one [S, W] plane a candidate row
+# whatever it holds. By pairs the bit test is a gather (10 ns an element
+# on the chip). By column nothing is gathered, and bit-major is what lets
+# the filter's words be unpacked where they lie: bit b of a tile of words,
+# `(words >> b) & 1`, is a lane vector that lines up with row b of the
+# entry's tile element for element, with no reshape across lanes (column
+# order, bit minor, needs a [tile, 32] -> [32 · tile] relayout a step).
 
-# entry slots one histogram step covers: its two one-hot operands are
-# [PAIRS_STEP, H] and [PAIRS_STEP, 128] bfloat16, 16 MiB at H = 128
-PAIRS_STEP = 1 << 15
+# entry slots one scan step covers, all of one shard: by column a tile of
+# 2,048 filter words and the [32, 2048] ranks under their bits, by pairs
+# 65,536 gathered slots. The step's two one-hot operands ([H, PAIRS_STEP]
+# and [128, PAIRS_STEP] bfloat16, 32 MiB at H = 128) are fused into the
+# product and never leave the chip's own memory; a float32 partial sum is
+# exact below 2^24 and turned to int32 every step. On a v5e, by column at
+# [32 shards, 10,000 rows]: 2^14 17.2 ms, 2^15 11.1, 2^16 9.8, 2^17 9.6
+# (benches/recount_kernels.py --steps)
+PAIRS_STEP = 1 << 16
 
 
 def pairs_count_slots(n_rows: int) -> int:
@@ -463,48 +474,75 @@ def pairs_count_slots(n_rows: int) -> int:
     return h * 128
 
 
+def pairs_by_column(shards: list) -> np.ndarray:
+    """Host-side builder of an entry by column: [(columns, ranks)] a shard
+    (shard-local columns, none twice) -> int32[1, S, 32, W] bit-major."""
+    arr = np.full((1, len(shards), WORD_BITS, SHARD_WIDTH // WORD_BITS), -1,
+                  np.int32)
+    for s, (cols, rank) in enumerate(shards):
+        arr[0, s, cols & (WORD_BITS - 1), cols >> 5] = rank
+    return arr
+
+
+def pairs_by_pairs(shards: list, slots: int) -> np.ndarray:
+    """Host-side builder of an entry by pairs: [(columns, ranks)] a shard,
+    in the order they are to lie -> int32[2, S, slots], sentinel-padded."""
+    arr = np.full((2, len(shards), slots), SPARSE_SENTINEL, np.int32)
+    for s, (cols, rank) in enumerate(shards):
+        arr[0, s, :cols.size] = cols
+        arr[1, s, :rank.size] = rank
+    return arr
+
+
 def pairs_count_local(pairs: jax.Array, src: jax.Array,
                       n_slots: int) -> jax.Array:
     """counts int32[n_slots] of one block of shards: pairs int32[2, S, K]
-    or, by column, int32[S, 2^20]; src uint32[S, W]. One scan over steps of
-    PAIRS_STEP slots, each within one shard: the bit of every slot (by
-    pairs `_dense_bit_test`'s gather from that shard's plane, by column
-    the plane's own words unpacked), then the sum by rank as a product of
-    two one-hot matrices on the matrix unit (rank = 128 · hi + lo;
-    counts[hi, lo] = Σ_k bit_k · [hi_k = hi] · [lo_k = lo]), exact in
-    float32 for a step of 2^15 slots. Nothing of the entry's size is
-    materialized beside it: sixteen request threads may have this program
-    in flight at once. Timings of the alternatives:
-    benches/recount_kernels.py, PERF.md section 6."""
-    by_column = pairs.ndim == 2
-    n_shards, k = pairs.shape[-2], pairs.shape[-1]
-    step = min(PAIRS_STEP, k)
-    per_shard = k // step
-    h = n_slots // 128
-    hi_ids = jnp.arange(h, dtype=jnp.int32)
-    lo_ids = jnp.arange(128, dtype=jnp.int32)
-    bit_ids = jnp.arange(WORD_BITS, dtype=jnp.uint32)
+    or, by column, int32[1, S, 32, W]; src uint32[S, W]. One scan over
+    steps of PAIRS_STEP slots, each within one shard. A step takes its
+    ranks as r int32[A, T], slots along lanes, -1 where the filter has no
+    bit (by pairs A = 1 and the bit is `_dense_bit_test`'s gather from
+    that shard's plane; by column A = 32 and bit b of the tile's T words,
+    a lane vector, lines up with row b of the entry's tile), then sums by
+    rank as a product of two one-hot matrices on the matrix unit (rank =
+    128 · hi + lo; counts[hi, lo] = Σ_k [hi_k = hi] · [lo_k = lo]), both
+    built with the slot index minor, r broadcast along sublanes, and
+    contracted over it: hiT · loTᵀ, the form the matrix unit takes
+    without a transpose. Exact in float32 for a step of 2^16 slots.
+    Nothing of the entry's size is materialized beside it: sixteen
+    request threads may have this program in flight at once. Timings of
+    the alternatives: benches/recount_kernels.py, PERF.md section 6."""
+    by_column = pairs.ndim == 4
+    if by_column:
+        pairs = pairs[0]
+        step = min(PAIRS_STEP // WORD_BITS, src.shape[1])    # words
+    else:
+        step = min(PAIRS_STEP, pairs.shape[2])
+        pairs = jnp.pad(pairs, ((0, 0), (0, 0), (0, -pairs.shape[2] % step)),
+                        constant_values=SPARSE_SENTINEL)
+    n_shards, per_shard = src.shape[0], pairs.shape[-1] // step
+    hi_ids = jnp.arange(n_slots // 128, dtype=jnp.int32)[None, :, None]
+    lo_ids = jnp.arange(128, dtype=jnp.int32)[None, :, None]
+    bit_ids = jnp.arange(WORD_BITS, dtype=jnp.uint32)[:, None]
 
     def one(acc, i):
         s, at = i // per_shard, (i % per_shard) * step
         if by_column:
-            r = lax.dynamic_slice(pairs, (s, at), (1, step))[0]
-            words = lax.dynamic_slice(src, (s, at // WORD_BITS),
-                                      (1, step // WORD_BITS))[0]
-            b = ((((words[:, None] >> bit_ids[None]) & 1) != 0)
-                 .reshape(step) & (r >= 0))
+            r = lax.dynamic_slice(pairs, (s, 0, at), (1, WORD_BITS, step))[0]
+            words = lax.dynamic_slice(src, (s, at), (1, step))
+            b = ((words >> bit_ids) & 1) != 0
         else:
             blk = lax.dynamic_slice(pairs, (0, s, at), (2, 1, step))
             plane = lax.dynamic_index_in_dim(src, s, axis=0, keepdims=False)
-            r = blk[1, 0]
-            b = _dense_bit_test(blk[0, 0], plane)
-        hi = ((r >> 7)[:, None] == hi_ids[None]) & b[:, None]
-        lo = (r & 127)[:, None] == lo_ids[None]
-        got = jnp.dot(hi.astype(jnp.bfloat16).T, lo.astype(jnp.bfloat16),
-                      preferred_element_type=jnp.float32)
+            r = blk[1]
+            b = _dense_bit_test(blk[0, 0], plane)[None]
+        r = jnp.where(b, r, -1)          # -1 >> 7 is -1: it matches no hi
+        hi_t = ((r >> 7)[:, None, :] == hi_ids).astype(jnp.bfloat16)
+        lo_t = ((r & 127)[:, None, :] == lo_ids).astype(jnp.bfloat16)
+        got = lax.dot_general(hi_t, lo_t, (((0, 2), (0, 2)), ((), ())),
+                              preferred_element_type=jnp.float32)
         return acc + got.astype(jnp.int32), None
 
-    acc, _ = lax.scan(one, jnp.zeros((h, 128), jnp.int32),
+    acc, _ = lax.scan(one, jnp.zeros((n_slots // 128, 128), jnp.int32),
                       jnp.arange(n_shards * per_shard, dtype=jnp.int32))
     return acc.reshape(-1)
 

@@ -502,12 +502,12 @@ def groupby_chunk_matrix_mesh(mesh: Mesh, axis_slabs: tuple, idx: tuple,
 
 
 @functools.lru_cache(maxsize=None)
-def _pairs_count_mesh_fn(mesh: Mesh, n_slots: int, by_column: bool):
+def _pairs_count_mesh_fn(mesh: Mesh, n_slots: int, ndim: int):
     """Per-mesh, per-size shard_map program of ops/bitvector.pairs_count:
     every device counts over its own shard slots (the entry, in either
-    layout, and the filter are both sharded on the shard axis), one psum
-    on the interconnect, the count vector replicated."""
-    entry = P(SHARD_AXIS, None) if by_column else P(None, SHARD_AXIS, None)
+    layout, is sharded on its second axis and the filter on its first),
+    one psum on the interconnect, the count vector replicated."""
+    entry = P(None, SHARD_AXIS, *([None] * (ndim - 2)))
 
     @jax.jit
     @functools.partial(
@@ -602,12 +602,11 @@ class DeviceRunner:
 
     def put_pairs(self, pairs: np.ndarray) -> jax.Array:
         """Place one pairs entry (ops/bitvector.py), by pairs int32[2, S, K]
-        or by column int32[S, 2^20]: the shard axis padded with what reads
-        as no bit (the sparse sentinel, no rank) and sharded like a
-        leaf's."""
-        if pairs.ndim == 2:
-            return self._put_shard_padded(pairs, 0, fill=-1)
-        return self._put_shard_padded(pairs, 1, fill=SPARSE_SENTINEL)
+        or by column int32[1, S, 32, W]: the shard axis, the second of
+        both, padded with what reads as no bit (the sparse sentinel, no
+        rank) and sharded like a leaf's."""
+        return self._put_shard_padded(
+            pairs, 1, fill=-1 if pairs.ndim == 4 else SPARSE_SENTINEL)
 
     def pairs_count(self, pairs: jax.Array, src: jax.Array,
                     n_slots: int) -> jax.Array:
@@ -620,7 +619,7 @@ class DeviceRunner:
             record_dispatch("ici_program", self.mesh, "pairs", n_slots,
                             pairs, src)
             return _pairs_count_mesh_fn(self.mesh, n_slots,
-                                        pairs.ndim == 2)(pairs, src)
+                                        pairs.ndim)(pairs, src)
         return pairs_count(pairs, src, n_slots)
 
     def put_plane_slab(self, planes: np.ndarray) -> jax.Array:

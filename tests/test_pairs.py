@@ -6,6 +6,8 @@ row below the sparse threshold, one build however many threads ask, and
 the cases that fall to the dense walk whole (the hybrid representation
 off; an entry that would not fit a quarter of the residency budget)."""
 
+import pathlib
+import re
 import threading
 
 import jax
@@ -23,19 +25,26 @@ W = SHARD_WIDTH // 32
 # ------------------------------------------------------------------ kernel
 
 
+def _kept(rows_by_shard: list) -> list:
+    """[{rank: columns}] a shard -> [(columns, ranks)] a shard, rows in rank
+    order and a row's columns sorted: what executor._pairs_entry hands the
+    two builders of ops/bitvector.py, through which every entry here is
+    laid out too (the test and the executor cannot drift)."""
+    kept = []
+    for rows in rows_by_shard:
+        ranks = sorted(rows)
+        cols = [np.sort(np.asarray(rows[r], np.int32)) for r in ranks]
+        kept.append((
+            np.concatenate(cols + [np.empty(0, np.int32)]),
+            np.repeat(np.asarray(ranks, np.int32),
+                      [c.size for c in cols]).astype(np.int32)))
+    return kept
+
+
 def entry_of(rows_by_shard: list, n_rows: int, slots: int) -> np.ndarray:
     """[{rank: sorted columns}] a shard -> int32[2, S, slots], as
     executor._pairs_entry lays it out."""
-    arr = np.full((2, len(rows_by_shard), slots), bv.SPARSE_SENTINEL,
-                  np.int32)
-    for s, rows in enumerate(rows_by_shard):
-        at = 0
-        for rank in sorted(rows):
-            cols = np.sort(np.asarray(rows[rank], np.int32))
-            arr[0, s, at:at + cols.size] = cols
-            arr[1, s, at:at + cols.size] = rank
-            at += cols.size
-    return arr
+    return bv.pairs_by_pairs(_kept(rows_by_shard), slots)
 
 
 def oracle(rows_by_shard: list, src: np.ndarray, n_rows: int) -> np.ndarray:
@@ -72,11 +81,14 @@ def random_entry(seed, n_shards, n_rows, slots, empty_shards=()):
 
 KERNEL_CASES = [
     # shards, rows, slots, shards left all-pad
-    (1, 5, 8, ()),
+    (1, 5, 8, ()),                      # H = 1, K far below one lane tile
     (2, 130, 1 << 11, ()),
     (3, 700, 1 << 13, (1,)),
     (4, 300, 1 << 16, (0, 3)),          # more than one histogram step
     (2, 9966, 1 << 14, ()),             # a grid field's count vector
+    (9, 31, 1 << 9, (4,)),              # H = 1, two gather steps, K < a tile
+    (1, 128, 1 << 7, ()),               # H = 1 full, K one vector of lanes
+    (2, 129, 1536, ()),                 # K no multiple of the tile
 ]
 
 
@@ -95,10 +107,10 @@ def test_pairs_count_equals_oracle(n_shards, n_rows, slots, empty):
 
 def column_entry(seed, n_shards, n_rows, fill, empty_shards=()):
     """An entry whose columns hold at most one row each, in both layouts:
-    ([{rank: columns}] a shard, int32[S, 2^20] of rank or -1, the filter)."""
+    ([{rank: columns}] a shard, int32[1, S, 32, W] of rank or -1 laid
+    bit-major by the executor's builder, the filter)."""
     rng = np.random.default_rng(seed)
     shards = []
-    by_col = np.full((n_shards, SHARD_WIDTH), -1, np.int32)
     for s in range(n_shards):
         rows: dict = {}
         if s not in empty_shards:
@@ -106,16 +118,28 @@ def column_entry(seed, n_shards, n_rows, fill, empty_shards=()):
             cols = np.union1d(cols, [0, SHARD_WIDTH - 1])
             rank = rng.integers(0, n_rows, cols.size)
             rank[-1] = 0                      # the last column, in row 0
-            by_col[s, cols] = rank
             rows = {int(r): cols[rank == r] for r in np.unique(rank)}
         shards.append(rows)
+    by_col = bv.pairs_by_column(_kept(shards))
     src = rng.integers(0, 1 << 32, size=(n_shards, W), dtype=np.uint32)
     src[0, -1] |= np.uint32(1 << 31)
     return shards, by_col, src
 
 
+def test_by_column_lies_bit_major():
+    """At [0, s, b, w] the rank of the row that holds column 32 · w + b."""
+    shards, by_col, _ = column_entry(5, 2, 40, 0.01)
+    assert by_col.shape == (1, 2, 32, W) and by_col.dtype == np.int32
+    flat = np.full((2, SHARD_WIDTH), -1, np.int32)
+    for s, rows in enumerate(shards):
+        for rank, cols in rows.items():
+            flat[s, cols] = rank
+    assert (by_col[0] == flat.reshape(2, W, 32).transpose(0, 2, 1)).all()
+
+
 @pytest.mark.parametrize("n_shards,n_rows,fill,empty", [
-    (1, 3, 0.001, ()), (2, 9966, 0.7, ()), (3, 500, 0.2, (0, 2))])
+    (1, 3, 0.001, ()), (2, 9966, 0.7, ()), (3, 500, 0.2, (0, 2)),
+    (2, 1, 0.3, ()), (1, 128, 0.05, ())])             # H = 1
 def test_pairs_count_by_column_equals_oracle(n_shards, n_rows, fill, empty):
     shards, by_col, src = column_entry(n_rows, n_shards, n_rows, fill, empty)
     n_slots = bv.pairs_count_slots(n_rows)
@@ -132,34 +156,82 @@ def test_pairs_count_by_column_equals_oracle(n_shards, n_rows, fill, empty):
         assert (np.asarray(bv.pairs_count(pairs, src, n_slots)) == got).all()
 
 
-def test_all_pad_entry_counts_nothing():
-    pairs = np.full((2, 2, 8), bv.SPARSE_SENTINEL, np.int32)
+@pytest.mark.parametrize("layout", ["pairs", "column"])
+def test_all_pad_entry_counts_nothing(layout):
+    pairs = (np.full((2, 2, 8), bv.SPARSE_SENTINEL, np.int32)
+             if layout == "pairs" else np.full((1, 2, 32, W), -1, np.int32))
     src = np.full((2, W), 0xFFFFFFFF, np.uint32)
     assert not np.asarray(bv.pairs_count(pairs, src, 128)).any()
 
 
-@pytest.mark.parametrize("n_devices,n_shards", [(4, 4), (4, 6), (8, 3)])
-def test_mesh_form_agrees(n_devices, n_shards):
+def test_a_count_past_the_float_limit_is_exact():
+    """One row, every column of 17 shards in it, under a filter of all
+    ones: 17 x 2^20 is past 2^24, where a float32 sum stops counting by
+    ones; the partial sums are turned to int32 before they get there."""
+    n_shards = 17
+    by_col = np.zeros((1, n_shards, 32, W), np.int32)
+    src = np.full((n_shards, W), 0xFFFFFFFF, np.uint32)
+    got = np.asarray(bv.pairs_count(by_col, src, 128))
+    assert got[0] == n_shards * SHARD_WIDTH > 1 << 24
+    assert not got[1:].any()
+
+
+def _shapes(by_column: bool):
+    entry = ((1, 2, 32, W) if by_column else (2, 2, 1 << 12))
+    return (jax.ShapeDtypeStruct(entry, np.int32),
+            jax.ShapeDtypeStruct((2, W), np.uint32))
+
+
+def test_by_column_lowers_without_gather_or_scatter():
+    """By column nothing is gathered: the filter's words are unpacked where
+    they lie. By pairs the bit test is a gather, and only that."""
+    ops = {by_column: set(re.findall(
+        r"stablehlo\.(\w+)",
+        bv.pairs_count.lower(*_shapes(by_column), 128 * 128).as_text()))
+        for by_column in (True, False)}
+    assert not ops[True] & {"gather", "scatter"}, sorted(ops[True])
+    assert "dot_general" in ops[True]
+    assert "gather" in ops[False] and "scatter" not in ops[False]
+
+
+@pytest.mark.parametrize("by_column", [True, False])
+def test_the_program_is_named_jit_pairs_count(by_column):
+    """The device trace names a program after its jitted entry point, and
+    benchmarks/layer_metrics/recount_roofline.py finds the recount by it."""
+    reader = (pathlib.Path(__file__).parent.parent / "benchmarks"
+              / "layer_metrics" / "recount_roofline.py").read_text()
+    (program,) = re.findall(r'^PROGRAM = "(\w+)"$', reader, re.M)
+    text = bv.pairs_count.lower(*_shapes(by_column), 128).compile().as_text()
+    assert re.search(r"HloModule (\w+)", text).group(1) == program \
+        == "jit_pairs_count"
+
+
+@pytest.mark.parametrize("n_devices,n_shards,slots", [
+    (4, 4, 1 << 12), (4, 6, 1 << 12), (8, 3, 1 << 12),
+    (4, 5, 1536),       # K no multiple of the tile, shards none of the mesh
+    (8, 3, 8)])         # K below one vector of lanes
+def test_mesh_form_agrees(n_devices, n_shards, slots):
     """The shard_map + psum form on the CPU's forced devices: the entry
     and the filter padded to the mesh and sharded on the shard axis, the
     counts the single-device kernel's."""
     mesh = make_mesh(jax.devices()[:n_devices])
     runner = DeviceRunner(mesh)
-    shards, src = random_entry(7 + n_shards, n_shards, 300, 1 << 12)
-    pairs = entry_of(shards, 300, 1 << 12)
-    n_slots = bv.pairs_count_slots(300)
+    n_rows = 300 if slots > 8 else 3
+    shards, src = random_entry(7 + n_shards, n_shards, n_rows, slots)
+    pairs = entry_of(shards, n_rows, slots)
+    n_slots = bv.pairs_count_slots(n_rows)
     dev_pairs, dev_src = runner.put_pairs(pairs), runner.put_leaf(src)
     assert dev_pairs.shape[1] % n_devices == 0
     got = np.asarray(runner.pairs_count(dev_pairs, dev_src, n_slots))
-    assert (got[:300] == oracle(shards, src, 300)).all()
+    assert (got[:n_rows] == oracle(shards, src, n_rows)).all()
     single = np.asarray(DeviceRunner().pairs_count(pairs, src, n_slots))
     assert (got == single).all()
-    # by column: sharded on its first axis, pad shards hold no rank
-    shards, by_col, src = column_entry(n_shards, n_shards, 300, 0.05)
+    # by column: sharded on the same axis, pad shards hold no rank
+    shards, by_col, src = column_entry(n_shards, n_shards, n_rows, 0.05)
     dev = runner.put_pairs(by_col)
-    assert dev.shape[0] % n_devices == 0
+    assert dev.shape[1] % n_devices == 0 and dev.shape[2:] == (32, W)
     got = np.asarray(runner.pairs_count(dev, runner.put_leaf(src), n_slots))
-    assert (got[:300] == oracle(shards, src, 300)).all()
+    assert (got[:n_rows] == oracle(shards, src, n_rows)).all()
 
 
 # ------------------------------------------------------- the resident entry
@@ -225,8 +297,8 @@ def test_no_plane_for_a_row_below_the_threshold(wide):
 def test_an_attribute_field_lies_by_column(tmp_path):
     """One value a column, 10,000 values, seven columns in ten filled: no
     column holds two rows and the sorted columns would take 2^20 slots a
-    shard, so the entry is one rank a column (4 MiB a shard, half the
-    pairs) and the recount gathers nothing; same Pairs as brute force."""
+    shard, so the entry is one rank a column, laid bit-major (4 MiB a shard,
+    half the pairs) and the recount gathers nothing; same Pairs as brute force."""
     rng = np.random.default_rng(31)
     h = Holder(str(tmp_path / "d")).open()
     try:
@@ -248,7 +320,7 @@ def test_an_attribute_field_lies_by_column(tmp_path):
         assert ex.topn_recount_rows == 1 and ex.topn_pairs_recounts == 1
         (key,) = [k for k, _ in ex.residency.entries_snapshot()
                   if k[0] == "pairs"]
-        assert ex.residency.peek(key).dev.shape == (2, SHARD_WIDTH)
+        assert ex.residency.peek(key).dev.shape == (1, 2, 32, W)
     finally:
         h.close()
 
