@@ -3,7 +3,7 @@ suite (SURVEY.md §6: roaring container ops roaring/roaring_test.go:1364-1522,
 fragment import/snapshot/checksum fragment_internal_test.go:1135-1986).
 
 These measure the storage plane (numpy + C++ kernels); the TPU query plane
-is measured by bench.py at the repo root. Prints one JSON line per metric:
+is measured by benchmarks/run.py on the chip. Prints one JSON line per metric:
     {"metric": ..., "value": ..., "unit": ...}
 
 Run: python benches/micro.py [--quick]

@@ -29,12 +29,6 @@ at that column. Variants timed here:
                     slots] and [128, slots], slots on the lane axis), the
                     product contracting the last axis of both, left to XLA
                     in a scan (--steps sweeps the slots a step)
-  lane-major one-hot, Pallas
-                    the same step written as a Pallas kernel: both
-                    operands made and consumed in VMEM, one accumulator
-                    (--lanes sweeps its tile, --explore other shapes);
-                    tried in PR 30, no faster than what XLA makes of the
-                    scan, and not shipped
   shipped           ops/bitvector.py pairs_count as the executor calls it,
                     by pairs and by column; with the bytes of temporaries
                     the compiled program allocates
@@ -46,7 +40,6 @@ at that column. Variants timed here:
 from __future__ import annotations
 
 import argparse
-import functools
 import json
 import os
 import sys
@@ -103,10 +96,9 @@ def main():
     ap.add_argument("--steps", type=int, nargs="*", default=[],
                     help="slots a scan step of the XLA body, to time "
                     "beside the shipped PAIRS_STEP")
-    ap.add_argument("--lanes", type=int, nargs="*", default=[1 << 10],
-                    help="tiles of the Pallas step to time")
     ap.add_argument("--explore", action="store_true",
-                    help="also the shapes tried and not shipped")
+                    help="also the count vector rounded to 16 rows of "
+                    "128, tried and not shipped")
     ap.add_argument("--only", default="",
                     help="time only the variants whose name holds this")
     args = ap.parse_args()
@@ -172,8 +164,6 @@ def main():
         return hist(jnp.maximum(bycol, 0).reshape(-1, chunk),
                     ok.reshape(-1, chunk))[:R]
 
-    from jax.experimental import pallas as pl
-
     from pilosa_tpu.ops import bitvector as bv
 
     @jax.jit
@@ -210,79 +200,6 @@ def main():
         acc, _ = jax.lax.scan(one, jnp.zeros((H, 128), jnp.int32),
                               jnp.arange(S * per_shard, dtype=jnp.int32))
         return acc.reshape(-1)[:R]
-
-    def rank_sum_kernel(hp, unpack, *refs):
-        """The step as a Pallas kernel, one grid step: r_ref int32[rows,
-        lanes], each row a lane vector of ranks (-1 counts nothing); with
-        `unpack`, row j counts only under bit j of w_ref uint32[1, lanes].
-        Both one-hots live and die in VMEM; out_ref int32[hp, 128] stays
-        there over the whole grid."""
-        r_ref, out_ref = refs[0], refs[-1]
-        rows, lanes = r_ref.shape
-        hi_ids = jax.lax.broadcasted_iota(jnp.int32, (hp, lanes), 0)
-        lo_ids = jax.lax.broadcasted_iota(jnp.int32, (128, lanes), 0)
-
-        def row(j, acc):
-            r = r_ref[pl.ds(j, 1), :]
-            if unpack:
-                bit = jax.lax.shift_right_logical(
-                    refs[1][...], jnp.full((1, lanes), j, jnp.uint32)) & 1
-                r = jnp.where(bit != 0, r, -1)
-            hi_t = (hi_ids == (r >> 7)).astype(jnp.bfloat16)
-            lo_t = (lo_ids == (r & 127)).astype(jnp.bfloat16)
-            return acc + jax.lax.dot_general(
-                hi_t, lo_t, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-
-        acc = jax.lax.fori_loop(0, rows, row,
-                                jnp.zeros((hp, 128), jnp.float32))
-
-        @pl.when((pl.program_id(0) == 0) & (pl.program_id(1) == 0))
-        def _init():
-            out_ref[...] = jnp.zeros_like(out_ref)
-
-        out_ref[...] += acc.astype(jnp.int32)
-
-    def rank_sum(ranks, words, h, lanes):
-        """int32[128 h] counts of ranks int32[B, rows, L]; grid (B, L /
-        lanes), one accumulator, written to HBM once."""
-        b, rows, length = ranks.shape
-        hp = -(-h // 16) * 16
-        a = [ranks]
-        specs = [pl.BlockSpec((None, rows, lanes), lambda i, j: (i, 0, j))]
-        if words is not None:
-            a.append(words.reshape(b, 1, length))
-            specs.append(
-                pl.BlockSpec((None, 1, lanes), lambda i, j: (i, 0, j)))
-        got = pl.pallas_call(
-            functools.partial(rank_sum_kernel, hp, words is not None),
-            grid=(b, length // lanes), in_specs=specs,
-            out_specs=pl.BlockSpec((hp, 128), lambda i, j: (0, 0)),
-            out_shape=jax.ShapeDtypeStruct((hp, 128), jnp.int32),
-            interpret=jax.default_backend() != "tpu")(*a)
-        return got[:h].reshape(-1)
-
-    def lane_major_pallas(h, lanes):
-        return jax.jit(lambda bitmajor, src: rank_sum(
-            bitmajor[0], src, h, min(lanes, W))[:R])
-
-    @jax.jit
-    def whole_gather_pallas(pairs, src):
-        r = jnp.where(bv._dense_bit_test(pairs[0], src), pairs[1], -1)
-        return rank_sum(r.reshape(-1, min(8, S), K), None, H,
-                        min(1 << 10, K))[:R]
-
-    @jax.jit
-    def gather_by_eight_pallas(pairs, src):
-        def one(acc, at):
-            blk = jax.lax.dynamic_slice(pairs, (0, at, 0), (2, 8, K))
-            planes = jax.lax.dynamic_slice(src, (at, 0), (8, W))
-            r = jnp.where(bv._dense_bit_test(blk[0], planes), blk[1], -1)
-            return acc + rank_sum(r[None], None, H, min(1 << 10, K)), None
-
-        acc, _ = jax.lax.scan(one, jnp.zeros(H * 128, jnp.int32),
-                              jnp.arange(0, S, 8, dtype=jnp.int32))
-        return acc[:R]
 
     def lane_major_xla(step, n_slots):
         """ops/bitvector.py's body traced under another PAIRS_STEP or
@@ -330,24 +247,12 @@ def main():
             runs[f"lane-major one-hot, XLA, by {layout}, step {step}"] = (
                 lane_major_xla(step, n_slots),
                 ("pairs" if layout == "pairs" else "bitmajor", "src"), want)
-    for lanes in args.lanes:
-        runs[f"lane-major one-hot, Pallas, by column, {lanes} lanes"] = (
-            lane_major_pallas(H, lanes), ("bitmajor", "src"), want)
     if args.explore:
-        # H a multiple of 16 in place of a power of two; by pairs, the
-        # gather of the whole entry, or of eight shards a scan step,
-        # before the Pallas step
+        # H a multiple of 16 in place of a power of two
         h16 = -(-R // 2048) * 16
         runs[f"lane-major one-hot, XLA, by column, H = {h16}"] = (
             lane_major_xla(bv.PAIRS_STEP, h16 * 128), ("bitmajor", "src"),
             want)
-        runs[f"lane-major one-hot, Pallas, by column, H = {h16}"] = (
-            lane_major_pallas(h16, 1 << 10), ("bitmajor", "src"), want)
-        runs["whole gather, then Pallas"] = (
-            whole_gather_pallas, ("pairs", "src"), want)
-        if S % 8 == 0:
-            runs["gather by eight shards, then Pallas"] = (
-                gather_by_eight_pallas, ("pairs", "src"), want)
     runs["shipped by pairs"] = (shipped, ("pairs", "src"), want)
     runs["shipped by column"] = (shipped, ("bitmajor", "src"), want)
     for name, (fn, keys, check) in runs.items():
@@ -358,7 +263,7 @@ def main():
         got = np.asarray(fn(*a))
         first = time.perf_counter() - t0
         ok = None if check is None else bool((got == check).all())
-        np.asarray(fn(*a))     # a fresh Pallas binary's second run is slow too
+        np.asarray(fn(*a))     # a second warm call before the timed ones
         ts = []
         for _ in range(args.reps):
             t0 = time.perf_counter()

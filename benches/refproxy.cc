@@ -21,10 +21,10 @@
 // copying code. Two additional workloads give the engine benches a
 // like-for-like denominator:
 //   exec_128shard_1pct  — Count(Intersect) of two 1%-dense rows over 128
-//                         shards (bench.py executor stage's exact data
+//                         shards (the engine's served-Count data
 //                         shape; executor.go:1521 + roaring fan-in)
 //   kernel_2rows_dense  — Count(Intersect) of two 50%-dense rows over
-//                         1024 shards (bench.py kernel stage's shape;
+//                         1024 shards (the dense kernel's shape;
 //                         all bitmap×bitmap popcount-AND)
 //   bsi_sum_16shard     — Sum(Range(v>thr)): 10-plane range walk + 11
 //                         filtered plane counts over 16 shards of dense
@@ -480,7 +480,7 @@ int main(int argc, char** argv) {
   // engine-comparable workloads -------------------------------------------
   std::mt19937_64 rng(7);
 
-  // bench.py executor stage shape: 2 rows x 128 shards x 1% of 2^20 cols
+  // executor shape: 2 rows x 128 shards x 1% of 2^20 cols
   {
     const int n_shards = 128, per_shard = 1 << 20;
     const int n_bits = per_shard / 100;
@@ -500,7 +500,7 @@ int main(int argc, char** argv) {
             [&] { return rowa.intersection_count_with(rowb); }, 1.0);
   }
 
-  // bench.py kernel stage shape: 2 rows x 1024 shards x ~50% density
+  // kernel shape: 2 rows x 1024 shards x ~50% density
   // (random words -> all bitmap containers; 128MB per row)
   {
     const int n_shards = 1024, conts = 16;  // 16 containers per 2^20 shard
@@ -526,7 +526,7 @@ int main(int argc, char** argv) {
             [&] { return rowa.intersection_count_with(rowb); }, 2.0);
   }
 
-  // bench.py groupby stage shape: two axes of 100 rows over 4 shards,
+  // groupby shape: two axes of 100 rows over 4 shards,
   // 2000 bits/row; one op = the full 100x100 cross product of pairwise
   // intersection counts — the reference's groupByIterator walks exactly
   // this per-combination count loop (executor.go:897-1090)
@@ -556,7 +556,7 @@ int main(int argc, char** argv) {
       }, 1.0);
   }
 
-  // bench.py http stage shape: Count(Intersect) of 2 rows x 100k bits over
+  // http shape: Count(Intersect) of 2 rows x 100k bits over
   // 8 shards — the serving work behind one HTTP query (the Go reference's
   // wire+parse overhead is negligible against it)
   {
@@ -576,7 +576,7 @@ int main(int argc, char** argv) {
             [&] { return rowa.intersection_count_with(rowb); }, 1.0);
   }
 
-  // bench.py distributed stage shape: Count(Intersect) of 2 rows x 0.5%
+  // distributed shape: Count(Intersect) of 2 rows x 0.5%
   // density over 16 shards — what each fan-out query costs the reference
   // in kernel work before its own HTTP scatter-gather overhead
   {
@@ -596,7 +596,7 @@ int main(int argc, char** argv) {
             [&] { return rowa.intersection_count_with(rowb); }, 1.0);
   }
 
-  // bench.py bsi stage shape: Sum(Range(v > thr)) over 16 shards of dense
+  // bsi shape: Sum(Range(v > thr)) over 16 shards of dense
   // BSI planes (10 bit planes + exists): range walk materializes the
   // filter row plane-by-plane (fragment.go:718-985 rangeOp GT), then the
   // sum is a filtered popcount per plane (executor.go:363 executeSum)

@@ -1,9 +1,9 @@
 """Build + run the C++ reference-baseline proxy and record the results.
 
 Produces benches/refproxy.json: {bench_name: {"ns_per_op": float, "ops": int,
-"qps": float}} plus host metadata. bench.py reads this file to attach
-vs_go_reference ratios to its stages. See refproxy.cc for why a scalar C++
-proxy stands in for the absent Go toolchain.
+"qps": float}} plus host metadata: the record BASELINE.md's table is
+rendered from. See refproxy.cc for why a scalar C++ proxy stands in for the
+absent Go toolchain.
 """
 
 import json

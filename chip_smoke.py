@@ -16,9 +16,7 @@ process, run one after the other, each gone before the next starts:
               HTTP (import-roaring + import), runs a few queries of every
               family against a plain numpy reference written in this file,
               a read-your-writes Set, and 32 concurrent clients.
-  B  kernels  every Pallas entry point compiled (not interpreted) at
-              production shape against its XLA twin.
-  C  dryrun   (4-device hosts only) PILOSA_DRYRUN_PLATFORM=native
+  B  dryrun   (4-device hosts only) PILOSA_DRYRUN_PLATFORM=native
               python __graft_entry__.py 4.
 
 Exit status is the result: 0 only if every phase ran and every answer
@@ -59,7 +57,7 @@ from pilosa_tpu.storage.roaring import Bitmap  # noqa: E402
 SHARD_WIDTH = 1 << 20
 INDEX = "smoke"
 OVERALL_LIMIT_S = 1140.0  # the contract's 1200 s, less a margin to report
-PHASE_LIMIT_S = {"server": 900.0, "kernels": 300.0, "dryrun": 300.0}
+PHASE_LIMIT_S = {"server": 900.0, "dryrun": 300.0}
 # the concurrent phase's own limit, per request: concurrent multi-device
 # dispatch is where a mesh would hang, and a hang must fail fast
 CONCURRENT_REQUEST_LIMIT_S = 120.0
@@ -88,10 +86,9 @@ class Sizes:
     bsi_shards: int = 32           # a value on EVERY column of these
     bsi_max: int = (1 << 21) - 1   # bit depth 21
     topn: int = 1000
-    # the filtered walk recounts every candidate down to the n-th best on
-    # the device: one [S, W] leaf (16 MiB at 128 shards) built on the host
-    # and uploaded per recounted row, so n=1000 would move >16 GiB — a
-    # finding recorded in PERF.md, not a cost a smoke check should pay
+    # sized when the filtered walk uploaded one [S, W] leaf (16 MiB at 128
+    # shards) a recounted row; since PR 29 rows this thin are recounted
+    # from their sorted columns in one launch
     topn_filtered: int = 100
     clients: int = 32
     per_client: int = 10
@@ -727,7 +724,7 @@ def run_concurrent(http: Http, d: Data, ck: Checker) -> dict:
             "max_batch_seen": after["max_batch_seen"]}
 
 
-def check_memory(http: Http, device: dict, full: bool, ck: Checker) -> dict:
+def check_memory(http: Http, device: dict, z: Sizes, ck: Checker) -> dict:
     hbm = http.get("/debug/hbm")
     dvars = http.get("/debug/vars")
     stats = [d.get("memoryStats") or {} for d in dvars["deviceMemory"]]
@@ -739,9 +736,14 @@ def check_memory(http: Http, device: dict, full: bool, ck: Checker) -> dict:
            "device_bytes_in_use": in_use,
            "byKind": {k: v["bytes"] for k, v in
                       dvars["deviceResidency"]["by_kind"].items()}}
-    if full:
-        ck.record("residency", "residentBytes >= 1 GiB",
-                  hbm["residentBytes"] >= 1 << 30, True)
+    # what the queries above must have left on the device: every BSI plane
+    # (Sum, Range) and both GroupBy axes, a plane of 128 KiB a shard each
+    # (1 GiB was the floor while a filtered TopN uploaded a plane a
+    # recounted row; since PR 29 it recounts from sorted columns)
+    planes = int(z.bsi_max).bit_length() + z.g_rows + z.h_rows
+    floor = planes * z.shards * (SHARD_WIDTH // 8)
+    ck.record("residency", f"residentBytes >= {floor} ({planes} planes)",
+              hbm["residentBytes"] >= floor, True)
     if device["platform"] == "tpu" and device["count"] > 1:
         # nothing piled on the first chip (the CPU backend reports no stats)
         ck.record("mesh_balance",
@@ -788,7 +790,7 @@ def phase_server(kids: Children, d: Data, want_platform: str, tmp: str,
                   f"dense, sparse and run dispatches moved: {moved}",
                   all(moved[k] > 0 for k in ("dense", "sparse", "run")),
                   True)
-        summary["memory"] = check_memory(http, device, d.sizes is FULL, ck)
+        summary["memory"] = check_memory(http, device, d.sizes, ck)
         print(f"residency {json.dumps(summary['memory'])}", flush=True)
     except SmokeFailure as e:
         died = (f"\nserver exited with code {proc.returncode}"
@@ -810,13 +812,16 @@ def phase_server(kids: Children, d: Data, want_platform: str, tmp: str,
 
 
 # ---------------------------------------------------------------------------
-# Phases B and C: children that hold the chip themselves.
+# Phase B: a child that holds the chips itself.
 # ---------------------------------------------------------------------------
 
 
-def run_child(kids: Children, phase: str, argv: list, env: dict) -> str:
-    kids.begin_phase(phase)
-    proc = kids.start(phase, argv, env)
+def phase_dryrun(kids: Children, n: int) -> dict:
+    kids.begin_phase("dryrun")
+    proc = kids.start(
+        "dryrun",
+        [sys.executable, os.path.join(REPO, "__graft_entry__.py"), str(n)],
+        dict(os.environ, PILOSA_DRYRUN_PLATFORM="native"))
     try:
         proc.wait(timeout=kids.remaining())
     except subprocess.TimeoutExpired:
@@ -825,130 +830,11 @@ def run_child(kids: Children, phase: str, argv: list, env: dict) -> str:
     code = kids.stop(proc)
     if timed_out or code != 0:
         raise SmokeFailure(
-            f"phase {phase!r} "
+            "phase 'dryrun' "
             + ("timed out" if timed_out else f"exited with code {code}")
-            + f"\n{kids.tail(phase, 'out', 1500)}\n{kids.tail(phase)}")
-    return kids.tail(phase, "out", 20000)
-
-
-def phase_kernels(kids: Children, rehearse: bool) -> dict:
-    argv = [sys.executable, os.path.abspath(__file__), "--child", "kernels"]
-    if rehearse:
-        argv.append("--rehearse-cpu")
-    out = run_child(kids, "kernels", argv, dict(os.environ))
-    return json.loads(out.strip().splitlines()[-1])
-
-
-def phase_dryrun(kids: Children, n: int) -> dict:
-    env = dict(os.environ, PILOSA_DRYRUN_PLATFORM="native")
-    out = run_child(kids, "dryrun", [sys.executable, os.path.join(
-        REPO, "__graft_entry__.py"), str(n)], env)
+            + f"\n{kids.tail('dryrun', 'out', 1500)}\n{kids.tail('dryrun')}")
+    out = kids.tail("dryrun", "out", 20000)
     return {"ok": True, "tail": out.strip().splitlines()[-2:]}
-
-
-def child_kernels(rehearse: bool) -> int:
-    """Phase B body (runs in its own process: it takes the chip). Each
-    Pallas entry point, compiled at production shape, against its XLA
-    twin; on a multi-device host the shard_map wrappers as well."""
-    import jax
-    import jax.numpy as jnp
-
-    from pilosa_tpu.parallel import mesh as pmesh
-
-    pmesh.configure_compile_cache()
-    if rehearse:
-        pmesh.force_platform("cpu")
-    from pilosa_tpu.ops import bitvector as bv
-    from pilosa_tpu.ops import bsi
-    from pilosa_tpu.ops import pallas_kernels as pk
-    from pilosa_tpu.ops import topn
-
-    platform = jax.devices()[0].platform
-    if platform != ("cpu" if rehearse else "tpu"):
-        print(f"kernels: platform is {platform!r}", file=sys.stderr)
-        return 2
-    if not rehearse and pk._interpret():
-        print("kernels: pallas would interpret on a tpu", file=sys.stderr)
-        return 2
-    s, w, depth = (16, 1024, 5) if rehearse else (128, 32768, 21)
-    key = iter(jax.random.split(jax.random.key(7), 64))
-
-    def bits(*shape):
-        return jax.random.bits(next(key), shape, dtype=jnp.uint32)
-
-    results: dict = {}
-
-    def same(name, got, want):
-        ok = bool(np.array_equal(np.asarray(got), np.asarray(want)))
-        results[name] = ok
-        print(f"  [{'ok' if ok else 'MISMATCH'}] {name}", flush=True)
-
-    a, b = bits(s, w), bits(s, w)
-    same("intersect_count", pk.intersect_count(a, b),
-         bv.intersect_count(a, b))
-
-    leaves = tuple(bits(s, w) for _ in range(8))
-    programs = {
-        2: ("andnot", ("leaf", 0), ("leaf", 1)),
-        3: ("xor", ("and", ("leaf", 0), ("leaf", 1)), ("not", ("leaf", 2))),
-        4: ("or", ("and", ("leaf", 0), ("leaf", 1)),
-            ("and", ("leaf", 2), ("leaf", 3))),
-        8: ("or",) + tuple(("and", ("leaf", i), ("leaf", i + 1))
-                           for i in range(0, 8, 2)),
-    }
-    for n, prog in programs.items():
-        same(f"program_count[{n} leaves]",
-             pk.program_count(leaves[:n], prog),
-             bv.popcount(pmesh._eval(leaves[:n], prog)))
-
-    pre, axis = bits(8, s, w), bits(16, s, w)
-    same("cross_count_matrix", pk.cross_count_matrix(pre, axis),
-         bv.cross_count_matrix(pre, axis))
-
-    rows = bits(8, s, w)
-    ii = jnp.asarray(np.arange(16, dtype=np.int32) % 8)
-    jj = jnp.asarray((np.arange(16, dtype=np.int32) * 3 + 1) % 8)
-    pair_twin = jax.jit(lambda r, i, j: jax.lax.map(
-        lambda ij: jnp.sum(bv.popcount(r[ij[0]] & r[ij[1]])), (i, j)))
-    pair_want = pair_twin(rows, ii, jj)
-    same("pair_stream_counts", pk.pair_stream_counts(rows, ii, jj),
-         pair_want)
-
-    cand, src = bits(256, 16 * w), bits(16 * w)
-    same("topn_counts_packed", pk.topn_counts_packed(cand, src),
-         topn.tanimoto_counts_packed(cand, src))
-    del cand
-
-    planes, exists = bits(depth, s, w), bits(s, w)
-    pred = jnp.asarray(bsi.value_to_bits(0x155555 & ((1 << depth) - 1),
-                                         depth))
-    for op in (bsi.LT, bsi.LTE, bsi.GT, bsi.GTE, bsi.EQ, bsi.NEQ):
-        same(f"bsi_compare[{op}]", pk.bsi_compare(planes, exists, pred, op),
-             bsi.compare(planes, exists, pred, op))
-    same("bsi_sum_counts", pk.bsi_sum_counts(planes, exists),
-         bsi.sum_counts(planes, exists))
-
-    n_dev = len(jax.devices())
-    if n_dev > 1:
-        m = pmesh.make_mesh()
-        runner = pmesh.DeviceRunner(m)
-        put = [runner.put_leaf(np.asarray(x)) for x in leaves[:3]]
-        same("program_count_mesh",
-             pk.program_count_mesh(m, tuple(put), programs[3]),
-             jnp.sum(bv.popcount(pmesh._eval(leaves[:3], programs[3]))))
-        slab = runner.put_plane_slab(np.asarray(rows))
-        same("pair_stream_counts_mesh",
-             pk.pair_stream_counts_mesh(m, slab, np.asarray(ii),
-                                        np.asarray(jj)),
-             np.asarray(pair_want).astype(np.int64))
-
-    dev = jax.devices()[0]
-    print(json.dumps({"ok": all(results.values()), "platform": dev.platform,
-                      "device_kind": dev.device_kind, "devices": n_dev,
-                      "jax": jax.__version__, "shape": [s, w],
-                      "bsi_depth": depth, "interpret": pk._interpret(),
-                      "kernels": results}))
-    return 0 if all(results.values()) else 1
 
 
 # ---------------------------------------------------------------------------
@@ -960,10 +846,7 @@ def main() -> int:
                     help="tiny sizes on the CPU backend, to debug the "
                          "script; the output says platform=cpu")
     ap.add_argument("--seed", type=int, default=20260926)
-    ap.add_argument("--child", choices=["kernels"], help=argparse.SUPPRESS)
     args = ap.parse_args()
-    if args.child == "kernels":
-        return child_kernels(args.rehearse_cpu)
 
     want = "cpu" if args.rehearse_cpu else "tpu"
     sizes = REHEARSAL if args.rehearse_cpu else FULL
@@ -983,11 +866,6 @@ def main() -> int:
         phase_server(kids, data, want, tmp, summary)
         phases["server"] = True
         del data
-        b = phase_kernels(kids, args.rehearse_cpu)
-        phases["kernels"] = bool(b["ok"])
-        summary["kernels"] = b
-        if not b["ok"]:
-            raise SmokeFailure(f"kernel parity failed: {b['kernels']}")
         if summary["device"]["count"] == 4:
             summary["dryrun"] = phase_dryrun(kids, 4)
             phases["dryrun"] = True
@@ -1006,7 +884,6 @@ def main() -> int:
     after = cache_entries()
     summary.update({
         "rehearsal": args.rehearse_cpu, "seed": args.seed,
-        "jax": summary.get("kernels", {}).get("jax"),
         "phases": phases,
         "compile_cache": {
             "dir": cache_dir(), "entries_before": len(cache_before),

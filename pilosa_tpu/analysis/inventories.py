@@ -6,7 +6,9 @@ tests/test_metrics_conformance.py:
 
 * env gates — every `PILOSA_TPU_*` name referenced anywhere under
   pilosa_tpu/ must appear in docs/operations.md, so an operator reading
-  the env-var table sees the complete gate surface.
+  the env-var table sees the complete gate surface; and every name that
+  table lists must still be referenced under pilosa_tpu/, so a switch
+  deleted from the code does not live on in the documents.
 * config knobs — every field of every `[section]` dataclass in
   cli/config.py must appear (kebab-case) BOTH in docs/operations.md and
   in `Config.to_toml()` (the serialization a knob must ride to be
@@ -35,6 +37,8 @@ from pilosa_tpu.analysis.lint import (
 )
 
 _ENV_TOKEN = re.compile(r"PILOSA_TPU_[A-Z0-9_]*[A-Z0-9]")
+# a row of the docs' env table: the name, in backticks, in the first cell
+_ENV_TABLE_ROW = re.compile(r"^\|\s*`(" + _ENV_TOKEN.pattern + ")`")
 
 
 def _read(path: str) -> str:
@@ -67,12 +71,20 @@ def env_gate_findings(root: str) -> list[Finding]:
                         f"docs/operations.md not found under {root}; "
                         "pass --root <repo root>")]
     findings = []
-    for name, (rel, lineno) in sorted(env_gate_inventory(root).items()):
+    inventory = env_gate_inventory(root)
+    for name, (rel, lineno) in sorted(inventory.items()):
         if name not in docs:
             findings.append(Finding(
                 rel, lineno, "env-gate-docs",
                 f"env gate {name} is read in code but undocumented in "
                 "docs/operations.md"))
+    for lineno, line in enumerate(docs.splitlines(), 1):
+        m = _ENV_TABLE_ROW.match(line)
+        if m and m.group(1) not in inventory:
+            findings.append(Finding(
+                "docs/operations.md", lineno, "env-gate-docs",
+                f"env gate {m.group(1)} is listed in the env table but "
+                "nothing under pilosa_tpu/ reads it"))
     return findings
 
 

@@ -52,7 +52,6 @@ STORAGE_VERSION = 0
 # unattributed in the pilosa_kernels* metric families. This lives in
 # constants (import-free) so the linter never has to import jax.
 KERNEL_FAMILY_REPS = {
-    "pallas": "dense",       # ops/pallas_kernels.py blocked kernels
     "topn": "dense",         # ops/topn.py cache ranking
     "bsi": "dense",          # ops/bsi.py bit-sliced planes
     "bitwise": "dense",      # ops/bitvector.py dense plane programs

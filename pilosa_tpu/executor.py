@@ -1426,7 +1426,7 @@ class Executor:
 
     def _eval_program_dense(self, program, leaves, kinds):
         """Dense [S', W] result of a compiled program. All-dense programs
-        take the runner's fused path (XLA / Pallas / ICI shard_map);
+        take the runner's fused path (XLA jit / ICI shard_map);
         hybrid programs evaluate through the sparse/run kernel families
         and materialize the root to a plane only if it is still sparse or
         run — downstream consumers (plan cache, Row segments, BSI/GroupBy
@@ -1588,7 +1588,6 @@ class Executor:
         elif (isinstance(program, tuple) and len(program) > 3
                 and program[0] == "and"
                 and all(p == ("leaf", i) for i, p in enumerate(program[1:]))
-                and not self.runner.use_pallas
                 and len({l.shape for l in leaves}) == 1):
             # the planner's Count(Intersect(...)) pushdown on 3+-way
             # chains: one fused AND+popcount dispatch keyed on chain
@@ -1763,10 +1762,10 @@ class Executor:
             bhi = min(hi, f.options.max) - f.base
             dlo = bsi_ops.compare(planes, exists,
                                   bsi_ops.value_to_bits(blo, depth),
-                                  bsi_ops.GTE, pallas=self.runner.use_pallas)
+                                  bsi_ops.GTE)
             dhi = bsi_ops.compare(planes, exists,
                                   bsi_ops.value_to_bits(bhi, depth),
-                                  bsi_ops.LTE, pallas=self.runner.use_pallas)
+                                  bsi_ops.LTE)
             return fetch(jax.numpy.bitwise_and(dlo, dhi))
 
         value = cond.value
@@ -1791,8 +1790,7 @@ class Executor:
             return fetch(exists)
         base_value = min(max(value - f.base, 0), f.options.max - f.base)
         pred = bsi_ops.value_to_bits(base_value, depth)
-        return fetch(bsi_ops.compare(planes, exists, pred, op_map[op],
-                                     pallas=self.runner.use_pallas))
+        return fetch(bsi_ops.compare(planes, exists, pred, op_map[op]))
 
     def _bsi_filter(self, index: Index, call: Call, shards):
         """Optional filter child for Sum/Min/Max — a device array [S', W]
@@ -1820,14 +1818,8 @@ class Executor:
             counts_per_plane, n = totals[:-1], int(totals[-1])
         else:
             # one dispatch + one fetch: per-plane counts with the exists
-            # count packed as the last row (bsi_ops.sum_counts, or the
-            # Pallas blocked plane sweep behind PILOSA_TPU_PALLAS)
-            if self.runner.use_pallas and planes.ndim == 3:
-                from pilosa_tpu.ops import pallas_kernels
-                packed = np.asarray(
-                    pallas_kernels.bsi_sum_counts(planes, exists))
-            else:
-                packed = np.asarray(bsi_ops.sum_counts(planes, exists))
+            # count packed as the last row (bsi_ops.sum_counts)
+            packed = np.asarray(bsi_ops.sum_counts(planes, exists))
             counts_per_plane, n = packed[:-1].sum(axis=1), int(packed[-1].sum())
         raw_sum = bsi_ops.counts_to_sum(counts_per_plane)
         # add base back per counted value (val = raw + base*count)
@@ -2189,17 +2181,10 @@ class Executor:
                     [self._row_leaf_dev(index, f.name, VIEW_STANDARD, shards,
                                         rid) for rid, _ in block])
             self.topn_recount_rows += len(block)
-            # one dispatch, one host fetch of the packed counts: over the
-            # leaves where they lie (XLA), or over a slab of them for the
-            # Pallas blocked kernel behind PILOSA_TPU_PALLAS
+            # one dispatch, one host fetch of the packed counts, over the
+            # leaves where they lie
             with tracing.span("dispatch"):
-                if self.runner.use_pallas:
-                    from pilosa_tpu.ops import pallas_kernels
-                    handle = pallas_kernels.topn_counts_packed(
-                        jnp.stack(leaves).reshape(len(leaves), -1),
-                        src_dense.reshape(-1))
-                else:
-                    handle = leaves_counts_packed(leaves, src_dense)
+                handle = leaves_counts_packed(leaves, src_dense)
             with tracing.span("device.wait"):
                 packed = np.asarray(handle)[:, :len(block)]
             with tracing.span("reduce"):
@@ -2386,12 +2371,11 @@ class Executor:
         once from host rows, cached by the residency manager), and each
         level of the cross product is evaluated by the cross_count_matrix
         kernel family — counts[P, R] = popcount(prefix ⊗ axis) fused on
-        device (ops/bitvector.py; sharded psum form in parallel/mesh.py;
-        Pallas blocked form behind PILOSA_TPU_PALLAS). Prefix slabs are
-        never persisted: each chunk's prefix is re-gathered from the
-        component axis slabs and AND-reduced inside the fused dispatch, so
-        device memory stays O(P_CHUNK · S · W) regardless of how many
-        combinations survive.
+        device (ops/bitvector.py; sharded psum form in parallel/mesh.py).
+        Prefix slabs are never persisted: each chunk's prefix is
+        re-gathered from the component axis slabs and AND-reduced inside
+        the fused dispatch, so device memory stays O(P_CHUNK · S · W)
+        regardless of how many combinations survive.
 
         Zero-count pruning runs ON DEVICE (live_from_matrix: jnp.nonzero
         with a static bound + true live count), and chunk dispatches

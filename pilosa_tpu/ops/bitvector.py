@@ -191,34 +191,31 @@ def live_from_matrix(cmat: jax.Array, bound: int):
     return n_live, idx.astype(jnp.int32), counts
 
 
-def chunk_count_matrix(axis_slabs, idx, axis, n_valid,
-                       cross_fn=None) -> jax.Array:
-    """The ONE chunk composition every GroupBy variant traces: gather + AND
-    the prefix slab from the component axes, cross-count against the
-    level's axis slab, mask padding rows. `cross_fn` swaps the matrix
-    kernel (None = the fused XLA form; the Pallas blocked form plugs in
-    here), so the XLA, Pallas, and mesh paths cannot drift apart."""
-    fn = cross_count_matrix if cross_fn is None else cross_fn
-    return mask_prefix_rows(fn(gather_prefix(axis_slabs, idx), axis),
-                            n_valid)
+def chunk_count_matrix(axis_slabs, idx, axis, n_valid) -> jax.Array:
+    """The ONE chunk composition both GroupBy callers trace (the single
+    device programs below, the mesh's shard_map in parallel/mesh.py):
+    gather + AND the prefix slab from the component axes, cross-count
+    against the level's axis slab, mask padding rows."""
+    return mask_prefix_rows(
+        cross_count_matrix(gather_prefix(axis_slabs, idx), axis), n_valid)
 
 
-@counted_jit("groupby", static_argnames=("bound", "cross_fn"))
+@counted_jit("groupby", static_argnames=("bound",))
 def groupby_chunk_live(axis_slabs: tuple, idx: tuple, axis: jax.Array,
-                       n_valid: jax.Array, bound: int, cross_fn=None):
+                       n_valid: jax.Array, bound: int):
     """One pipelined GroupBy level chunk, fully on device: the chunk
     composition plus the zero-prune. Returns device arrays only — the
     executor enqueues every chunk of a level before its single host sync."""
-    cmat = chunk_count_matrix(axis_slabs, idx, axis, n_valid, cross_fn)
+    cmat = chunk_count_matrix(axis_slabs, idx, axis, n_valid)
     return live_from_matrix(cmat, bound)
 
 
-@counted_jit("groupby", static_argnames=("cross_fn",))
+@counted_jit("groupby")
 def groupby_chunk_matrix(axis_slabs: tuple, idx: tuple, axis: jax.Array,
-                         n_valid: jax.Array, cross_fn=None) -> jax.Array:
+                         n_valid: jax.Array) -> jax.Array:
     """Dense [chunk, R] count matrix for one chunk — the overflow fallback
     when a chunk's live combinations exceed the pruning bound."""
-    return chunk_count_matrix(axis_slabs, idx, axis, n_valid, cross_fn)
+    return chunk_count_matrix(axis_slabs, idx, axis, n_valid)
 
 
 # ---------------------------------------------------------------------------

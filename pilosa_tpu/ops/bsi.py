@@ -169,23 +169,16 @@ def _compare(planes, exists, pred_bits, op):
 _compare = counted_jit("bsi", static_argnames=("op",))(_compare)
 
 
-def compare(planes: jax.Array, exists: jax.Array, pred_bits, op: str,
-            pallas: bool = False) -> jax.Array:
+def compare(planes: jax.Array, exists: jax.Array, pred_bits,
+            op: str) -> jax.Array:
     """Dense bitvector of rows (columns) whose BSI value satisfies `op pred`.
 
     BETWEEN is composed by the caller as GTE(a) & LTE(b), matching the
-    reference's executeRangeBetweenShard (executor.go) semantics.
-
-    `pallas` selects the blocked Pallas sweep (ops/pallas_kernels.py
-    bsi_compare: matched/remaining pinned in VMEM across the depth
-    unroll) — the executor passes its PILOSA_TPU_PALLAS gate; requires
-    the [depth, S, W] layout. The XLA form takes any batch shape."""
+    reference's executeRangeBetweenShard (executor.go) semantics. Takes
+    any batch shape: planes [depth, ..., W], exists [..., W]."""
     pred_bits = jnp.asarray(pred_bits, dtype=jnp.int32)
     if pred_bits.shape[0] != planes.shape[0]:
         raise ValueError("pred_bits length must equal plane depth")
-    if pallas and planes.ndim == 3:
-        from pilosa_tpu.ops import pallas_kernels
-        return pallas_kernels.bsi_compare(planes, exists, pred_bits, op)
     return _compare(planes, exists, pred_bits, op)
 
 

@@ -67,8 +67,7 @@ def tanimoto_counts_packed(rows: jax.Array, src: jax.Array) -> jax.Array:
     int32[3, R] with [0] = |row ∩ src|, [1] = |row|, [2] = |src|
     broadcast. The popcount-audit form (arXiv:1611.07612's fused-harvest
     idea applied at the dispatch level): the three separate popcounts of
-    tanimoto_counts cost three device round trips on high-latency links.
-    The Pallas twin is ops/pallas_kernels.topn_counts_packed."""
+    tanimoto_counts cost three device round trips on high-latency links."""
     inter = popcount(jnp.bitwise_and(rows, src[None]))
     rcounts = popcount(rows)
     scount = popcount(src)

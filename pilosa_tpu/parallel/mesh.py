@@ -377,9 +377,7 @@ def scatter_queries(mesh: Mesh, ii: np.ndarray, jj: np.ndarray):
     """Shared replica-scatter scaffolding for query streams: pads K to a
     multiple of the replica count with (0, 0) no-op queries (dropped after
     gather) and places ii/jj sharded over the replica axis (replicated on
-    a 1-D shard mesh). Returns (ii_dev, jj_dev, real_k, rep_spec) — used
-    by both the XLA and Pallas stream kernels so padding semantics cannot
-    diverge."""
+    a 1-D shard mesh). Returns (ii_dev, jj_dev, real_k, rep_spec)."""
     n_rep = mesh.shape.get(REPLICA_AXIS, 1)
     rep_spec = P(REPLICA_AXIS) if REPLICA_AXIS in mesh.shape else P()
     k = ii.shape[0]
@@ -446,12 +444,11 @@ def _pair_stream_fn(mesh: Mesh):
 # over the shard axis — the [P, R, S] intermediate never crosses devices
 # and the zero-prune runs on the replicated [P, R] result. The replica
 # axis (if any) holds full data copies, so every replica computes the same
-# matrix (same pattern as _program_count_mesh_fn).
+# matrix.
 
 
 @functools.lru_cache(maxsize=None)
-def _groupby_cmat_mesh_fn(mesh: Mesh, n_axes: int, use_pallas: bool):
-    cross_fn = _pallas_cross_fn() if use_pallas else None
+def _groupby_cmat_mesh_fn(mesh: Mesh, n_axes: int):
     slab_spec = P(None, SHARD_AXIS, None)
 
     @jax.jit
@@ -464,38 +461,29 @@ def _groupby_cmat_mesh_fn(mesh: Mesh, n_axes: int, use_pallas: bool):
         # the shared chunk composition on the local shard slice (masked
         # padding rows are zero on every device, so masking commutes with
         # the psum), then one ICI all-reduce over the shard axis
-        local = chunk_count_matrix(axis_slabs, idx, axis, n_valid, cross_fn)
+        local = chunk_count_matrix(axis_slabs, idx, axis, n_valid)
         return jax.lax.psum(local, SHARD_AXIS)
 
     return run
 
 
-def _pallas_cross_fn():
-    from pilosa_tpu.ops.pallas_kernels import cross_count_matrix
-
-    return cross_count_matrix
+def groupby_chunk_matrix_mesh(mesh: Mesh, axis_slabs: tuple, idx: tuple,
+                              axis: jax.Array, n_valid) -> jax.Array:
+    """Sharded groupby_chunk_matrix: per-device partial [P, R] counts, one
+    ICI psum. A device array — no host sync."""
+    record_dispatch("groupby_mesh", mesh, len(idx),
+                    tuple(axis_slabs), tuple(idx), axis)
+    return _groupby_cmat_mesh_fn(mesh, len(idx))(
+        tuple(axis_slabs), tuple(idx), axis, n_valid)
 
 
 def groupby_chunk_live_mesh(mesh: Mesh, axis_slabs: tuple, idx: tuple,
-                            axis: jax.Array, n_valid, bound: int,
-                            use_pallas: bool = False):
-    """Sharded groupby_chunk_live: per-device partial [P, R] counts, one
-    ICI psum, on-device prune. Returns device arrays — no host sync."""
-    record_dispatch("groupby_mesh", mesh, len(idx), use_pallas,
-                    tuple(axis_slabs), tuple(idx), axis)
-    cmat = _groupby_cmat_mesh_fn(mesh, len(idx), use_pallas)(
-        tuple(axis_slabs), tuple(idx), axis, n_valid)
-    return live_from_matrix(cmat, bound)
-
-
-def groupby_chunk_matrix_mesh(mesh: Mesh, axis_slabs: tuple, idx: tuple,
-                              axis: jax.Array, n_valid,
-                              use_pallas: bool = False) -> jax.Array:
-    """Dense mesh count matrix — the overflow fallback's sharded form."""
-    record_dispatch("groupby_mesh", mesh, len(idx), use_pallas,
-                    tuple(axis_slabs), tuple(idx), axis)
-    return _groupby_cmat_mesh_fn(mesh, len(idx), use_pallas)(
-        tuple(axis_slabs), tuple(idx), axis, n_valid)
+                            axis: jax.Array, n_valid, bound: int):
+    """Sharded groupby_chunk_live: the mesh count matrix, pruned on
+    device. Returns device arrays — no host sync."""
+    return live_from_matrix(
+        groupby_chunk_matrix_mesh(mesh, axis_slabs, idx, axis, n_valid),
+        bound)
 
 
 # -- TopN recount from sorted columns, mesh form ------------------------------
@@ -531,16 +519,8 @@ class DeviceRunner:
     """
 
     def __init__(self, mesh: Optional[Mesh] = None,
-                 use_pallas: Optional[bool] = None,
                  ici_serving: Optional[bool] = None):
         self.mesh = mesh
-        if use_pallas is None:
-            use_pallas = os.environ.get("PILOSA_TPU_PALLAS", "").lower() in (
-                "1", "true", "yes", "on")
-        # with a mesh the Pallas kernels run under shard_map (each device
-        # blocks over its local shards, partials psum on ICI — see
-        # pallas_kernels.program_count_mesh)
-        self.use_pallas = bool(use_pallas)
         # ICI-native serving kernels: general bitmap programs run as
         # explicit shard_map + psum programs from the per-mesh program
         # cache (eval_count_mesh / eval_row_mesh) instead of relying on
@@ -662,26 +642,13 @@ class DeviceRunner:
         # EXCEPT under "not", which complements pad shards to all-ones; the
         # executor always masks Not() through the existence row (itself a
         # leaf with zero pad shards), keeping pad contributions at zero.
-        if self.use_pallas:
-            # explicitly-blocked Pallas kernel: whole program + popcount in
-            # VMEM, no HBM intermediates (PILOSA_TPU_PALLAS=1; parity with
-            # the XLA path is tested in tests/test_pallas.py). Under a mesh
-            # the same kernel runs per-device via shard_map + ICI psum.
-            from pilosa_tpu.ops.pallas_kernels import (
-                program_count,
-                program_count_mesh,
-            )
-
-            if self.mesh is not None:
-                return program_count_mesh(self.mesh, tuple(leaves), program)
-            return jnp.sum(program_count(tuple(leaves), program))
         if self.mesh is not None and self.ici_serving:
             # explicit shard_map + psum serving form: per-device partial
             # counts over the local shard slice, one ICI all-reduce
             return eval_count_mesh(self.mesh, tuple(leaves), program)
         return eval_count_total(tuple(leaves), program)
 
-    # -- GroupBy cross-count dispatch (XLA / Pallas / mesh routing) --------
+    # -- GroupBy cross-count dispatch (single device / mesh routing) -------
 
     def groupby_chunk(self, axis_slabs, idx, axis, n_valid, bound: int):
         """(n_live, flat_idx[bound], counts[bound]) device arrays for one
@@ -690,10 +657,8 @@ class DeviceRunner:
         axis_slabs, idx = tuple(axis_slabs), tuple(idx)
         if self.mesh is not None:
             return groupby_chunk_live_mesh(self.mesh, axis_slabs, idx, axis,
-                                           n_valid, bound, self.use_pallas)
-        cross_fn = _pallas_cross_fn() if self.use_pallas else None
-        return groupby_chunk_live(axis_slabs, idx, axis, n_valid, bound,
-                                  cross_fn)
+                                           n_valid, bound)
+        return groupby_chunk_live(axis_slabs, idx, axis, n_valid, bound)
 
     def groupby_cmat(self, axis_slabs, idx, axis, n_valid) -> jax.Array:
         """Dense [chunk, R] count matrix (device array) — the fallback when
@@ -701,6 +666,5 @@ class DeviceRunner:
         axis_slabs, idx = tuple(axis_slabs), tuple(idx)
         if self.mesh is not None:
             return groupby_chunk_matrix_mesh(self.mesh, axis_slabs, idx,
-                                             axis, n_valid, self.use_pallas)
-        cross_fn = _pallas_cross_fn() if self.use_pallas else None
-        return groupby_chunk_matrix(axis_slabs, idx, axis, n_valid, cross_fn)
+                                             axis, n_valid)
+        return groupby_chunk_matrix(axis_slabs, idx, axis, n_valid)

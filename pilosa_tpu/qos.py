@@ -45,8 +45,7 @@ Modes (`[qos] mode`): `off` (default — zero behavior change), `observe`
 rejected: the safe rollout step), `enforce`. `PILOSA_TPU_QOS=0` is the
 env kill switch over everything including the priority plumbing.
 
-Disabled cost: one env check (+ one ContextVar.get on priority sites) —
-bench.py's `qos` stage pins the admission-path overhead budget (<= 1%).
+Disabled cost: one env check (+ one ContextVar.get on priority sites).
 """
 
 from __future__ import annotations

@@ -26,8 +26,8 @@ act on:
   `slo/*` gauges and the shared health_score.
 
 Disabled cost: one ContextVar.get() returning None per charge site (the
-profiler's nop-fast-path discipline; bench.py's `accounting` stage pins
-the overhead budget). `PILOSA_TPU_ACCOUNTING=0` is the kill switch.
+profiler's nop-fast-path discipline). `PILOSA_TPU_ACCOUNTING=0` is the
+kill switch.
 """
 
 from __future__ import annotations

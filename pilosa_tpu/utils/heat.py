@@ -34,10 +34,9 @@ steer by:
   hot/cold split applied to HBM residency).
 
 Disabled cost: one attribute check per charge site (the profiler's
-nop-fast-path discipline; bench.py's `heat` stage pins the enabled
-overhead <= 1%). `PILOSA_TPU_HEAT=0` is the kill switch: no tracker is
-built, every charge site short-circuits, and residency eviction is
-forced back to `lru`.
+nop-fast-path discipline). `PILOSA_TPU_HEAT=0` is the kill switch: no
+tracker is built, every charge site short-circuits, and residency
+eviction is forced back to `lru`.
 """
 
 from __future__ import annotations

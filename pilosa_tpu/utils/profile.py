@@ -28,8 +28,8 @@ the SAME profile object), and every layer appends its attribution record:
     grafts them under the fan-out records — a cross-node profile TREE.
 
 Disabled cost: one ContextVar.get() returning None per instrumentation
-site (the nop fast path — asserted by bench.py's profiler overhead A/B).
-Nothing allocates, locks, or formats unless a profile is installed.
+site (the nop fast path). Nothing allocates, locks, or formats unless a
+profile is installed.
 """
 
 from __future__ import annotations

@@ -389,7 +389,7 @@ class KernelStats:
     timings, so /metrics renders them as proper cumulative histograms.
 
     Disabled cost (PILOSA_TPU_KERNEL_STATS=0): one env read per
-    dispatch — asserted ≤1% by bench.py's device_obs A/B."""
+    dispatch."""
 
     def __init__(self):
         self._lock = threading.Lock()

@@ -206,7 +206,9 @@ def test_cli_baseline_suppresses_but_check_reports(tmp_path):
     docs.mkdir()
     with open(os.path.join(ROOT, "docs", "operations.md"),
               encoding="utf-8") as f:
-        (docs / "operations.md").write_text(f.read())
+        # less the env table's rows: this package reads none of them
+        (docs / "operations.md").write_text("".join(
+            line for line in f if not line.startswith("| `PILOSA_TPU_")))
     baseline = tmp_path / "baseline.txt"
     baseline.write_text("# incident hotfix\npilosa_tpu/bad.py:ctx-thread\n")
     proc = subprocess.run(
@@ -225,6 +227,27 @@ def test_env_gate_inventory_sees_known_gates():
     assert "PILOSA_TPU_QOS" in inv
     assert "PILOSA_TPU_WAL_FSYNC" in inv
     assert env_gate_findings(ROOT) == []
+
+
+def test_env_gate_listed_but_unread_is_a_finding(tmp_path):
+    """The other direction: a switch deleted from the code and left in
+    the docs' env table is a finding at the table's row; a name the prose
+    merely mentions is not."""
+    pkg = tmp_path / "pilosa_tpu"
+    pkg.mkdir()
+    (pkg / "__init__.py").write_text(
+        'import os\nON = os.environ.get("PILOSA_TPU_KEPT", "1")\n')
+    docs = tmp_path / "docs"
+    docs.mkdir()
+    (docs / "operations.md").write_text(
+        "| Env | Default | Meaning |\n|---|---|---|\n"
+        "| `PILOSA_TPU_KEPT` | 1 | read |\n"
+        "| `PILOSA_TPU_GONE` | 0 | nothing reads this |\n"
+        "\n`PILOSA_TPU_CLUSTER_REPLICAS` is the pattern of a config field.\n")
+    findings = env_gate_findings(str(tmp_path))
+    assert [(f.path, f.line, f.rule) for f in findings] == [
+        ("docs/operations.md", 4, "env-gate-docs")]
+    assert "PILOSA_TPU_GONE" in findings[0].msg
 
 
 def test_config_knob_inventory_complete():

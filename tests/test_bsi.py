@@ -72,6 +72,33 @@ def test_min_max_empty_candidate(data):
     assert int(cnt) == 0
 
 
+def _pack(mask):
+    """bool[..., n] -> uint32[..., n // 32], column 32 w + i at bit i of
+    word w (the layout of bv.dense_from_columns)."""
+    return np.packbits(mask, axis=-1, bitorder="little").view(np.uint32)
+
+
+@pytest.fixture(scope="module")
+def compare_sets(data):
+    """name -> (values int64[..., columns], planes, exists, depth): the
+    module's scattered values (`data`, one row of words a plane), and
+    depth 6 over [3, 640] words (ragged shard and word counts) with a
+    value in every column, the planes packed from the values, once under
+    a full and once under a random existence mask."""
+    values, planes, exists = data
+    flat = np.zeros(WIDTH, dtype=np.int64)
+    flat[list(values)] = list(values.values())
+    rng = np.random.default_rng(11)
+    vals = rng.integers(0, 1 << 6, size=(3, 640 * 32), dtype=np.int64)
+    packed = np.stack([_pack(((vals >> i) & 1).astype(bool))
+                       for i in range(6)])
+    full = np.full((3, 640), 0xFFFFFFFF, dtype=np.uint32)
+    masked = rng.integers(0, 2**32, size=(3, 640), dtype=np.uint32)
+    return {"scattered": (flat, planes, exists, DEPTH),
+            "packed": (vals, packed, full, 6),
+            "packed_masked": (vals, packed, masked, 6)}
+
+
 @pytest.mark.parametrize("op,pyop", [
     (bsi.LT, lambda v, p: v < p),
     (bsi.LTE, lambda v, p: v <= p),
@@ -80,13 +107,32 @@ def test_min_max_empty_candidate(data):
     (bsi.EQ, lambda v, p: v == p),
     (bsi.NEQ, lambda v, p: v != p),
 ])
-@pytest.mark.parametrize("pred", [0, 1, 1000, (1 << DEPTH) - 1, 2048])
-def test_compare(data, op, pyop, pred):
-    values, planes, exists = data
-    pred_bits = bsi.value_to_bits(pred, DEPTH)
-    got = set(bv.columns_from_dense(np.asarray(bsi.compare(planes, exists, pred_bits, op))).tolist())
-    expect = {c for c, v in values.items() if pyop(v, pred)}
-    assert got == expect
+@pytest.mark.parametrize("name,pred", [
+    ("scattered", p) for p in (0, 1, 1000, (1 << DEPTH) - 1, 2048)
+] + [(name, p) for name in ("packed", "packed_masked")
+     for p in (0, 1, 17, 63)])
+def test_compare(compare_sets, name, op, pyop, pred):
+    """Every op against numpy on the values the planes were built from;
+    no column outside the existence row ever matches."""
+    values, planes, exists, depth = compare_sets[name]
+    pred_bits = bsi.value_to_bits(pred, depth)
+    got = np.asarray(bsi.compare(planes, exists, pred_bits, op))
+    np.testing.assert_array_equal(got, _pack(pyop(values, pred)) & exists)
+
+
+@pytest.mark.parametrize("depth,s,w", [(1, 1, 512), (8, 3, 640),
+                                       (24, 9, 512)])
+def test_sum_counts_matches_numpy(depth, s, w):
+    """Packed int32[depth + 1, S]: a row a plane of popcounts under the
+    filter, the filter's own count last."""
+    rng = np.random.default_rng(depth)
+    planes = rng.integers(0, 2**32, size=(depth, s, w), dtype=np.uint32)
+    filt = rng.integers(0, 2**32, size=(s, w), dtype=np.uint32)
+    got = np.asarray(bsi.sum_counts(planes, filt))
+    expect = np.concatenate([
+        np.bitwise_count(planes & filt[None]).sum(axis=-1),
+        np.bitwise_count(filt).sum(axis=-1)[None]])
+    np.testing.assert_array_equal(got, expect)
 
 
 def test_between(data):

@@ -234,3 +234,58 @@ def test_mesh_vs_single_device_agreement(tmp_path):
         results[mode] = {k: list(v) for k, v in out.items()}
         h.close()
     assert results["single"] == results["mesh"] == results["replica_mesh"]
+
+
+# ------------------------------------------------- the kernels by themselves
+
+
+@pytest.mark.parametrize("p,r,s,w", [
+    (1, 1, 1, 512), (5, 7, 1, 512), (8, 128, 1, 1024), (9, 130, 1, 512),
+    (6, 9, 3, 512),
+])
+def test_cross_count_matrix_matches_numpy(p, r, s, w):
+    """counts[P, R] of [P, S, W] against [R, S, W] over ragged prefix,
+    row, shard and word counts."""
+    from pilosa_tpu.ops.bitvector import cross_count_matrix
+
+    rng = np.random.default_rng(21)
+    a = rng.integers(0, 2**32, size=(p, s, w), dtype=np.uint32)
+    b = rng.integers(0, 2**32, size=(r, s, w), dtype=np.uint32)
+    got = np.asarray(cross_count_matrix(a, b))
+    expect = np.bitwise_count(a[:, None] & b[None]).sum(axis=(-2, -1))
+    np.testing.assert_array_equal(got, expect)
+
+
+@pytest.mark.parametrize("replicas", [None, 1, 2],
+                         ids=["single", "mesh", "replica_mesh"])
+def test_groupby_chunk_contract(replicas):
+    """DeviceRunner.groupby_chunk's (n_live, flat_idx, counts) — gather,
+    AND, cross count, mask of the padding rows, on-device prune — and the
+    dense matrix of the overflow fallback, against a numpy count matrix;
+    on a mesh the per-device partials meet in one psum over the shard
+    axis."""
+    import jax
+    import jax.numpy as jnp
+
+    runner = DeviceRunner(replicas and make_mesh(replicas=replicas))
+    rng = np.random.default_rng(23)
+    host_a = rng.integers(0, 2**32, size=(6, 4, 512), dtype=np.uint32)
+    host_b = rng.integers(0, 2**32, size=(5, 4, 512), dtype=np.uint32)
+    host_b[3] = 0  # an axis row no prefix meets: pruned on the device
+    pick = np.array([0, 2, 5, 0], dtype=np.int32)  # the last is padding
+    idx = (jnp.asarray(pick),)
+    slab_a = runner.put_plane_slab(host_a)
+    slab_b = runner.put_plane_slab(host_b)
+    cmat = np.bitwise_count(
+        host_a[pick[:3]][:, None] & host_b[None]).reshape(3, 5, -1).sum(-1)
+    lp, lr = np.nonzero(cmat)
+    n_live, flat_idx, counts = jax.device_get(runner.groupby_chunk(
+        (slab_a,), idx, slab_b, jnp.int32(3), 30))
+    assert int(n_live) == lp.size == 12
+    np.testing.assert_array_equal(flat_idx[:lp.size], lp * 5 + lr)
+    np.testing.assert_array_equal(counts[:lp.size], cmat[lp, lr])
+    assert (flat_idx[lp.size:] == 4 * 5).all() and not counts[lp.size:].any()
+    dense = np.asarray(runner.groupby_cmat((slab_a,), idx, slab_b,
+                                           jnp.int32(3)))
+    np.testing.assert_array_equal(dense[:3], cmat)
+    assert not dense[3].any()

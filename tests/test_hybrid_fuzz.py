@@ -17,9 +17,7 @@ the dense oracle. Any divergence — results, or error-vs-result
 behavior — is a hybrid bug.
 
 A final phase flips the PILOSA_TPU_HYBRID=0 kill switch at runtime and
-asserts the hybrid executor immediately behaves purely dense; a Pallas
-phase re-runs the parity with PILOSA_TPU_PALLAS-style kernels on
-(interpret mode off-TPU).
+asserts the hybrid executor immediately behaves purely dense.
 """
 
 import numpy as np
@@ -235,34 +233,3 @@ def test_zero_threshold_restores_pure_dense(setup):
         assert hybrid.hybrid.snapshot()["sparseUploads"] == before
     finally:
         hybrid.hybrid.threshold = old
-
-
-def test_pallas_executor_threeway_parity(setup):
-    """The Pallas kernel family (interpret mode off-TPU) under the same
-    three-way hybrid config: a fresh Pallas-on executor against the
-    plain dense XLA oracle, with the runny rows healed first so the run
-    representation is actually in play. Rounds are short — interpret
-    mode runs the kernel body in Python."""
-    from pilosa_tpu.parallel.mesh import DeviceRunner
-
-    h, hybrid, plain, rng = setup
-    idx = h.index("z")
-    for fname in FIELDS:  # heal: contiguous block -> few intervals
-        base = RUNNY_BASE[fname]
-        cols = np.arange(base, base + RUNNY_LEN)
-        idx.field(fname).import_bits([RUNNY_ROW] * len(cols),
-                                     cols.tolist())
-    hp = Executor(h, runner=DeviceRunner(use_pallas=True))
-    hp.hybrid.threshold = THRESHOLD
-    hp.hybrid.run_threshold = RUN_THRESHOLD
-    assert hp.hybrid.active()
-    # force run-leaf traffic, then randomized trees + a TopN (the
-    # fused popcount-rank Pallas path)
-    _both(hp, plain,
-          f"Count(Intersect(Row(f={RUNNY_ROW}), Row(g={RUNNY_ROW})))")
-    _both(hp, plain, f"Union(Row(f={RUNNY_ROW}), Row(g=0))")
-    for _ in range(6):
-        _both(hp, plain, _rand_query(rng))
-    _both(hp, plain, f"TopN(f, Row(f={RUNNY_ROW}), n=4)")
-    _both(hp, plain, "TopN(g, Union(Row(g=0), Row(g=1)), n=4)")
-    assert hp.hybrid.snapshot()["runUploads"] > 0

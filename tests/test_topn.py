@@ -2,6 +2,7 @@
 fragment_internal_test.go top/TopN cases)."""
 
 import numpy as np
+import pytest
 
 from pilosa_tpu.ops import bitvector as bv
 from pilosa_tpu.ops import topn
@@ -62,6 +63,22 @@ def test_tanimoto():
         # threshold is dropped
         t = 100 * len(c & ssrc) > thr * (len(c) + len(ssrc) - len(c & ssrc))
         assert bool(mask[i]) == t
+
+
+@pytest.mark.parametrize("r,w", [(1, 512), (8, 2048), (100, 2048),
+                                 (130, 4096)])
+def test_tanimoto_counts_packed_matches_numpy(r, w):
+    """Packed int32[3, R] = (|row ∩ src|, |row|, |src| broadcast): the one
+    dispatch, one fetch form of tanimoto_counts."""
+    rng = np.random.default_rng(r)
+    rows = rng.integers(0, 2**32, size=(r, w), dtype=np.uint32)
+    src = rng.integers(0, 2**32, size=(w,), dtype=np.uint32)
+    got = np.asarray(topn.tanimoto_counts_packed(rows, src))
+    assert got.shape == (3, r)
+    np.testing.assert_array_equal(
+        got[0], np.bitwise_count(rows & src).sum(axis=1))
+    np.testing.assert_array_equal(got[1], np.bitwise_count(rows).sum(axis=1))
+    assert np.all(got[2] == np.bitwise_count(src).sum())
 
 
 # ---------------------------------------------------------------------------
@@ -151,31 +168,6 @@ def test_topn_src_walk_prunes_and_matches_naive(tmp_path):
     assert list(top) == [(rid, c) for rid, c in expect]
     # pruning: the walk must stop well before materializing all 800 rows
     assert ex.topn_recount_rows < n_rows, ex.topn_recount_rows
-    ex.holder.close()
-
-
-def test_pallas_count_flag_parity(tmp_path):
-    """PILOSA_TPU_PALLAS routes Count() through the Pallas program_count
-    kernel; results must match the XLA path exactly."""
-    from pilosa_tpu.executor import Executor
-    from pilosa_tpu.parallel.mesh import DeviceRunner
-
-    ex = _make_executor(tmp_path)
-    idx = ex.holder.create_index("i")
-    f = idx.create_field("f")
-    rng = np.random.default_rng(5)
-    for rid in (1, 2):
-        cols = np.unique(rng.integers(0, 1 << 16, size=3000))
-        f.import_bits([rid] * len(cols), cols.tolist())
-
-    plain = ex.execute("i", "Count(Intersect(Row(f=1), Row(f=2)))")[0]
-    ex_pallas = Executor(ex.holder, runner=DeviceRunner(use_pallas=True))
-    assert ex_pallas.runner.use_pallas
-    fused = ex_pallas.execute("i", "Count(Intersect(Row(f=1), Row(f=2)))")[0]
-    assert fused == plain > 0
-    # union+andnot program shape too
-    q = "Count(Difference(Union(Row(f=1), Row(f=2)), Row(f=1)))"
-    assert ex_pallas.execute("i", q)[0] == ex.execute("i", q)[0]
     ex.holder.close()
 
 
