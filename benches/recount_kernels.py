@@ -33,7 +33,13 @@ at that column. Variants timed here:
                     by pairs and by column; with the bytes of temporaries
                     the compiled program allocates
 
+--crossover times the shipped program alone, in both layouts of one entry
+at 2^10 ... 2^19 slots a shard under a count vector of 128 (H = 1) and of
+16,384 (H = 128): the table ops/bitvector.py PAIRS_BY_COLUMN_SLOTS was set
+from (PR 32), the slots from which an entry that may lie by column does.
+
     chiprun -- python3 benches/recount_kernels.py            # the chip
+    chiprun -- python3 benches/recount_kernels.py --crossover
     python3 benches/recount_kernels.py --shards 2 --slots 8192 --rows 300
 """
 
@@ -87,6 +93,61 @@ def oracle(cols, rank, src, R):
     return out[:R]
 
 
+def timed(fn, args, check, reps):
+    """Compile and two warm calls, then the median of `reps` fetches."""
+    t0 = time.perf_counter()
+    got = np.asarray(fn(*args))
+    first = time.perf_counter() - t0
+    ok = None if check is None else bool((got == check).all())
+    np.asarray(fn(*args))     # a second warm call before the timed ones
+    ts = []
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        np.asarray(fn(*args))
+        ts.append((time.perf_counter() - t0) * 1e3)
+    return {"ms_min": min(ts), "ms_median": sorted(ts)[len(ts) // 2],
+            "first_s": first, "agrees": ok}
+
+
+def crossover(args):
+    """The shipped program in both layouts of one entry, over the slots a
+    shard and the count vector: one line a (H, slots), then the table."""
+    import jax
+
+    from pilosa_tpu.ops import bitvector as bv
+    S = args.shards
+    out = {"device": jax.devices()[0].device_kind, "shards": S, "rows": []}
+    for R in (31, 9966):
+        n_slots = bv.pairs_count_slots(R)
+        for lg in range(args.min_log_slots, 20):
+            K = 1 << lg
+            cols, rank, _, _, src = make(32 + lg, S, K, R, 0.73, 0.05)
+            want = oracle(cols, rank, src, R)
+            kept = [(cols[s][cols[s] < C], rank[s][cols[s] < C])
+                    for s in range(S)]
+            src = jax.device_put(src)
+            row = {"H": n_slots // 128, "slots": K,
+                   "bits": int((cols < C).sum())}
+            for layout, arr in (("pairs", np.stack([cols, rank])),
+                                ("column", bv.pairs_by_column(kept))):
+                arr = jax.device_put(arr)
+                row[layout] = timed(
+                    lambda p, f: bv.pairs_count(p, f, n_slots)[:R],
+                    (arr, src), want, args.reps)
+                del arr
+            print(json.dumps(row), flush=True)
+            out["rows"].append(row)
+    print("| H | slots a shard | by pairs ms | by column ms | ns a slot by "
+          "pairs | agrees |\n|---|---|---|---|---|---|")
+    for r in out["rows"]:
+        p, c = r["pairs"], r["column"]
+        print(f"| {r['H']} | 2^{r['slots'].bit_length() - 1} | "
+              f"{p['ms_median']:.2f} | {c['ms_median']:.2f} | "
+              f"{p['ms_median'] * 1e6 / (S * r['slots']):.2f} | "
+              f"{p['agrees'] and c['agrees']} |")
+    print(json.dumps(out))
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--shards", type=int, default=32)
@@ -101,7 +162,14 @@ def main():
                     "128, tried and not shipped")
     ap.add_argument("--only", default="",
                     help="time only the variants whose name holds this")
+    ap.add_argument("--crossover", action="store_true",
+                    help="only the shipped program, by pairs against by "
+                    "column, over the slots a shard at H = 1 and H = 128")
+    ap.add_argument("--min-log-slots", type=int, default=10,
+                    help="--crossover starts at 2^this slots a shard")
     args = ap.parse_args()
+    if args.crossover:
+        return crossover(args)
     import jax
     import jax.numpy as jnp
     S, K, R = args.shards, args.slots, args.rows
@@ -259,18 +327,7 @@ def main():
         if args.only not in name:
             continue
         a = [dev[k] for k in keys]
-        t0 = time.perf_counter()
-        got = np.asarray(fn(*a))
-        first = time.perf_counter() - t0
-        ok = None if check is None else bool((got == check).all())
-        np.asarray(fn(*a))     # a second warm call before the timed ones
-        ts = []
-        for _ in range(args.reps):
-            t0 = time.perf_counter()
-            np.asarray(fn(*a))
-            ts.append((time.perf_counter() - t0) * 1e3)
-        out[name] = {"ms_min": min(ts), "ms_median": sorted(ts)[len(ts) // 2],
-                     "first_s": first, "agrees": ok}
+        out[name] = timed(fn, a, check, args.reps)
         if fn is shipped:
             mem = bv.pairs_count.lower(*a, n_slots).compile(
                 ).memory_analysis()

@@ -82,6 +82,7 @@ class PairsEntry:
     def __init__(self, dev, ids: np.ndarray, stored: np.ndarray):
         self.dev, self.ids, self.stored = dev, ids, stored
         self.nbytes = dev.nbytes
+        self.by_column = dev.ndim == 4
 
 
 class Pairs(list):
@@ -150,8 +151,11 @@ class Executor:
         # launches of the pairs kernel; the bytes they had to read, from
         # the data and the request (4 a stored bit of the rows recounted,
         # 128 KiB a shard for the filter plane), not from what was
-        # uploaded; entries built and the bytes they hold on the device
+        # uploaded; of the launches, those over an entry laid by column
+        # (nothing gathered); entries built and the bytes they hold on
+        # the device
         self.topn_pairs_recounts = 0
+        self.topn_pairs_recounts_by_column = 0
         self.topn_pairs_bytes = 0
         self.pairs_entries_built = 0
         self.pairs_entry_bytes = 0
@@ -684,16 +688,47 @@ class Executor:
         if gens is None:
             gens = self._leaf_gens(index, field_name, view_name, shards,
                                    row_id)
-        key = ("row", index.name, field_name, view_name, row_id,
-               tuple(shards), gens)
+        self._touch_reads(index, field_name, view_name, shards, 1)
+        return self._row_leaf_at(index, field_name, view_name, shards,
+                                 row_id, gens)
+
+    def _touch_reads(self, index: Index, field_name: str, view_name: str,
+                     shards, reads: int) -> None:
+        """Read heat at the fragment coordinate, one lock round trip for
+        the whole shard set (every consumer of row leaves — bitmap
+        programs, BSI planes, TopN recounts, GroupBy slabs — funnels
+        through _row_leaf_dev / _row_leaves_dev, so this is THE read
+        charge site)."""
         tracker = self.heat
         if tracker is not None and tracker.enabled:
-            # read heat at the fragment coordinate, one lock round trip
-            # for the whole shard set (every consumer of row leaves —
-            # bitmap programs, BSI planes, TopN recounts, GroupBy slabs —
-            # funnels through here, so this is THE read charge site)
             tracker.touch_many([(index.name, field_name, view_name, s)
-                                for s in shards], reads=1)
+                                for s in shards], reads=reads)
+
+    def _row_leaves_dev(self, index: Index, field_name: str, view_name: str,
+                        shards, row_ids) -> list:
+        """_row_leaf_dev for a block of one field's rows (a TopN recount
+        block): the same leaves under the same keys, with what does not
+        depend on the row done once a block — every shard's fragment
+        looked up once, and the block's reads charged to each fragment in
+        one touch (`reads` adds up: len(row_ids) at once is what that many
+        touches of one leave) — where a row at a time costs rows × shards
+        lookups and heat updates under the tracker's one lock."""
+        f = index.field(field_name)
+        view = f.view(view_name) if f else None
+        frags = [] if view is None else [view.fragment(s) for s in shards]
+        self._touch_reads(index, field_name, view_name, shards,
+                          len(row_ids))
+        return [self._row_leaf_at(
+            index, field_name, view_name, shards, rid,
+            tuple(0 if fr is None else fr.row_generation(rid)
+                  for fr in frags)) for rid in row_ids]
+
+    def _row_leaf_at(self, index: Index, field_name: str, view_name: str,
+                     shards, row_id: int, gens: tuple):
+        """The resident leaf of one row at these generations (the part of
+        _row_leaf_dev below the key)."""
+        key = ("row", index.name, field_name, view_name, row_id,
+               tuple(shards), gens)
 
         def make():
             hyb = self.hybrid
@@ -2007,17 +2042,22 @@ class Executor:
         where the hybrid representation is off: every row whose fullest
         shard holds no more bits than the sparse threshold (the number
         planner.choose_representation goes by; no hysteresis, the entry is
-        rebuilt with its generations), as sorted columns with the row's
-        rank beside each or, where no column holds two of the rows and
-        that is no larger, as one rank a column, bit-major (ops/bitvector.py
-        pairs_count takes either). One entry a
+        rebuilt with its generations), in the layout whose recount is the
+        faster (ops/bitvector.py pairs_count takes either). By column, one
+        rank a column laid bit-major, 4 MiB a shard whatever the rows
+        hold: where no column holds two of the rows, the sorted columns
+        would take PAIRS_BY_COLUMN_SLOTS slots a shard or more (from
+        there the gather by pairs costs more than the pass over every
+        column) and 4 MiB a shard fit a quarter of the residency budget.
+        By pairs, sorted columns with the row's rank beside each, 8 bytes
+        a slot: every other field. One entry a
         (field, view, shard set, fragment generations), built once however
         many threads ask (DeviceResidency.leaf is single-flight), charged
         to the residency budget like any leaf; a write to the field bumps
         a fragment's generation, so the next TopN builds anew and the old
         entry ages out. A field whose small rows would take more than a
-        quarter of the budget gets an entry of no rows, and its TopN the
-        dense walk."""
+        quarter of the budget in either layout gets an entry of no rows,
+        and its TopN the dense walk."""
         hyb = self.hybrid
         view = f.view(VIEW_STANDARD)
         if hyb is None or not hyb.active() or view is None:
@@ -2058,14 +2098,18 @@ class Executor:
                     kept.append((cols[hit], at[hit].astype(np.int32)))
                 slots = hyb.pad_slots(max(
                     [c.size for c, _ in kept] + [1]))
-                if len(shards) * slots * 8 > self.residency.budget // 4:
+                quarter = self.residency.budget // 4
+                # by column where that is the faster recount, its 4 MiB a
+                # shard fit and the data allows it (no column holds two
+                # of the rows); else by pairs, where those fit
+                by_column = (
+                    slots >= bv.PAIRS_BY_COLUMN_SLOTS
+                    and len(shards) * SHARD_WIDTH * 4 <= quarter
+                    and all(np.bincount(cols, minlength=1).max(initial=0)
+                            <= 1 for cols, _ in kept))
+                if not by_column and len(shards) * slots * 8 > quarter:
                     ids, slots = ids[:0], hyb.pad_slots(1)
                     kept = [(np.empty(0, np.int32),) * 2] * len(shards)
-                # by column where the data allows it (no column holds two
-                # of the rows) and it is no larger than the pairs
-                by_column = 2 * slots >= SHARD_WIDTH and all(
-                    np.bincount(cols, minlength=1).max(initial=0) <= 1
-                    for cols, _ in kept)
                 arr = (bv.pairs_by_column(kept) if by_column
                        else bv.pairs_by_pairs(kept, slots))
                 stored = np.zeros(ids.size, np.int64)
@@ -2095,6 +2139,7 @@ class Executor:
             handle = self.runner.pairs_count(
                 entry.dev, src_dense, pairs_count_slots(entry.ids.size))
         self.topn_pairs_recounts += 1
+        self.topn_pairs_recounts_by_column += int(entry.by_column)
         self.topn_pairs_bytes += (4 * int(entry.stored[at].sum())
                                   + n_shards * WORDS * 4)
 
@@ -2177,9 +2222,9 @@ class Executor:
                     and block[0][1] < heap[0][0]):
                 break  # threshold prune: no remaining row can reach top n
             with tracing.span("leaves"):
-                leaves = self._stackable(
-                    [self._row_leaf_dev(index, f.name, VIEW_STANDARD, shards,
-                                        rid) for rid, _ in block])
+                leaves = self._stackable(self._row_leaves_dev(
+                    index, f.name, VIEW_STANDARD, shards,
+                    [rid for rid, _ in block]))
             self.topn_recount_rows += len(block)
             # one dispatch, one host fetch of the packed counts, over the
             # leaves where they lie
@@ -2221,6 +2266,13 @@ class Executor:
                     keep = 100 * inter > tanimoto * (
                         cand_counts[small] + scount - inter)
                     inter = np.where(keep, inter, 0)
+                if n is not None and ids.size > n:
+                    # the entry's own n best by the heap's order (count
+                    # descending, id ascending): no other row of it can
+                    # be among the n best of all, and the heap sees n
+                    # pairs where a grid field has 10,000
+                    best = np.lexsort((ids, -inter))[:n]
+                    ids, inter = ids[best], inter[best]
                 offer(list(zip(ids.tolist(), inter.tolist())))
         if n is None:
             return out
@@ -2282,9 +2334,8 @@ class Executor:
             qctx.check()  # abort between recount chunks
             chunk = row_ids[start : start + CHUNK]
             with tracing.span("leaves"):
-                leaves = self._stackable(
-                    [self._row_leaf_dev(index, f.name, VIEW_STANDARD, shards,
-                                        rid) for rid in chunk])
+                leaves = self._stackable(self._row_leaves_dev(
+                    index, f.name, VIEW_STANDARD, shards, chunk))
             self.topn_recount_rows += len(chunk)
             if src_dense is not None:
                 packed = np.asarray(leaves_counts_packed(

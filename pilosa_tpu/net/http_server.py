@@ -644,6 +644,8 @@ class Handler:
             snap["topnRecountRows"] = getattr(ex, "topn_recount_rows", 0)
             snap["groupByHostSyncs"] = getattr(ex, "groupby_host_syncs", 0)
             snap["topnPairsRecounts"] = getattr(ex, "topn_pairs_recounts", 0)
+            snap["topnPairsRecountsByColumn"] = getattr(
+                ex, "topn_pairs_recounts_by_column", 0)
             snap["topnPairsBytes"] = getattr(ex, "topn_pairs_bytes", 0)
             snap["pairsEntriesBuilt"] = getattr(ex, "pairs_entries_built", 0)
             snap["pairsEntryBytes"] = getattr(ex, "pairs_entry_bytes", 0)

@@ -441,15 +441,17 @@ def sparse_to_dense(sp: jax.Array, n_words: int) -> jax.Array:
 # one row that holds column 32 · w + b of shard s, -1 where none does;
 # only a field whose columns hold at most one of those rows allows it (a
 # record's attribute: one value a column), and the executor takes it
-# where it is no larger than the pairs. A filtered TopN recounts all of
-# the rows at once: the filter plane's bit at every stored column, summed
-# by rank — where the dense walk stacks one [S, W] plane a candidate row
-# whatever it holds. By pairs the bit test is a gather (10 ns an element
-# on the chip). By column nothing is gathered, and bit-major is what lets
-# the filter's words be unpacked where they lie: bit b of a tile of words,
-# `(words >> b) & 1`, is a lane vector that lines up with row b of the
-# entry's tile element for element, with no reshape across lanes (column
-# order, bit minor, needs a [tile, 32] -> [32 · tile] relayout a step).
+# where its recount is the faster one (PAIRS_BY_COLUMN_SLOTS below) and
+# its 4 MiB a shard fit the entry's share of the residency budget. A
+# filtered TopN recounts all of the rows at once: the filter plane's bit
+# at every stored column, summed by rank — where the dense walk stacks
+# one [S, W] plane a candidate row whatever it holds. By pairs the bit
+# test is a gather (8 ns a slot on the chip). By column nothing is
+# gathered, and bit-major is what lets the filter's words be unpacked
+# where they lie: bit b of a tile of words, `(words >> b) & 1`, is a lane
+# vector that lines up with row b of the entry's tile element for element,
+# with no reshape across lanes (column order, bit minor, needs a
+# [tile, 32] -> [32 · tile] relayout a step).
 
 # entry slots one scan step covers, all of one shard: by column a tile of
 # 2,048 filter words and the [32, 2048] ranks under their bits, by pairs
@@ -460,6 +462,21 @@ def sparse_to_dense(sp: jax.Array, n_words: int) -> jax.Array:
 # [32 shards, 10,000 rows]: 2^14 17.2 ms, 2^15 11.1, 2^16 9.8, 2^17 9.6
 # (benches/recount_kernels.py --steps)
 PAIRS_STEP = 1 << 16
+
+# slots a shard (the power of two over the fullest shard's stored bits)
+# from which an entry that may lie by column does: the layout is chosen by
+# the recount's time, not by the entry's bytes. By pairs a launch pays the
+# gather, 7.6-8.9 ns a slot from 2^15 slots a shard up; by column it passes
+# over all 2^20 columns of a shard whatever they hold, 4 MiB a shard. On a
+# v5e at 32 shards, by pairs / by column, ms a launch (H = 1; H = 128
+# within 0.4 of the first and 1.3 above the second): 2^10 1.5 / 8.7, 2^13
+# 3.2 / 8.7, 2^14 5.3 / 8.8, 2^15 9.3 / 9.0 (H = 128: 9.3 / 9.8), 2^16
+# 17.0 / 8.8, 2^17 32.8 / 8.9, 2^19 127.0 / 8.7 (benches/recount_kernels.py
+# --crossover, PERF.md section 6). The two cross at 2^15, within 5 % of
+# each other on either side of it by H, and there the pairs are a
+# sixteenth of the bytes: by pairs; the count vector's length moves
+# neither side by more than an eighth, so the rule does not read it.
+PAIRS_BY_COLUMN_SLOTS = 1 << 16
 
 
 def pairs_count_slots(n_rows: int) -> int:

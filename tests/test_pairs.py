@@ -297,8 +297,9 @@ def test_no_plane_for_a_row_below_the_threshold(wide):
 def test_an_attribute_field_lies_by_column(tmp_path):
     """One value a column, 10,000 values, seven columns in ten filled: no
     column holds two rows and the sorted columns would take 2^20 slots a
-    shard, so the entry is one rank a column, laid bit-major (4 MiB a shard,
-    half the pairs) and the recount gathers nothing; same Pairs as brute force."""
+    shard, sixteen times PAIRS_BY_COLUMN_SLOTS, so the entry is one rank a
+    column, laid bit-major (4 MiB a shard, here half the pairs) and the
+    recount gathers nothing; same Pairs as brute force."""
     rng = np.random.default_rng(31)
     h = Holder(str(tmp_path / "d")).open()
     try:
@@ -321,6 +322,67 @@ def test_an_attribute_field_lies_by_column(tmp_path):
         (key,) = [k for k, _ in ex.residency.entries_snapshot()
                   if k[0] == "pairs"]
         assert ex.residency.peek(key).dev.shape == (1, 2, 32, W)
+    finally:
+        h.close()
+
+
+LAYOUT_CASES = {
+    # stored bits a shard over PAIRS_BY_COLUMN_SLOTS, whether one column
+    # holds two rows, the residency budget, whether it lies by column
+    "at-the-crossover": (0.75, False, None, True),
+    "below-the-crossover": (0.375, False, None, False),
+    "a-column-holds-two-rows": (0.75, True, None, False),
+    # a quarter of it is one byte short of 2 shards x 4 MiB
+    "no-room-by-column": (0.75, False, 4 * 2 * 4 * SHARD_WIDTH - 1, False),
+}
+
+
+@pytest.mark.parametrize("case", LAYOUT_CASES)
+def test_the_layout_is_the_faster_recount(tmp_path, case):
+    """An attribute field of 300 thin rows over 2 shards: by column from
+    PAIRS_BY_COLUMN_SLOTS slots a shard, though its pairs would be a
+    fraction of the 4 MiB a shard; by pairs below that, where a column
+    holds two rows, and where the by-column entry would pass a quarter of
+    the residency budget and the pairs do not (by pairs, not the entry of
+    no rows). Same Pairs as brute force every time, and
+    topn_pairs_recounts_by_column counts the by-column launches alone."""
+    fill, shared, budget, by_column = LAYOUT_CASES[case]
+    rng = np.random.default_rng(32)
+    n_rows, per_shard = 300, int(fill * bv.PAIRS_BY_COLUMN_SLOTS)
+    h = Holder(str(tmp_path / "d")).open()
+    try:
+        idx = h.create_index("a", track_existence=False)
+        cols = np.concatenate([
+            s * SHARD_WIDTH + rng.permutation(SHARD_WIDTH)[:per_shard]
+            for s in range(2)])
+        vals = rng.integers(0, n_rows, cols.size)
+        if shared:      # one column of the second shard in two rows
+            cols = np.append(cols, cols[-1])
+            vals = np.append(vals, (vals[-1] + 1) % n_rows)
+        idx.create_field("g", FieldOptions(cache_size=50000)).import_bits(
+            vals.astype(np.uint64), cols.astype(np.uint64))
+        filt = np.arange(0, 2 * SHARD_WIDTH, 5, dtype=np.uint64)
+        idx.create_field("f").import_bits(np.zeros(filt.size, np.uint64),
+                                          filt)
+        ex = Executor(h)
+        if budget is not None:
+            ex.residency.budget = budget
+        under = np.bincount(vals[cols % 5 == 0], minlength=n_rows)
+        want = sorted(((int(c), -r) for r, c in enumerate(under) if c),
+                      reverse=True)
+        for launches, n in enumerate((40, 7), start=1):
+            (got,) = ex.execute("a", f"TopN(g, Row(f=0), n={n})")
+            assert [tuple(p) for p in got] == [(-nr, c)
+                                               for c, nr in want[:n]]
+            assert ex.topn_recount_rows == 0
+            assert ex.topn_pairs_recounts == launches
+            assert ex.topn_pairs_recounts_by_column == launches * by_column
+        (key,) = [k for k, _ in ex.residency.entries_snapshot()
+                  if k[0] == "pairs"]
+        entry = ex.residency.peek(key)
+        assert entry.dev.shape == ((1, 2, 32, W) if by_column else (
+            2, 2, ex.hybrid.pad_slots(per_shard + shared)))
+        assert entry.by_column == by_column and entry.ids.size == n_rows
     finally:
         h.close()
 
@@ -350,6 +412,32 @@ def test_sixteen_threads_build_the_entry_once(wide):
     assert ex.residency.snapshot()["by_kind"]["pairs"]["entries"] == 1
     # and the three dense rows and the filter were each uploaded once
     assert ex.residency.misses == N_DENSE + 2
+
+
+def test_a_block_of_rows_resolves_as_the_rows_one_by_one(wide):
+    """_row_leaves_dev (a recount block): the leaves _row_leaf_dev gives
+    for each row, under the same residency keys, and the same read heat
+    on every fragment, charged in one round trip of the tracker's lock
+    where the rows one by one take one each."""
+    h, _ = wide
+    idx, rows, shards = h.index("w"), [2, 0, 5000, 1], [0, 1]
+    one, block = Executor(h), Executor(h)
+    a = [one._row_leaf_dev(idx, "g", "standard", shards, r) for r in rows]
+    calls = []
+    touch_many = block.heat.touch_many
+    block.heat.touch_many = lambda keys, **kw: (calls.append(kw),
+                                                touch_many(keys, **kw))
+    b = block._row_leaves_dev(idx, "g", "standard", shards, rows)
+    assert [kw for kw in calls if "reads" in kw] == [{"reads": len(rows)}]
+    assert all((np.asarray(x) == np.asarray(y)).all() for x, y in zip(a, b))
+    assert ([k for k, _ in one.residency.entries_snapshot()]
+            == [k for k, _ in block.residency.entries_snapshot()])
+
+    def reads(ex):
+        return sorted((e["field"], e["shard"], e["reads"])
+                      for e in ex.heat.snapshot(top=0)["hot"])
+
+    assert reads(one) == reads(block) == [("g", 0, 4), ("g", 1, 4)]
 
 
 @pytest.mark.parametrize("why", ["hybrid-off", "entry-over-a-quarter"])
