@@ -683,27 +683,50 @@ def run_write(http: Http, d: Data, ck: Checker) -> None:
 
 
 def run_concurrent(http: Http, d: Data, ck: Checker) -> dict:
-    """32 clients x 10 Counts: the continuous batcher must be on the path."""
+    """32 clients x 10 Counts of a dense pair (the continuous batcher must
+    be on the path), and among them, from the same clients, three Counts
+    the batcher does not take: a 3-way all-dense Intersect, a dense
+    Union of three and a tree with a sparse leaf. On a mesh the first two
+    are programs that sum across chips and the third runs chip-local
+    kernels, launched while the batcher's own are in flight: where a mesh
+    would hang, under the phase's limit a request."""
     z = d.sizes
     ids = d.dense_ids[:8]
+    s0 = d.sparse_ids[0]
     pairs = [(ids[i % len(ids)], ids[(i * 3 + 1) % len(ids)])
              for i in range(z.clients * z.per_client)]
     want = {}
     for a, b in set(pairs):
-        want[(a, b)] = int(ref_intersect(d.f[a], d.f[b]).size)
+        want[f"Count(Intersect(Row(f={a}), Row(f={b})))"] = int(
+            ref_intersect(d.f[a], d.f[b]).size)
+    trees = {}  # a client's three, by its place among the dense rows
+    for k in range(len(ids)):
+        a, b, c = (ids[(k + j) % len(ids)] for j in (0, 1, 3))
+        fa, fb, fc = d.f[a], d.f[b], d.f[c]
+        trees[k] = [
+            (f"Count(Intersect(Row(f={a}), Row(f={b}), Row(f={c})))",
+             ref_intersect(ref_intersect(fa, fb), fc)),
+            (f"Count(Union(Row(f={a}), Row(f={b}), Row(f={c})))",
+             ref_union(ref_union(fa, fb), fc)),
+            (f"Count(Difference(Union(Row(f={a}), Row(f={s0})), "
+             f"Row(f={b})))",
+             ref_difference(ref_union(fa, d.f[s0]), fb))]
+        for pql, cols in trees[k]:
+            want[pql] = int(cols.size)
     before = http.get("/debug/vars")["countBatcher"]["batched_queries"]
     wrong: list = []
     errors: list = []
 
     def client(tid: int) -> None:
+        mine = [f"Count(Intersect(Row(f={a}), Row(f={b})))" for a, b in
+                pairs[tid * z.per_client:(tid + 1) * z.per_client]]
+        for j, (pql, _) in enumerate(trees[tid % len(ids)]):
+            mine.insert(min(len(mine), 3 * j + 2), pql)
         try:
-            for q in range(z.per_client):
-                a, b = pairs[tid * z.per_client + q]
-                got = http.query(
-                    f"Count(Intersect(Row(f={a}), Row(f={b})))",
-                    timeout=CONCURRENT_REQUEST_LIMIT_S)[0]
-                if got != want[(a, b)]:
-                    wrong.append(((a, b), got, want[(a, b)]))
+            for pql in mine:
+                got = http.query(pql, timeout=CONCURRENT_REQUEST_LIMIT_S)[0]
+                if got != want[pql]:
+                    wrong.append((pql, got, want[pql]))
         except Exception as e:  # noqa: BLE001 — reported below
             errors.append(f"{type(e).__name__}: {e}")
 
@@ -716,11 +739,13 @@ def run_concurrent(http: Http, d: Data, ck: Checker) -> dict:
     after = http.get("/debug/vars")["countBatcher"]
     batched = after["batched_queries"] - before
     ck.record("concurrent_counts",
-              f"{z.clients} clients x {z.per_client} Count(Intersect)",
+              f"{z.clients} clients x ({z.per_client} Count(Intersect) of "
+              f"a pair + 3 trees)",
               {"wrong": wrong[:3], "errors": errors[:3]},
               {"wrong": [], "errors": []})
     ck.record("concurrent_counts", "batched_queries > 0", batched > 0, True)
-    return {"queries": len(pairs), "batched_queries": batched,
+    return {"queries": z.clients * (z.per_client + 3),
+            "batched_queries": batched,
             "max_batch_seen": after["max_batch_seen"]}
 
 

@@ -279,6 +279,11 @@ def cmd_server(args) -> int:
             print("draining (send another signal to skip)...", flush=True)
             server.drain()
     finally:
+        if mesh is not None:
+            # the last word on the rule for collectives (docs/operations.md
+            # "One node, several chips"): /debug/vars `mesh` as it stood
+            print("mesh: " + json.dumps(server.runner.mesh_snapshot()),
+                  flush=True)
         server.close()
     return 0
 

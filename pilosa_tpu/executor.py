@@ -215,7 +215,7 @@ class Executor:
             # over replica slices (SURVEY §2.9 strategy 3 in the
             # PRODUCTION serving path, not just the bench kernels)
             self.batcher = CountBatcher(runner=self.runner)
-            self.sum_batcher = PlaneSumBatcher()
+            self.sum_batcher = PlaneSumBatcher(runner=self.runner)
             self.minmax_batcher = MinMaxBatcher()
         else:
             self.batcher = None
@@ -1631,8 +1631,11 @@ class Executor:
             from pilosa_tpu.ops.bitvector import intersect_chain_count_total
 
             def launch():
-                return intersect_chain_count_total(tuple(leaves))
+                return self.runner.collective(
+                    intersect_chain_count_total, tuple(leaves))
         else:
+            # on a mesh a psum program, launched like the chain's on the
+            # one collective thread (parallel/mesh.py)
             def launch():
                 return self.runner.count_total_leaves_dev(leaves, program)
         # un-batched dispatches are this query's alone: `dispatch` is the
@@ -2229,7 +2232,8 @@ class Executor:
             # one dispatch, one host fetch of the packed counts, over the
             # leaves where they lie
             with tracing.span("dispatch"):
-                handle = leaves_counts_packed(leaves, src_dense)
+                handle = self.runner.collective(
+                    leaves_counts_packed, leaves, src_dense)
             with tracing.span("device.wait"):
                 packed = np.asarray(handle)[:, :len(block)]
             with tracing.span("reduce"):
@@ -2338,8 +2342,9 @@ class Executor:
                     index, f.name, VIEW_STANDARD, shards, chunk))
             self.topn_recount_rows += len(chunk)
             if src_dense is not None:
-                packed = np.asarray(leaves_counts_packed(
-                    leaves, src_dense))[:, :len(chunk)].astype(np.int64)
+                packed = np.asarray(self.runner.collective(
+                    leaves_counts_packed, leaves, src_dense
+                ))[:, :len(chunk)].astype(np.int64)
                 counts, scount = packed[0], int(packed[2, 0])
                 if tanimoto:
                     # STRICT like tanimoto_mask / the pairs recount: the

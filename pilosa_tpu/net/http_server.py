@@ -721,6 +721,14 @@ class Handler:
             # cache — the dashboard's slice-local-share sparkline source
             if hasattr(ex, "ici_snapshot"):
                 snap["iciServing"] = ex.ici_snapshot()
+            # one node, several chips: how many devices a leaf is laid
+            # over, and the launches that went to more than one, by
+            # whether their program holds a collective; collectiveThreads
+            # is how many threads ever launched one of those (0 or 1:
+            # parallel/mesh.py on_collective_thread)
+            runner = getattr(ex, "runner", None)
+            if runner is not None:
+                snap["mesh"] = runner.mesh_snapshot()
             # durable hinted handoff (storage/hints.py): queued/replayed/
             # dropped totals + per-target pending bytes — the previously
             # silent skipped-replica writes, now an operator surface

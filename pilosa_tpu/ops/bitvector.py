@@ -103,7 +103,7 @@ def xor_count(a: jax.Array, b: jax.Array) -> jax.Array:
     return popcount(jnp.bitwise_xor(a, b))
 
 
-@counted_jit("count")
+@counted_jit("count", cross_shard=True)
 def intersect_chain_count_total(leaves: tuple) -> jax.Array:
     """Total popcount of an N-way intersection in ONE fused dispatch — the
     planner's Count(Intersect(...)) pushdown kernel (pilosa_tpu/planner.py).
@@ -144,7 +144,7 @@ def row_popcounts(rows: jax.Array) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
-@counted_jit("groupby")
+@counted_jit("groupby", cross_shard=True)
 def cross_count_matrix(prefix: jax.Array, axis: jax.Array) -> jax.Array:
     """counts[P, R]: intersection popcounts of every (prefix, axis-row) pair.
 
@@ -200,7 +200,7 @@ def chunk_count_matrix(axis_slabs, idx, axis, n_valid) -> jax.Array:
         cross_count_matrix(gather_prefix(axis_slabs, idx), axis), n_valid)
 
 
-@counted_jit("groupby", static_argnames=("bound",))
+@counted_jit("groupby", cross_shard=True, static_argnames=("bound",))
 def groupby_chunk_live(axis_slabs: tuple, idx: tuple, axis: jax.Array,
                        n_valid: jax.Array, bound: int):
     """One pipelined GroupBy level chunk, fully on device: the chunk
@@ -210,7 +210,7 @@ def groupby_chunk_live(axis_slabs: tuple, idx: tuple, axis: jax.Array,
     return live_from_matrix(cmat, bound)
 
 
-@counted_jit("groupby")
+@counted_jit("groupby", cross_shard=True)
 def groupby_chunk_matrix(axis_slabs: tuple, idx: tuple, axis: jax.Array,
                          n_valid: jax.Array) -> jax.Array:
     """Dense [chunk, R] count matrix for one chunk — the overflow fallback
@@ -561,7 +561,7 @@ def pairs_count_local(pairs: jax.Array, src: jax.Array,
     return acc.reshape(-1)
 
 
-@counted_jit("sparse", static_argnames=("n_slots",))
+@counted_jit("sparse", cross_shard=True, static_argnames=("n_slots",))
 def pairs_count(pairs: jax.Array, src: jax.Array, n_slots: int) -> jax.Array:
     """|row ∩ src| for every row of a pairs entry, summed over shards on
     the device: one launch and one fetch of n_slots int32 a TopN."""
@@ -914,10 +914,10 @@ def hybrid_count_dev(program, leaves: list, kinds: list,
     hybrid kernel is per-shard local (zero collectives), so on a mesh the
     sharded program partitions with no cross-device dependencies and
     concurrent request threads can dispatch freely — a device-side total
-    would insert a GSPMD all-reduce, and concurrent all-reduce programs
-    from independent threads interleave across devices and deadlock
-    (the dense path funnels concurrent counts through the single-threaded
-    batcher for exactly this reason)."""
+    would insert a GSPMD all-reduce, and a program that holds a
+    collective has to be launched on the process's one collective thread
+    (parallel/mesh.py on_collective_thread), a hop a request for each of
+    these kernels' callers."""
     # all-run AND (the Count(Intersect) pushdown's common shape): fold
     # with run_intersect and finish with the fused run_intersect_count —
     # the final overlap list is never sorted or materialized

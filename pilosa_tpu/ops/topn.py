@@ -75,7 +75,7 @@ def tanimoto_counts_packed(rows: jax.Array, src: jax.Array) -> jax.Array:
         [inter, rcounts, jnp.broadcast_to(scount, inter.shape)], axis=0)
 
 
-@counted_jit("topn")
+@counted_jit("topn", cross_shard=True)
 def leaves_counts_packed(leaves: tuple, src: jax.Array) -> jax.Array:
     """tanimoto_counts_packed over row leaves as they lie in residency: a
     tuple of [S, W] planes and the filter plane [S, W] -> int32[3, R],

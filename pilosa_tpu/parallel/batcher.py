@@ -149,8 +149,9 @@ class ContinuousBatcher:
     # exists to amortize)
     HANDOFF_AT_CUT = True
 
-    def __init__(self, max_batch: int = MAX_BATCH):
+    def __init__(self, max_batch: int = MAX_BATCH, runner=None):
         self.max_batch = max_batch
+        self.runner = runner  # the device batchers' DeviceRunner, if any
         self.admission_s = _ADMISSION_S
         self._lock = threading.Lock()
         self._pending: dict[tuple, list[_Req]] = defaultdict(list)
@@ -414,6 +415,14 @@ class ContinuousBatcher:
     def _compute(self, key: tuple, payloads: list) -> list:
         raise NotImplementedError
 
+    def _launch_cross_shard(self, fn, *args):
+        """Launch a jitted function that sums over the shard axis: on a
+        runner with a mesh through its one collective thread (the leader
+        is a request thread), else in place."""
+        if self.runner is None:
+            return fn(*args)
+        return self.runner.collective(fn, *args)
+
     def queue_depth(self) -> int:
         """Requests currently queued (pre-cut) across every compatibility
         key — the telemetry sampler's saturation gauge."""
@@ -437,7 +446,7 @@ class ContinuousBatcher:
 # ------------------------------------------------------------------ counts
 
 
-@counted_jit("batcher", static_argnames=("op",))
+@counted_jit("batcher", cross_shard=True, static_argnames=("op",))
 def _batched_counts(leaves: tuple, ii: jax.Array, jj: jax.Array,
                     op: str) -> jax.Array:
     """Shard-chunk count partials int32[K, C] for K queries
@@ -522,10 +531,6 @@ class CountBatcher(ContinuousBatcher):
     copy) instead of every replica redundantly computing all K — batch
     throughput scales with the replica count."""
 
-    def __init__(self, max_batch: int = MAX_BATCH, runner=None):
-        super().__init__(max_batch)
-        self.runner = runner
-
     def count(self, op: str, a: jax.Array, b: Optional[jax.Array]) -> int:
         if b is None:
             op, b = "id", a
@@ -565,7 +570,9 @@ class CountBatcher(ContinuousBatcher):
         if n_rep > 1:
             fn = _replica_counts_fn(self.runner.mesh, op)
             return fn(tuple(leaves), ii, jj)  # device array, not fetched
-        return _batched_counts(tuple(leaves), ii, jj, op)
+        # on a mesh the chunk sums cross devices (one all-reduce)
+        return self._launch_cross_shard(
+            _batched_counts, tuple(leaves), ii, jj, op)
 
     def _finalize(self, key: tuple, handle, payloads: list) -> list:
         with tracing.span("device.wait"):
@@ -595,7 +602,7 @@ def _dedup_masks(payloads: list) -> tuple[list, list[int]]:
     return masks + [masks[0]] * (kp - len(masks)), idx
 
 
-@counted_jit("batcher")
+@counted_jit("batcher", cross_shard=True)
 def _batched_plane_sums(planes: jax.Array, masks: tuple) -> jax.Array:
     """Per-query per-plane filtered popcounts with the mask's own count
     appended -> int32[K, depth + 1, C] shard-chunk partials (one dispatch,
@@ -663,7 +670,8 @@ class PlaneSumBatcher(ContinuousBatcher):
         planes = payloads[0][0]
         with tracing.span("dispatch", batch=len(payloads)):
             masks, idx = _dedup_masks(payloads)
-            return _batched_plane_sums(planes, tuple(masks)), idx
+            return self._launch_cross_shard(
+                _batched_plane_sums, planes, tuple(masks)), idx
 
     def _finalize(self, key: tuple, handle, payloads: list) -> list:
         arrs, idx = handle

@@ -15,6 +15,8 @@ channel reduce (executor.go:2183-2321), with XLA collectives replacing HTTP.
 
 from __future__ import annotations
 
+import concurrent.futures
+import contextvars
 import functools
 import os
 import re
@@ -38,6 +40,7 @@ from pilosa_tpu.ops.bitvector import (
     popcount,
 )
 from pilosa_tpu.analysis import lockwitness
+from pilosa_tpu.utils import tracing
 from pilosa_tpu.utils.telemetry import counted_jit, record_dispatch
 
 SHARD_AXIS = "shard"
@@ -218,6 +221,38 @@ def mesh_from_config(devices: str = "auto", platform: str = "",
     return make_mesh(avail, replicas=max(replicas, 1))
 
 
+# -- one thread for every program that holds a collective ---------------------
+# A program laid over several devices is enqueued on each of them in turn.
+# Two threads that launch two such programs at once can reach the devices
+# in different orders; if both programs hold a collective (a psum, or the
+# all-reduce GSPMD puts where a jitted function sums over the shard axis
+# of sharded operands), each then waits on one device for a partner that
+# is queued behind the other, for ever. Programs without a collective do
+# not wait for each other and may be launched from anywhere. So the rule,
+# wherever a runner has a mesh: a program that holds a collective is
+# launched on this thread and on no other. The launch is the enqueue alone
+# (asynchronous); the caller fetches the result on its own thread.
+
+_collective_pool = concurrent.futures.ThreadPoolExecutor(
+    max_workers=1, thread_name_prefix="mesh-collective")
+_on_collective_thread = threading.local()
+
+
+def on_collective_thread(fn, *args, **kwargs):
+    """fn(*args, **kwargs), made on the process's one collective thread in
+    the caller's context (its open span counts the launch) and returned,
+    or raised, to the caller."""
+    if getattr(_on_collective_thread, "here", False):
+        return fn(*args, **kwargs)
+
+    def run():
+        _on_collective_thread.here = True
+        return fn(*args, **kwargs)
+
+    return _collective_pool.submit(
+        contextvars.copy_context().run, run).result()
+
+
 # -- program evaluation ------------------------------------------------------
 # program: nested tuples, e.g. ("and", ("leaf", 0), ("or", ("leaf", 1), ...)).
 # Ops: leaf(i) | and | or | xor | andnot (binary: a &~ b) | not.
@@ -252,7 +287,7 @@ def eval_row(leaves: jax.Array, program) -> jax.Array:
     return _eval(leaves, program)
 
 
-@counted_jit("program", static_argnames=("program",))
+@counted_jit("program", cross_shard=True, static_argnames=("program",))
 def eval_count_total(leaves: jax.Array, program) -> jax.Array:
     """[L, S, W] -> scalar total count. Under a sharded input GSPMD lowers the
     sum to an ICI all-reduce — the Count() reduce (executor.go:1521,2209)."""
@@ -335,8 +370,14 @@ def eval_count_mesh(mesh: Mesh, leaves: tuple, program) -> jax.Array:
     explicit psum over the shard axis (ICI)."""
     fn = _ici_cached(("count", mesh, program, len(leaves)),
                      lambda: _build_count_mesh(mesh, program, len(leaves)))
-    record_dispatch("ici_program", mesh, "count", program, len(leaves))
-    return fn(tuple(leaves))
+
+    # the family stays a literal at every site: pilosa-lint reads it there
+    def launch():
+        record_dispatch("ici_program", mesh, "count", program, len(leaves),
+                        devices=mesh.size, collective=True)
+        return fn(tuple(leaves))
+
+    return on_collective_thread(launch)
 
 
 def eval_row_mesh(mesh: Mesh, leaves: tuple, program) -> jax.Array:
@@ -346,11 +387,12 @@ def eval_row_mesh(mesh: Mesh, leaves: tuple, program) -> jax.Array:
     from)."""
     fn = _ici_cached(("row", mesh, program, len(leaves)),
                      lambda: _build_row_mesh(mesh, program, len(leaves)))
-    record_dispatch("ici_program", mesh, "row", program, len(leaves))
+    record_dispatch("ici_program", mesh, "row", program, len(leaves),
+                    devices=mesh.size)
     return fn(tuple(leaves))
 
 
-@counted_jit("stream")
+@counted_jit("stream", cross_shard=True)
 def count_pair_stream(rows: jax.Array, ii: jax.Array, jj: jax.Array,
                       carry: jax.Array) -> jax.Array:
     """Serve a stream of K Count(Intersect(Row(i), Row(j))) queries against a
@@ -406,8 +448,13 @@ def pair_stream_counts(mesh: Mesh, rows: jax.Array, ii: np.ndarray,
     # on a 1-D ('shard',) mesh there is no replica axis: every device scans
     # the full stream (replicated), sharded only over the data
     ii_d, jj_d, k, rep_spec = scatter_queries(mesh, ii, jj)
-    record_dispatch("stream_mesh", mesh, rows, ii_d, jj_d)
-    out = np.asarray(_pair_stream_fn(mesh)(rows, ii_d, jj_d)).astype(np.int64)
+
+    def launch():
+        record_dispatch("stream_mesh", mesh, rows, ii_d, jj_d,
+                        devices=mesh.size, collective=True)
+        return _pair_stream_fn(mesh)(rows, ii_d, jj_d)
+
+    out = np.asarray(on_collective_thread(launch)).astype(np.int64)
     return out[:k]
 
 
@@ -471,10 +518,15 @@ def groupby_chunk_matrix_mesh(mesh: Mesh, axis_slabs: tuple, idx: tuple,
                               axis: jax.Array, n_valid) -> jax.Array:
     """Sharded groupby_chunk_matrix: per-device partial [P, R] counts, one
     ICI psum. A device array — no host sync."""
-    record_dispatch("groupby_mesh", mesh, len(idx),
-                    tuple(axis_slabs), tuple(idx), axis)
-    return _groupby_cmat_mesh_fn(mesh, len(idx))(
-        tuple(axis_slabs), tuple(idx), axis, n_valid)
+    axis_slabs, idx = tuple(axis_slabs), tuple(idx)
+
+    def launch():
+        record_dispatch("groupby_mesh", mesh, len(idx), axis_slabs, idx,
+                        axis, devices=mesh.size, collective=True)
+        return _groupby_cmat_mesh_fn(mesh, len(idx))(
+            axis_slabs, idx, axis, n_valid)
+
+    return on_collective_thread(launch)
 
 
 def groupby_chunk_live_mesh(mesh: Mesh, axis_slabs: tuple, idx: tuple,
@@ -530,6 +582,9 @@ class DeviceRunner:
         if ici_serving is None:
             ici_serving = os.environ.get("PILOSA_TPU_ICI", "1") != "0"
         self.ici_serving = bool(ici_serving) and mesh is not None
+        if mesh is not None:
+            # from here on launches are counted by the devices they go to
+            tracing.mesh_launches.watching = True
 
     @property
     def n_devices(self) -> int:
@@ -545,6 +600,23 @@ class DeviceRunner:
     def n_replicas(self) -> int:
         return (1 if self.mesh is None
                 else self.mesh.shape.get(REPLICA_AXIS, 1))
+
+    def mesh_snapshot(self) -> dict:
+        """The /debug/vars `mesh` block: the devices a leaf is laid over
+        and, of the launches that went to more than one, how many held a
+        collective and how many threads made those (0 or 1)."""
+        return {"devices": self.n_devices, "shardSlots": self.n_shard_slots,
+                **tracing.mesh_launches.snapshot()}
+
+    def collective(self, fn, *args, **kwargs):
+        """Launch fn(*args, **kwargs), a jitted function that reduces over
+        the shard axis of its operands (counted_jit's `cross_shard`). With
+        a mesh its program holds the all-reduce GSPMD puts there, and is
+        launched on the one collective thread; on one device it is the
+        plain call."""
+        if self.mesh is None:
+            return fn(*args, **kwargs)
+        return on_collective_thread(fn, *args, **kwargs)
 
     def _put_shard_padded(self, arr: np.ndarray, shard_axis: int,
                           fill: int = 0) -> jax.Array:
@@ -592,15 +664,19 @@ class DeviceRunner:
                     n_slots: int) -> jax.Array:
         """int32[n_slots] device counts of a pairs entry's rows under the
         filter plane `src` [S', W], launched and not fetched. With a mesh
-        the explicit shard_map + psum form, never the jit form on sharded
-        operands (whose GSPMD all-reduce deadlocks under concurrent
-        request threads)."""
-        if self.mesh is not None:
+        the explicit shard_map + psum form, launched like every program
+        that holds a collective on the one collective thread."""
+        if self.mesh is None:
+            return pairs_count(pairs, src, n_slots)
+
+        def launch():
             record_dispatch("ici_program", self.mesh, "pairs", n_slots,
-                            pairs, src)
+                            pairs, src, devices=self.mesh.size,
+                            collective=True)
             return _pairs_count_mesh_fn(self.mesh, n_slots,
                                         pairs.ndim)(pairs, src)
-        return pairs_count(pairs, src, n_slots)
+
+        return on_collective_thread(launch)
 
     def put_plane_slab(self, planes: np.ndarray) -> jax.Array:
         """Place a [depth, S, W] BSI plane slab on device(s), shard-axis
@@ -646,7 +722,7 @@ class DeviceRunner:
             # explicit shard_map + psum serving form: per-device partial
             # counts over the local shard slice, one ICI all-reduce
             return eval_count_mesh(self.mesh, tuple(leaves), program)
-        return eval_count_total(tuple(leaves), program)
+        return self.collective(eval_count_total, tuple(leaves), program)
 
     # -- GroupBy cross-count dispatch (single device / mesh routing) -------
 

@@ -551,13 +551,16 @@ def dispatch_key(args: tuple, kwargs: Optional[dict] = None):
     return treedef, tuple(_sig_of(l) for l in leaves)
 
 
-def record_dispatch(family: str, *args) -> None:
+def record_dispatch(family: str, *args, devices: int = 1,
+                    collective: bool = False) -> None:
     """Manual counting hook for dispatch sites that build their jitted
     callables dynamically (the mesh shard_map paths). No wall clock wraps
     the jitted call here, so the kernel-stats entry counts the dispatch
-    without a latency sample."""
+    without a latency sample. `devices` is how many the launch goes to
+    and `collective` whether its program holds a psum (the /debug/vars
+    `mesh` block counts launches to several devices by it)."""
     lockwitness.note_blocking("dispatch", family)
-    tracing.note_launch()
+    tracing.note_launch(devices, collective)
     if not enabled():
         return
     try:
@@ -571,10 +574,28 @@ def record_dispatch(family: str, *args) -> None:
         pass
 
 
-def counted_jit(family: str, **jit_kwargs):
+def _launch_devices(args, kwargs) -> int:
+    """The most devices an operand of a launch is laid over."""
+    import jax
+
+    n = 1
+    for leaf in jax.tree_util.tree_leaves((args, kwargs)):
+        if isinstance(leaf, jax.Array) and not isinstance(
+                leaf, jax.core.Tracer):
+            n = max(n, len(leaf.sharding.device_set))
+    return n
+
+
+def counted_jit(family: str, cross_shard: bool = False, **jit_kwargs):
     """jax.jit + per-call compile/cached accounting under `family`, plus
     per-(family, rep, arity) dispatch latency and h2d byte attribution
     (KernelStats) when PILOSA_TPU_KERNEL_STATS is on.
+
+    `cross_shard` declares that the function reduces over the shard axis
+    of its operands: laid over a mesh, GSPMD puts an all-reduce into the
+    program, so such a launch is counted as one that holds a collective
+    (tracing.mesh_launches) and has to be made through
+    parallel/mesh.py on_collective_thread.
 
     Drop-in at the decorator site: the wrapper forwards to the jitted
     callable and skips accounting AND timing inside a trace (a wrapped
@@ -600,7 +621,11 @@ def counted_jit(family: str, **jit_kwargs):
             # holding a witnessed lock stalls every sibling of that lock
             # behind the accelerator (no-op unless PILOSA_TPU_LOCKCHECK=1)
             lockwitness.note_blocking("dispatch", family)
-            tracing.note_launch()
+            if tracing.mesh_launches.watching:
+                devices = _launch_devices(args, kwargs)
+                tracing.note_launch(devices, cross_shard and devices > 1)
+            else:
+                tracing.note_launch()
             arity = -1
             h2d = 0
             if enabled():

@@ -101,7 +101,8 @@ def _covered(intervals: list, lo: float, hi: float) -> float:
 class Span:
     __slots__ = ("tracer", "name", "trace_id", "span_id", "parent", "start",
                  "end", "start_wall", "tags", "self_ms", "cpu_ms",
-                 "launches", "_cpu0", "_kids", "_token", "_annotation")
+                 "launches", "devices", "_cpu0", "_kids", "_token",
+                 "_annotation")
 
     def __init__(self, tracer, name: str, trace_id: Optional[str] = None,
                  tags: Optional[dict] = None):
@@ -118,6 +119,7 @@ class Span:
         self.self_ms = 0.0
         self.cpu_ms = 0.0
         self.launches = 0  # device programs enqueued under this span
+        self.devices = 1  # the most devices one of them went to
         self._kids: list = []  # (start, end) of finished child spans
         self._token = None
         self._annotation = None
@@ -161,6 +163,7 @@ class Span:
             self.parent._kids.append((self.start, end))
         if self.launches:
             self.tags["dispatches"] = self.launches
+            self.tags["devices"] = self.devices
         if _telemetry_on():
             spans.add(self.name, wall_ms, self.self_ms, self.cpu_ms)
         prof = qprofile.current_profile.get()
@@ -184,12 +187,60 @@ def span(name: str, trace_id: Optional[str] = None, **tags) -> Span:
     return Span(current_tracer.get(), name, trace_id, tags)
 
 
-def note_launch() -> None:
+def note_launch(devices: int = 1, collective: bool = False) -> None:
     """One device program enqueued (counted_jit / record_dispatch): the
-    open span's `dispatches` tag."""
+    open span's `dispatches` tag and, of the devices a launch went to,
+    the most (`devices`). A launch to several devices is also counted in
+    `mesh_launches`, as one that holds a collective or as a local one."""
     sp = current_span.get()
     if sp is not None:
         sp.launches += 1
+        if devices > sp.devices:
+            sp.devices = devices
+    if devices > 1:
+        mesh_launches.add(collective)
+
+
+class MeshLaunches:
+    """Launches that went to more than one device, counted where they are
+    made: `collective` where the program holds a psum or a GSPMD reduce
+    across the mesh's shard axis, `local` where every device works its own
+    block and nothing crosses the interconnect. `threads` are the threads
+    that have launched a collective one: independent threads' collective
+    programs can reach the devices in different orders and wait for each
+    other for ever, so parallel/mesh.py launches every one of them on one
+    thread, and this set is how to see that it does (the /debug/vars
+    `mesh` block: `collectiveThreads` reads 0 or 1)."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.watching = False  # a DeviceRunner with a mesh exists
+        self._collective = 0
+        self._local = 0
+        self._threads: set = set()
+
+    def add(self, collective: bool) -> None:
+        with self._lock:
+            if collective:
+                self._collective += 1
+                self._threads.add(threading.get_ident())
+            else:
+                self._local += 1
+
+    def snapshot(self) -> dict:
+        with self._lock:
+            return {"collectiveLaunches": self._collective,
+                    "localLaunches": self._local,
+                    "collectiveThreads": len(self._threads)}
+
+    def reset(self) -> None:
+        with self._lock:
+            self._collective = self._local = 0
+            self._threads.clear()
+
+
+# process-global, like `spans` below: one process, one set of devices
+mesh_launches = MeshLaunches()
 
 
 def _telemetry_on() -> bool:
