@@ -200,8 +200,13 @@ class Executor:
         # (planner.choose_representation) with promote/demote hysteresis
         # and heat-informed demotion. PILOSA_TPU_HYBRID=0 / threshold 0
         # restore pure-dense behavior (read per decision, no restart).
-        from pilosa_tpu.parallel.residency import HybridManager
+        from pilosa_tpu.parallel.residency import HybridManager, RowStatsMemo
         self.hybrid = HybridManager(heat=self.heat)
+        # a row's per-shard statistics (generations, cardinalities, run
+        # statistics, heat coordinates), kept per write version of its
+        # view: what the planner, the plan cache's key, the
+        # representation choice and the leaf lookup read of a row
+        self.row_stats = RowStatsMemo()
         # continuous batching of concurrent simple Counts into single
         # device dispatches (parallel/batcher.py); PILOSA_TPU_BATCH=0
         # falls back to one dispatch per query
@@ -518,6 +523,7 @@ class Executor:
         self._row_cache_epoch += 1
         self._row_cache.clear()
         self.residency.clear()
+        self.row_stats.clear()
         if self.plan_cache is not None:
             self.plan_cache.clear()
 
@@ -686,8 +692,8 @@ class Executor:
         from the resident index/interval array (one small kernel, zero
         host->device bytes) instead of re-uploading 128 KiB per shard."""
         if gens is None:
-            gens = self._leaf_gens(index, field_name, view_name, shards,
-                                   row_id)
+            gens = self.row_stats.get(index, field_name, view_name,
+                                      shards, row_id).gens
         self._touch_reads(index, field_name, view_name, shards, 1)
         return self._row_leaf_at(index, field_name, view_name, shards,
                                  row_id, gens)
@@ -695,14 +701,15 @@ class Executor:
     def _touch_reads(self, index: Index, field_name: str, view_name: str,
                      shards, reads: int) -> None:
         """Read heat at the fragment coordinate, one lock round trip for
-        the whole shard set (every consumer of row leaves — bitmap
-        programs, BSI planes, TopN recounts, GroupBy slabs — funnels
-        through _row_leaf_dev / _row_leaves_dev, so this is THE read
-        charge site)."""
+        the whole shard set: the read charge of _row_leaf_dev and
+        _row_leaves_dev (BSI planes, TopN recounts, GroupBy slabs). A
+        bitmap program's row leaves are charged together, once a request
+        (_compile_tree, _heat_charge)."""
         tracker = self.heat
         if tracker is not None and tracker.enabled:
-            tracker.touch_many([(index.name, field_name, view_name, s)
-                                for s in shards], reads=reads)
+            tracker.touch_many(self.row_stats.frag_keys(
+                index.name, field_name, view_name, tuple(shards)),
+                reads=reads)
 
     def _row_leaves_dev(self, index: Index, field_name: str, view_name: str,
                         shards, row_ids) -> list:
@@ -737,8 +744,8 @@ class Executor:
                 # probe (no hit/miss accounting) for a resident sparse
                 # twin under the SAME generations: any slot bucket the
                 # chooser could have used
-                card = self._row_max_card(index, field_name, view_name,
-                                          shards, row_id)
+                card = self.row_stats.get(index, field_name, view_name,
+                                          shards, row_id).max_card
                 skey = ("sparse", index.name, field_name, view_name,
                         row_id, tuple(shards), hyb.pad_slots(max(card, 1)),
                         gens)
@@ -750,7 +757,7 @@ class Executor:
                     # same probe for a resident RUN twin (interval-pair
                     # array): slot bucket comes from the write-maintained
                     # interval count, generation-cached like cardinality
-                    n_iv, _ = self._row_run_stats_max(
+                    n_iv, _ = self.row_stats.run_stats(
                         index, field_name, view_name, shards, row_id)
                     rkey = ("run", index.name, field_name, view_name,
                             row_id, tuple(shards),
@@ -768,42 +775,6 @@ class Executor:
             put=lambda h: (self.hybrid.record_upload("dense", h.nbytes),
                            self.runner.put_leaf(h))[1])
 
-    def _row_max_card(self, index: Index, field_name: str, view_name: str,
-                      shards, row_id: int) -> int:
-        """Largest per-shard cardinality of one row — the hybrid sizing
-        statistic (write-maintained, storage/fragment.py row_counts cache:
-        dict probes, not container walks)."""
-        f = index.field(field_name)
-        view = f.view(view_name) if f is not None else None
-        if view is None:
-            return 0
-        best = 0
-        for s in shards:
-            frag = view.fragment(s)
-            if frag is not None:
-                c = frag.row_cardinality(row_id)
-                if c > best:
-                    best = c
-        return best
-
-    def _row_run_stats_max(self, index: Index, field_name: str,
-                           view_name: str, shards, row_id: int):
-        """(max interval count, max run length) across shards — the run
-        sizing statistic (storage/fragment.py row_run_stats, generation-
-        cached: repeat reads are dict probes)."""
-        f = index.field(field_name)
-        view = f.view(view_name) if f is not None else None
-        if view is None:
-            return 0, 0
-        n_iv = max_run = 0
-        for s in shards:
-            frag = view.fragment(s)
-            if frag is not None:
-                n, m = frag.row_run_stats(row_id)
-                n_iv = max(n_iv, n)
-                max_run = max(max_run, m)
-        return n_iv, max_run
-
     def _row_leaf_run_dev(self, index: Index, field_name: str,
                           view_name: str, shards, row_id: int,
                           gens: tuple, slots: int):
@@ -816,14 +787,11 @@ class Executor:
         on upload, the TYPE_RUN regime of arXiv:1603.06549 carried to the
         device tier. Byte cost is the real padded allocation
         (S · 2 · slots · 4); pad shards fill with the sentinel in both
-        interval planes so they read as empty."""
+        interval planes so they read as empty. The read is the caller's
+        to charge (_compile_tree: once a request)."""
         from pilosa_tpu.ops import bitvector as bv
         key = ("run", index.name, field_name, view_name, row_id,
                tuple(shards), slots, gens)
-        tracker = self.heat
-        if tracker is not None and tracker.enabled:
-            tracker.touch_many([(index.name, field_name, view_name, s)
-                                for s in shards], reads=1)
         f = index.field(field_name)
         view = f.view(view_name) if f is not None else None
 
@@ -858,14 +826,11 @@ class Executor:
         hybrid representation for rows below the sparse threshold. Byte
         cost is the real padded allocation (S · slots · 4), charged to the
         residency budget like any leaf; pad shards fill with the sentinel
-        through put_leaf's fill parameter so they read as empty."""
+        through put_leaf's fill parameter so they read as empty. The read
+        is the caller's to charge (_compile_tree: once a request)."""
         from pilosa_tpu.ops import bitvector as bv
         key = ("sparse", index.name, field_name, view_name, row_id,
                tuple(shards), slots, gens)
-        tracker = self.heat
-        if tracker is not None and tracker.enabled:
-            tracker.touch_many([(index.name, field_name, view_name, s)
-                                for s in shards], reads=1)
         f = index.field(field_name)
         view = f.view(view_name) if f is not None else None
 
@@ -1266,9 +1231,13 @@ class Executor:
         marks leaf i "dense" ([S, W] uint32 plane), "sparse" ([S, slots]
         int32 sorted-index array) or "run" ([S, 2, slots] int32 interval
         pairs) — the hybrid representation the planner chose per row."""
+        from pilosa_tpu import planner as _planner
         leaves: list = []
         kinds: list = []
         shards_t = tuple(shards)
+        # (field, view) -> row leaves resolved: their reads are charged to
+        # the heat tracker once a request, below
+        reads: dict = {}
 
         def leaf(key: tuple, make):
             leaves.append(self.residency.leaf(key, make))
@@ -1279,6 +1248,22 @@ class Executor:
             leaves.append(arr)
             kinds.append(kind)
             return ("leaf", len(leaves) - 1)
+
+        def hybrid_leaf(c: Optional[Call], field_name: str, row_id: int):
+            rep, slots, gens = _planner.choose_representation(
+                self, index, c, field_name, VIEW_STANDARD, shards_t, row_id)
+            pair = (field_name, VIEW_STANDARD)
+            reads[pair] = reads.get(pair, 0) + 1
+            if rep == "sparse":
+                return leaf_arr(self._row_leaf_sparse_dev(
+                    index, field_name, VIEW_STANDARD, shards_t, row_id,
+                    gens, slots), "sparse")
+            if rep == "run":
+                return leaf_arr(self._row_leaf_run_dev(
+                    index, field_name, VIEW_STANDARD, shards_t, row_id,
+                    gens, slots), "run")
+            return leaf_arr(self._row_leaf_at(
+                index, field_name, VIEW_STANDARD, shards_t, row_id, gens))
 
         def row_leaf(c: Call):
             field_name = c.field_arg()
@@ -1292,20 +1277,7 @@ class Executor:
                             lambda: np.zeros((len(shards), WORDS), dtype=np.uint32))
             if f.options.type == FieldType.BOOL and isinstance(row_val, bool):
                 row_id = 1 if row_val else 0
-            from pilosa_tpu import planner as _planner
-            rep, slots, gens = _planner.choose_representation(
-                self, index, c, field_name, VIEW_STANDARD, shards, row_id)
-            if rep == "sparse":
-                return leaf_arr(self._row_leaf_sparse_dev(
-                    index, field_name, VIEW_STANDARD, shards, row_id,
-                    gens, slots), "sparse")
-            if rep == "run":
-                return leaf_arr(self._row_leaf_run_dev(
-                    index, field_name, VIEW_STANDARD, shards, row_id,
-                    gens, slots), "run")
-            return leaf_arr(self._row_leaf_dev(
-                index, field_name, VIEW_STANDARD, shards, row_id,
-                gens=gens))
+            return hybrid_leaf(c, field_name, row_id)
 
         def range_leaf(c: Call):
             if "_start" in c.args or "_end" in c.args:
@@ -1349,21 +1321,7 @@ class Executor:
             # the existence row is the archetypal run-container row (long
             # contiguous column ranges) — route it through the planner's
             # representation choice so it can upload as interval pairs
-            from pilosa_tpu import planner as _planner
-            rep, slots, gens = _planner.choose_representation(
-                self, index, None, EXISTENCE_FIELD_NAME, VIEW_STANDARD,
-                shards, 0)
-            if rep == "sparse":
-                return leaf_arr(self._row_leaf_sparse_dev(
-                    index, EXISTENCE_FIELD_NAME, VIEW_STANDARD, shards, 0,
-                    gens, slots), "sparse")
-            if rep == "run":
-                return leaf_arr(self._row_leaf_run_dev(
-                    index, EXISTENCE_FIELD_NAME, VIEW_STANDARD, shards, 0,
-                    gens, slots), "run")
-            return leaf_arr(self._row_leaf_dev(
-                index, EXISTENCE_FIELD_NAME, VIEW_STANDARD, shards, 0,
-                gens=gens))
+            return hybrid_leaf(None, EXISTENCE_FIELD_NAME, 0)
 
         def walk(c: Call):
             if c.name == "Row":
@@ -1397,6 +1355,7 @@ class Executor:
             raise ExecutionError(f"expected bitmap call, got {c.name}")
 
         program = walk(call)
+        self._heat_charge(index, reads, shards_t, reads=1)
         if not leaves:
             leaves.append(self.residency.leaf(
                 ("zeros", len(shards)),
@@ -1415,6 +1374,7 @@ class Executor:
 
         from pilosa_tpu import planner as _planner
         from pilosa_tpu.utils import accounting
+        shards = tuple(shards)  # the tuple every key below holds, once
         key = None
         pc = self.plan_cache
         if (pc is not None and pc.enabled
@@ -1490,33 +1450,51 @@ class Executor:
         tracker = self.heat
         if tracker is None or not tracker.enabled:
             return
-        pairs: list[tuple] = []
+        pairs: dict = {}  # (field, view) -> leaves of the tree on it
+
+        def note(field_name: str, view_name: str) -> None:
+            pair = (field_name, view_name)
+            pairs[pair] = pairs.get(pair, 0) + 1
 
         def walk(c: Call) -> None:
             if c.name == "Row":
-                pairs.append((c.field_arg(), VIEW_STANDARD))
+                note(c.field_arg(), VIEW_STANDARD)
             elif c.name == "Range":
                 cond_field = None
                 for k, v in c.args.items():
                     if isinstance(v, Condition):
                         cond_field = k
                 if cond_field is not None:
-                    pairs.append((cond_field, "bsig_" + cond_field))
+                    note(cond_field, "bsig_" + cond_field)
                 else:
                     fa = c.field_arg()
                     if fa:
-                        pairs.append((fa, VIEW_STANDARD))
+                        note(fa, VIEW_STANDARD)
             elif c.name == "Not":
-                pairs.append((EXISTENCE_FIELD_NAME, VIEW_STANDARD))
+                note(EXISTENCE_FIELD_NAME, VIEW_STANDARD)
             for ch in c.children:
                 walk(ch)
 
         walk(call)
-        if not pairs:
+        self._heat_charge(index, pairs, tuple(shards), reads=reads,
+                          device_ms=device_ms)
+
+    def _heat_charge(self, index: Index, pairs: dict, shards_t: tuple,
+                     reads: int = 0, device_ms: float = 0.0) -> None:
+        """Charge the fragments under `pairs` ((field, view) -> leaves on
+        it) in ONE round trip of the tracker's lock: a fragment is read
+        `reads` times a leaf on it, and `device_ms` is split a leaf and
+        then a shard, which is what a touch a leaf gave each fragment.
+        The coordinates come memoised (RowStatsMemo.frag_keys): no list
+        a leaf, no walk over the shards."""
+        tracker = self.heat
+        if not pairs or tracker is None or not tracker.enabled:
             return
-        tracker.touch_many(
-            [(index.name, f, v, s) for f, v in pairs for s in shards],
-            reads=reads, device_ms=device_ms)
+        n_leaves = sum(pairs.values())
+        tracker.touch_groups([
+            (self.row_stats.frag_keys(index.name, f, v, shards_t),
+             reads * n, device_ms * n / n_leaves)
+            for (f, v), n in pairs.items()])
 
     def _execute_bitmap_call(self, index: Index, call: Call, shards) -> Row:
         from pilosa_tpu import planner as _planner
@@ -1562,7 +1540,8 @@ class Executor:
         if _planner.is_empty_call(child):
             # planner short-circuit: zero leaves uploaded, zero dispatches
             return 0
-        shards = self._query_shards(index, shards)
+        # the tuple every key below holds, built here and passed down
+        shards = tuple(self._query_shards(index, shards))
         key = None
         epoch = 0
         pc = self.plan_cache
@@ -1617,8 +1596,7 @@ class Executor:
             # concurrent Counts coalesce into one device dispatch
             # (continuous batching — parallel/batcher.py; the batcher's
             # _run charges each co-batched query its wall-time share,
-            # and their heat was already charged per leaf in
-            # _row_leaf_dev)
+            # and their heat was already charged in _compile_tree)
             return self.batcher.count(*shape)
         elif (isinstance(program, tuple) and len(program) > 3
                 and program[0] == "and"

@@ -7,6 +7,7 @@ fragments by shard and creates them on demand (view.go:208-263).
 
 from __future__ import annotations
 
+import itertools
 import os
 import threading
 from typing import Optional
@@ -25,6 +26,11 @@ from pilosa_tpu.storage.fragment import Fragment
 
 VIEW_STANDARD = "standard"
 VIEW_BSI_PREFIX = "bsig_"
+
+# write versions are drawn from one process-wide sequence (next() on it
+# is one C call, atomic under the interpreter lock), so no two views, and
+# no view and its recreation under the same name, ever show the same one
+_WRITE_VERSIONS = itertools.count(1)
 
 
 def view_path(field_path: str, name: str) -> str:
@@ -52,6 +58,15 @@ class View:
         self.cache_size = cache_size
         self.cache_type = cache_type
         self.rank_caches: dict[int, RankCache] = {}
+        # write version: grows whenever a fragment of this view bumps its
+        # generation (Fragment._bump_generation, after the generation),
+        # is created or is dropped. What is computed from the fragments
+        # is stamped with the version read BEFORE them
+        # (parallel/residency.py RowStatsMemo).
+        self.version = next(_WRITE_VERSIONS)
+
+    def bump_version(self) -> None:
+        self.version = next(_WRITE_VERSIONS)
 
     # -- lifecycle ----------------------------------------------------------
 
@@ -87,14 +102,18 @@ class View:
             frag.close()
         self.fragments.clear()
         self.rank_caches.clear()
+        self.bump_version()
 
     def _open_fragment(self, shard: int) -> Fragment:
         frag = Fragment(
             os.path.join(self.path, "fragments", str(shard)),
             self.index, self.field, self.name, shard,
             wal_fsync=self.wal_fsync,
-        ).open()
+        )
+        frag.on_generation = self.bump_version
+        frag.open()
         self.fragments[shard] = frag
+        self.bump_version()
         if self.track_rank:
             cache_path = frag.path + ".cache"
             if os.path.exists(cache_path):
@@ -139,6 +158,7 @@ class View:
         frag = self.fragments.pop(shard, None)
         if frag is None:
             return
+        self.bump_version()
         frag.close()
         for p in (frag.path, frag.path + ".cache", frag.path + ".snapshotting",
                   frag.path + ".lock"):

@@ -641,6 +641,11 @@ class Handler:
             residency = getattr(ex, "residency", None)
             if residency is not None:
                 snap["deviceResidency"] = residency.snapshot()
+                row_stats = getattr(ex, "row_stats", None)
+                if row_stats is not None:
+                    # the row statistics memo beside the leaves' own
+                    # hits and misses (docs/operations.md)
+                    snap["deviceResidency"].update(row_stats.snapshot())
             snap["topnRecountRows"] = getattr(ex, "topn_recount_rows", 0)
             snap["groupByHostSyncs"] = getattr(ex, "groupby_host_syncs", 0)
             snap["topnPairsRecounts"] = getattr(ex, "topn_pairs_recounts", 0)

@@ -20,6 +20,7 @@ that the heat signal is load-bearing before tiering starts steering by it.
 
 from __future__ import annotations
 
+import dataclasses
 import os
 import threading
 from collections import OrderedDict
@@ -307,6 +308,147 @@ class DeviceResidency:
                     "evictions": self.evictions,
                     "heatEvictions": self.heat_evictions,
                     "eviction": self.eviction, "by_kind": by_kind}
+
+
+# ---------------------------------------------------------------------------
+# A row's per-shard statistics, per write version of its view
+# ---------------------------------------------------------------------------
+
+# entries the memo keeps (a row over one shard set each; about a KiB at 64
+# shards). Eviction only costs the next request of that row its six loops.
+ROW_STATS_BOUND = 1 << 16
+
+
+@dataclasses.dataclass(slots=True)
+class RowStats:
+    """What the read path asks of one row over one shard set, all of it a
+    pure function of the stored bits: `gens` the per-shard generations (()
+    where the view does not exist, as Executor._leaf_gens has it),
+    `max_card` and `total_card` the largest and the summed per-shard
+    cardinality, `frag_keys` the (index, field, view, shard) coordinates
+    (one list a field, view and shard set, shared: do not mutate), and
+    `run_stats` the (most intervals, longest run) of a shard, None until
+    RowStatsMemo.run_stats is asked for it. `version` is the view's write
+    version the entry was read under (None: no such view)."""
+
+    version: Optional[int]
+    gens: tuple
+    max_card: int
+    total_card: int
+    frag_keys: list
+    run_stats: Optional[tuple] = None
+
+
+class RowStatsMemo:
+    """Row statistics computed once per write version of the row's view
+    (models/view.py View.version), so that a served call reads them with
+    one dict probe and one integer comparison where the planner, the plan
+    cache's key, the representation choice and the leaf lookup each
+    walked every shard's fragment.
+
+    An entry is stamped with the version read BEFORE its fragments. A
+    write changes the bits, then its fragment's generation, then the
+    view's version, and only then is acknowledged
+    (storage/fragment.py _bump_generation): an entry that missed any of
+    it carries an older version and is computed anew.
+
+    The hit path takes no lock: each step is one call on an OrderedDict,
+    atomic under the interpreter lock, and `hits` / `misses` are plain
+    additions that may lose a count between threads."""
+
+    def __init__(self, bound: int = ROW_STATS_BOUND):
+        self.bound = max(1, int(bound))
+        self._lru: "OrderedDict[tuple, RowStats]" = OrderedDict()
+        self._frag_keys: dict[tuple, list] = {}
+        self._lock = threading.Lock()  # inserts and evictions only
+        self.hits = 0
+        self.misses = 0
+
+    def frag_keys(self, index_name: str, field_name: str, view_name: str,
+                  shards_t: tuple) -> list:
+        """The heat tracker's coordinates of one field and view over a
+        shard set, built once (shared: do not mutate)."""
+        key = (index_name, field_name, view_name, shards_t)
+        keys = self._frag_keys.get(key)
+        if keys is None:
+            if len(self._frag_keys) >= self.bound:
+                self._frag_keys.clear()
+            keys = self._frag_keys[key] = [
+                (index_name, field_name, view_name, s) for s in shards_t]
+        return keys
+
+    def get(self, index, field_name: str, view_name: str, shards,
+            row_id: int) -> RowStats:
+        """The row's statistics over `shards`; a request builds
+        tuple(shards) once and passes that down."""
+        shards_t = shards if type(shards) is tuple else tuple(shards)
+        f = index.field(field_name)
+        view = f.view(view_name) if f is not None else None
+        if view is None:
+            return RowStats(None, (), 0, 0, self.frag_keys(
+                index.name, field_name, view_name, shards_t), (0, 0))
+        version = view.version  # FIRST: see the class docstring
+        key = (index.name, field_name, view_name, row_id, shards_t)
+        stats = self._lru.get(key)
+        if stats is not None and stats.version == version:
+            self.hits += 1
+            try:
+                self._lru.move_to_end(key)
+            except KeyError:  # evicted since the probe: still this version
+                pass
+            return stats
+        self.misses += 1
+        gens = []
+        max_card = total_card = 0
+        for s in shards_t:
+            frag = view.fragment(s)
+            if frag is None:
+                gens.append(0)
+                continue
+            gens.append(frag.row_generation(row_id))
+            c = frag.row_cardinality(row_id)
+            total_card += c
+            if c > max_card:
+                max_card = c
+        stats = RowStats(version, tuple(gens), max_card, total_card,
+                         self.frag_keys(index.name, field_name, view_name,
+                                        shards_t))
+        with self._lock:
+            self._lru[key] = stats
+            self._lru.move_to_end(key)
+            while len(self._lru) > self.bound:
+                self._lru.popitem(last=False)
+        return stats
+
+    def run_stats(self, index, field_name: str, view_name: str, shards,
+                  row_id: int) -> tuple:
+        """(most intervals, longest run) of a shard of the row, walked on
+        the first ask and kept on the row's entry: only a row above the
+        sparse threshold needs it. Should a write land between the entry
+        and this walk, the entry's version is already an old one and the
+        next request reads both anew."""
+        stats = self.get(index, field_name, view_name, shards, row_id)
+        if stats.run_stats is None:
+            f = index.field(field_name)
+            view = f.view(view_name) if f is not None else None
+            n_iv = max_run = 0
+            for s in (shards if view is not None else ()):
+                frag = view.fragment(s)
+                if frag is not None:
+                    n, m = frag.row_run_stats(row_id)
+                    n_iv = max(n_iv, n)
+                    max_run = max(max_run, m)
+            stats.run_stats = (n_iv, max_run)
+        return stats.run_stats
+
+    def clear(self) -> None:
+        with self._lock:
+            self._lru.clear()
+            self._frag_keys.clear()
+
+    def snapshot(self) -> dict:
+        return {"rowStatsHits": self.hits, "rowStatsMisses": self.misses,
+                "rowStatsEntries": len(self._lru)}
 
 
 # ---------------------------------------------------------------------------

@@ -172,7 +172,7 @@ class QueryPlanner:
             return call, info
         from pilosa_tpu.executor import ExecutionError
         try:
-            planned = self._plan_top(index, call, list(shards), info)
+            planned = self._plan_top(index, call, tuple(shards), info)
         except ExecutionError:
             raise  # intended clean errors (zero-operand Intersect)
         except Exception:  # noqa: BLE001 — planning must never break a
@@ -372,16 +372,8 @@ class QueryPlanner:
 
     def _row_cardinality(self, index, field_name: str, view_name: str,
                          shards, row_id: int) -> int:
-        f = index.field(field_name)
-        view = f.view(view_name) if f is not None else None
-        if view is None:
-            return 0
-        total = 0
-        for s in shards:
-            frag = view.fragment(s)
-            if frag is not None:
-                total += frag.row_cardinality(row_id)
-        return total
+        return self.executor.row_stats.get(
+            index, field_name, view_name, shards, row_id).total_card
 
     def _existence_count(self, index, shards, memo) -> Optional[int]:
         if "ex" not in memo:
@@ -422,12 +414,12 @@ def subtree_cache_key(executor, index, call: Call,
     fragments on every lookup, so a write anywhere under the subtree
     produces a different key and invalidation costs nothing."""
     gens: list = []
-    shards_l = list(shards)
+    shards_t = tuple(shards)
 
     def leaf(field: str, view: str, row_id: int) -> None:
         gens.append(("r", field, view,
-                     executor._leaf_gens(index, field, view, shards_l,
-                                         row_id)))
+                     executor.row_stats.get(index, field, view, shards_t,
+                                            row_id).gens))
 
     def walk(c: Call) -> None:
         if c.name == "Row":
@@ -481,7 +473,7 @@ def subtree_cache_key(executor, index, call: Call,
             depth = f.bit_depth
             gens.append(("bsi", cond_field, depth, f.base, tuple(
                 executor._leaf_gens(index, cond_field, f.bsi_view_name,
-                                    shards_l, r)
+                                    shards_t, r)
                 for r in range(depth + 1))))
             return
         if c.name == "Not":
@@ -502,7 +494,7 @@ def subtree_cache_key(executor, index, call: Call,
         walk(call)
     except Exception:  # noqa: BLE001 — uncacheable shapes just miss
         return None
-    return (index.name, call.to_pql(), tuple(shards_l), tuple(gens))
+    return (index.name, call.to_pql(), shards_t, tuple(gens))
 
 
 def record_cache_event(call: Call, hit: bool) -> None:
@@ -535,52 +527,38 @@ def choose_representation(executor, index, call: Optional[Call],
     ICI router applied to representation).
 
     Returns (rep, padded slots, per-shard generations) — the generations
-    ride along because both the decision and the residency key need them
-    and the per-shard scan should run once. Hysteresis/heat state lives
-    in the executor's HybridManager (parallel/residency.py).
+    ride along because both the decision and the residency key need them.
+    What is read of the row comes from the executor's RowStatsMemo, a
+    walk over the shards once per write version of the view; the choice
+    itself is made anew every call, and its hysteresis/heat state lives
+    in the executor's HybridManager (both parallel/residency.py).
 
     `peek=True` is the EXPLAIN mode: the exact same decision WITHOUT
     advancing the hysteresis memory (HybridManager.choose peek), so
     explain-then-execute reports and then uses the same representation.
     `stats_out`, when given, receives the sizing statistics the decision
     read (maxShardCardinality, runIntervals) for the explain tree."""
-    gens = executor._leaf_gens(index, field_name, view_name, shards,
-                               row_id)
+    stats = executor.row_stats.get(index, field_name, view_name, shards,
+                                   row_id)
+    gens = stats.gens
     hyb = getattr(executor, "hybrid", None)
     if hyb is None or not hyb.active():
         if stats_out is not None:
             stats_out.update(maxShardCardinality=None, runIntervals=None)
         return "dense", 0, gens
-    f = index.field(field_name)
-    view = f.view(view_name) if f is not None else None
-    max_card = 0
-    if view is not None:
-        for s in shards:
-            frag = view.fragment(s)
-            if frag is not None:
-                c = frag.row_cardinality(row_id)
-                if c > max_card:
-                    max_card = c
+    max_card = stats.max_card
     run_stats = None
-    if (view is not None and max_card > hyb.threshold
-            and hyb.run_threshold > 0):
+    if max_card > hyb.threshold and hyb.run_threshold > 0:
         # above the sparse band: the run-vs-dense decision needs the
         # write-maintained interval statistics (storage/fragment.py
-        # row_run_stats — generation-cached, so repeat plans pay dict
-        # probes). Max across shards: the padded run leaf must cover the
-        # interval-richest shard.
-        n_iv = max_run = 0
-        for s in shards:
-            frag = view.fragment(s)
-            if frag is not None:
-                n, m = frag.row_run_stats(row_id)
-                n_iv = max(n_iv, n)
-                max_run = max(max_run, m)
-        run_stats = (n_iv, max_run)
+        # row_run_stats), the most over the shards: the padded run leaf
+        # must cover the interval-richest shard. Read on the memo's
+        # first ask for this row, kept with its entry after.
+        run_stats = executor.row_stats.run_stats(
+            index, field_name, view_name, shards, row_id)
     rep, slots = hyb.choose(
         (index.name, field_name, view_name, row_id), max_card,
-        frag_keys=[(index.name, field_name, view_name, s) for s in shards],
-        run_stats=run_stats, peek=peek)
+        frag_keys=stats.frag_keys, run_stats=run_stats, peek=peek)
     if stats_out is not None:
         stats_out.update(
             maxShardCardinality=int(max_card),

@@ -150,6 +150,10 @@ class Fragment:
         # of the reference's rowCache invalidation (fragment.go:435).
         self.generation = 0
         self._row_gen: dict[int, int] = {}
+        # called after every generation bump: the owning View's write
+        # version (models/view.py bump_version); None for a fragment
+        # opened on its own
+        self.on_generation = None
         # Floor for per-row generations: bulk mutations (roaring import,
         # resize tar restore) dirty every row at once; resetting per-row
         # generations to 0 would collide with the untouched-row key and
@@ -387,9 +391,33 @@ class Fragment:
 
     # -- mutation -----------------------------------------------------------
 
-    def _touch(self, row_id: int) -> None:
+    def _bump_generation(self, rows=None) -> int:
+        """The one place a generation grows: `rows` get the new
+        generation, None dirties every row (the bulk floor). ORDER IS THE
+        GUARANTEE: the stored bits changed before this call, the
+        fragment's own generation grows here, the view's write version
+        after it, and all three before the write is acknowledged. A
+        reader (parallel/residency.py RowStatsMemo) takes the view's
+        version FIRST and reads the fragments after, so what it stores
+        under an older version is recomputed, never served. Bumping the
+        view before the fragment would let a reader stamp the old
+        generations with the new version and serve a stale count after
+        an acknowledged write."""
         self.generation += 1
-        self._row_gen[row_id] = self.generation
+        gen = self.generation
+        if rows is None:
+            self._row_gen.clear()
+            self._bulk_gen = gen
+        else:
+            for row_id in rows:
+                self._row_gen[row_id] = gen
+        notify = self.on_generation
+        if notify is not None:
+            notify()
+        return gen
+
+    def _touch(self, row_id: int) -> None:
+        self._bump_generation((row_id,))
         self._block_checksums.pop(row_id // HASH_BLOCK_SIZE, None)
         if self._volatile:
             self.volatile_mutations += 1
@@ -479,10 +507,8 @@ class Fragment:
             # one generation bump for the whole batch; every row that saw
             # a changed mutation gets the new generation (residency and
             # plan-cache keys invalidate exactly once per batch)
-            self.generation += 1
-            gen = self.generation
+            self._bump_generation(changed_rows)
             for rid in changed_rows:
-                self._row_gen[rid] = gen
                 self._block_checksums.pop(rid // HASH_BLOCK_SIZE, None)
                 # run stats recompute lazily on the next planner read —
                 # a batch's net effect can split/merge arbitrarily many runs
@@ -958,9 +984,7 @@ class Fragment:
         self.storage = Bitmap.frozen(positions, presorted=presorted)
         self.storage.op_writer = None  # volatile: see docstring
         self._volatile = True
-        self.generation += 1
-        self._row_gen.clear()
-        self._bulk_gen = self.generation
+        self._bump_generation()
         self._block_checksums.clear()
         self._row_counts_cache = None
         self._row_ids_cache = None
@@ -1011,9 +1035,7 @@ class Fragment:
             # unions the incoming bitmap straight into storage); writer
             # state (including a frozen load's detached WAL) is preserved
             self.storage.union_in_place(Bitmap.from_bytes(data))
-        self.generation += 1
-        self._row_gen.clear()  # all rows considered dirty
-        self._bulk_gen = self.generation
+        self._bump_generation()  # all rows considered dirty
         self._block_checksums.clear()
         self._row_run_stats.clear()
         if self._volatile:
@@ -1286,9 +1308,7 @@ class Fragment:
             data = tar.extractfile(member).read()
         self.storage = Bitmap.from_bytes(data)
         self.storage.op_writer = self._op_file
-        self.generation += 1
-        self._row_gen.clear()
-        self._bulk_gen = self.generation
+        self._bump_generation()
         self._block_checksums.clear()
         self._row_run_stats.clear()
         if self._volatile:

@@ -209,30 +209,52 @@ class HeatTracker:
             return
         if now is None:
             now = time.monotonic()
+        with self._lock:
+            self._charge_locked(keys, now, reads, writes, device_ms,
+                                h2d_bytes, uploads, evictions)
+
+    def touch_groups(self, groups: list,
+                     now: Optional[float] = None) -> None:
+        """touch_many for several (keys, reads, device_ms) groups under
+        ONE lock acquisition: a request's leaves over several fields,
+        each field's fragments read as often as the request names it and
+        given its share of the device time."""
+        if not self.enabled:
+            return
+        if now is None:
+            now = time.monotonic()
+        with self._lock:
+            for keys, reads, device_ms in groups:
+                if keys:
+                    self._charge_locked(keys, now, reads, 0, device_ms,
+                                        0, 0, 0)
+
+    def _charge_locked(self, keys: list, now: float, reads: int,
+                       writes: int, device_ms: float, h2d_bytes: int,
+                       uploads: int, evictions: int) -> None:
         share_ms = device_ms / len(keys)
         share_bytes = h2d_bytes / len(keys)
-        with self._lock:
-            for key in keys:
-                e = self._f.get(key)
-                if e is None:
-                    if len(self._f) >= self.max_fragments:
-                        self._spill_locked(now)
-                    e = self._f[key] = _new_entry(now)
-                _decay(e, now)
-                if reads:
-                    e["reads"] += reads
-                    e["lastRead"] = now
-                    for i in range(len(HALF_LIVES)):
-                        e["rEwma"][i] += reads
-                if writes:
-                    e["writes"] += writes
-                    e["lastWrite"] = now
-                    for i in range(len(HALF_LIVES)):
-                        e["wEwma"][i] += writes
-                e["deviceMs"] += share_ms
-                e["h2dBytes"] += share_bytes
-                e["uploads"] += uploads
-                e["evictions"] += evictions
+        for key in keys:
+            e = self._f.get(key)
+            if e is None:
+                if len(self._f) >= self.max_fragments:
+                    self._spill_locked(now)
+                e = self._f[key] = _new_entry(now)
+            _decay(e, now)
+            if reads:
+                e["reads"] += reads
+                e["lastRead"] = now
+                for i in range(len(HALF_LIVES)):
+                    e["rEwma"][i] += reads
+            if writes:
+                e["writes"] += writes
+                e["lastWrite"] = now
+                for i in range(len(HALF_LIVES)):
+                    e["wEwma"][i] += writes
+            e["deviceMs"] += share_ms
+            e["h2dBytes"] += share_bytes
+            e["uploads"] += uploads
+            e["evictions"] += evictions
 
     def _spill_locked(self, now: float) -> None:
         """At capacity: merge the lowest-score entry's cumulative fields
