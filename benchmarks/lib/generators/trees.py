@@ -35,8 +35,9 @@ Keys of a mix:
   check_sample, check_min  how many answers the reference recomputes, and
              the fewest that make a run's comparison count
 Every request is Count(<tree>); its `label`, what the result line's
-`extra.by_leaves` groups latencies by, is the class of its template's
-leaf count (1-1, 2-3, 4-7, 8-15, 16-31).
+`extra.by_leaves` groups latencies by, is its template's leaf count: a
+request's time follows its leaves, and a percentile of the whole window
+lies between two of these groups (PERF.md section 2).
 """
 
 from __future__ import annotations
@@ -63,11 +64,6 @@ def n_leaves(template) -> int:
     if template[0] == "leaf":
         return 1
     return sum(n_leaves(c) for c in template[1])
-
-
-def label(template) -> str:
-    k = min(n_leaves(template), 16).bit_length()
-    return f"{1 << (k - 1)}-{(1 << k) - 1}"
 
 
 def size_class(row) -> int:
@@ -107,7 +103,7 @@ class Traffic:
             for i in rng.permutation(len(self.templates)):
                 ast = ("count", self._fill(rng, self.templates[i]))
                 yield {"pql": query.to_pql(ast), "ast": ast,
-                       "label": label(self.templates[i])}
+                       "label": str(n_leaves(self.templates[i]))}
 
     def warmup(self) -> list:
         stream = self._stream(np.random.default_rng([self.seed, 0x7AFF, 2]))

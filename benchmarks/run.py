@@ -80,8 +80,15 @@ def load_cell(name: str) -> tuple:
 
 
 def metrics_of(bench: dict, cell: dict, group: str) -> list:
-    return [m for m in bench[group]
+    """The cell's metrics of a group: those that list it and those that
+    list no cell; of the latter a per-layer metric only where the cell
+    reports the end-to-end metric it moves."""
+    mine = [m for m in bench[group]
             if "workloads" not in m or cell["name"] in m["workloads"]]
+    if group == "per_layer":
+        moved = {m["name"] for m in metrics_of(bench, cell, "end_to_end")}
+        mine = [m for m in mine if m["moves"] in moved]
+    return mine
 
 
 def server_argv(cfg_path: str) -> list:
@@ -387,7 +394,8 @@ def _run(args, bench, cell, config, mix, want_platform, tmp, kids,
     else:
         trace = reduce_trace(capture.get("doc"))
         ctx = {"vars_before": vars_before, "vars_after": vars_after,
-               "requests": len(sent), "trace": trace,
+               "requests": len(sent), "latencies_ms": [s.ms for s in sent],
+               "trace": trace,
                "window_compiles": window_compiles,
                "traced_bytes_needed": 0, "peaks": None}
         if device["platform"] != "cpu":

@@ -47,7 +47,8 @@ def test_same_seed_same_stream_and_every_seed_the_same_sizes(cell):
         assert sizes(a[i:i + mix["templates"]]) == \
             sizes(c[i:i + mix["templates"]])
     assert [q["label"] for q in a] != [q["label"] for q in c]
-    assert {q["label"] for q in a} == {"1-1", "2-3", "4-7", "8-15", "16-31"}
+    assert all(q["label"] == str(len(query.leaves(q["ast"][1]))) for q in a)
+    assert len({q["label"] for q in a}) > 12     # adhoc: 19 leaf counts
     assert all(set(q) == {"pql", "ast", "label"} for q in a)
 
 
@@ -74,8 +75,7 @@ def test_trees_are_the_random_query_tools(cell):
     req = gen.take()
     assert req["pql"] == query.to_pql(req["ast"])
     assert req["pql"].startswith("Count(")
-    lo, hi = map(int, req["label"].split("-"))
-    assert lo <= len(query.leaves(req["ast"][1])) <= hi
+    assert int(req["label"]) == len(query.leaves(req["ast"][1]))
 
 
 def test_every_seed_the_same_operand_sizes_and_rows_uniform(cell):
@@ -96,8 +96,9 @@ def test_every_seed_the_same_operand_sizes_and_rows_uniform(cell):
     b, reqs_b = shapes(42, mix["templates"])    # another seed, another pass
     assert a == b and len(a) > 50
     assert {q["pql"] for q in reqs_a} != {q["pql"] for q in reqs_b}
-    # a leaf's row is uniform over the field's rows: 100 templates hold 900
-    # leaves, so count the rows' classes over many populations instead
+    # a leaf's row is uniform over the field's rows: one population holds
+    # some 8 leaves a template (836 in adhoc's 100), so count the rows'
+    # classes over many populations instead
     n = collections.Counter(cls.values())
     drawn = collections.Counter()
     for k in range(30):
@@ -179,7 +180,9 @@ def test_flight_sends_weighted_passes_with_slots_drawn_afresh(flight_data):
     gen, warm, a = flight_stream(flight_data, 2_900_000_001, 7 * 40)
     _, warm_b, b = flight_stream(flight_data, 2_900_000_001, 7 * 40)
     _, _, c = flight_stream(flight_data, 6, 7 * 40)
-    assert gen.label_key == "by_query" and len(warm) == 7
+    # the walk (q1 once, q2 and q3 the six rows of pc, Count max(3, 6))
+    # and then the 7 drawn
+    assert gen.label_key == "by_query" and len(warm) == 1 + 6 + 6 + 6 + 7
     assert [q["pql"] for q in a] == [q["pql"] for q in b]
     assert [q["pql"] for q in warm] == [q["pql"] for q in warm_b]
     assert [q["pql"] for q in a] != [q["pql"] for q in c]
@@ -222,3 +225,60 @@ def test_flight_refuses_what_it_cannot_say(flight_data):
         "row": {"field": "cab", "draw": "heaviest"}}}])
     with pytest.raises(ValueError):
         flight.Traffic(bad, flight_data, 1).take()
+
+
+def slots_of(spec: dict) -> list:
+    """The field of every drawn slot of a mix's query."""
+    def under(doc):
+        (kind, body), = doc.items()
+        if kind == "row":
+            return [body["field"]] if "draw" in body else []
+        return [f for c in body for f in under(c)]
+    doc = spec.get("filter") or spec.get("tree")
+    return under(doc) if doc else []
+
+
+def rows_named(ast) -> set:
+    tree = ast[1] if ast[0] == "count" else ast[-1]
+    return set(query.leaves(tree)) if tree is not None else set()
+
+
+def walk_names_every_value(mix: dict, data, walk: list) -> None:
+    for spec in mix["queries"]:
+        label = spec.get("label", spec["call"])
+        mine = [q for q in walk if q["label"] == label]
+        named = set().union(*(rows_named(q["ast"]) for q in mine))
+        slots = slots_of(spec)
+        assert len(mine) == max([len(data.fields[f]) for f in slots],
+                                default=1), label
+        for field in slots:
+            assert {r for f, r in named if f == field} == set(
+                data.fields[field]), (label, field)
+
+
+def test_flight_warmup_names_every_value_of_every_slot(flight_data):
+    gen, warm, _ = flight_stream(flight_data, 2_900_000_001, 0)
+    walk = warm[:-FLIGHT["warmup_requests"]]
+    walk_names_every_value(FLIGHT, flight_data, walk)
+    # the walk is the same whatever the seed; the drawn part is not
+    _, other, _ = flight_stream(flight_data, 6, 0)
+    n = len(walk)
+    assert [q["pql"] for q in other[:n]] == [q["pql"] for q in walk]
+    assert [q["pql"] for q in other[n:]] != [q["pql"] for q in warm[n:]]
+    assert all(q["pql"] == query.to_pql(q["ast"]) for q in walk)
+
+
+def test_the_shipped_flight_warms_up_every_passenger_count_year_and_cab():
+    """taxi.flight (PR 36): a passenger count drawn `by_size` once in a
+    thousand is a sparse program shape of its own; the 66 drawn requests
+    met none and it compiled inside the window, six times a run."""
+    with open(os.path.join(BENCH, "configs", "taxi", "config.json")) as fh:
+        data = datagen.make(json.load(fh), 36, shards=1)
+    with open(os.path.join(BENCH, "traffic", "flight.json")) as fh:
+        mix = json.load(fh)
+    gen = byfile.load("lib/generators", "flight").Traffic(mix, data, 36)
+    walk = gen.warmup()[:-mix["warmup_requests"]]
+    walk_names_every_value(mix, data, walk)
+    assert {f for spec in mix["queries"] for f in slots_of(spec)} == {
+        "passenger_count", "pickup_year", "cab_type"}
+    assert len(walk) == 1 + 9 + 1 + 1 + 9 + 8 + 9
