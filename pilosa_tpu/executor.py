@@ -159,6 +159,10 @@ class Executor:
         self.topn_pairs_bytes = 0
         self.pairs_entries_built = 0
         self.pairs_entry_bytes = 0
+        # a TopN under tanimotoThreshold: candidates that entered the
+        # Tanimoto band and those it kept for the recount (/debug/vars)
+        self.topn_band_in = 0
+        self.topn_band_kept = 0
         # host syncs performed by GroupBy's device path — the pipelined
         # level loop promises at most ONE blocking fetch per cross-product
         # level (tests assert it, like topn_recount_rows; /debug/vars)
@@ -2162,15 +2166,18 @@ class Executor:
             # row evicted from one shard's cache undercounts in the merge
             # (executor.py _execute_topn recount rationale) and a stale
             # band test would drop rows whose true tanimoto qualifies.
-            with tracing.span("dispatch"):
-                handle = jnp.sum(popcount(src_dense))
-            with tracing.span("device.wait"):
-                scount = int(handle)
-            lo = scount * tanimoto / 100
-            hi = scount * 100 / tanimoto
-            exact = self._host_row_count_arr(index, f, shards, cand_ids)
-            keep = (exact > lo) & (exact < hi)
-            cand_ids, cand_counts = cand_ids[keep], exact[keep]
+            with tracing.span("topn.band"):
+                with tracing.span("dispatch"):
+                    handle = jnp.sum(popcount(src_dense))
+                with tracing.span("device.wait"):
+                    scount = int(handle)
+                lo = scount * tanimoto / 100
+                hi = scount * 100 / tanimoto
+                exact = self._host_row_count_arr(index, f, shards, cand_ids)
+                keep = (exact > lo) & (exact < hi)
+                self.topn_band_in += int(cand_ids.size)
+                cand_ids, cand_counts = cand_ids[keep], exact[keep]
+                self.topn_band_kept += int(cand_ids.size)
         with tracing.span("leaves"):
             entry = self._pairs_entry(index, f, shards)
         small = (np.isin(cand_ids, entry.ids) if entry is not None

@@ -654,6 +654,8 @@ class Handler:
             snap["topnPairsBytes"] = getattr(ex, "topn_pairs_bytes", 0)
             snap["pairsEntriesBuilt"] = getattr(ex, "pairs_entries_built", 0)
             snap["pairsEntryBytes"] = getattr(ex, "pairs_entry_bytes", 0)
+            snap["topnBandIn"] = getattr(ex, "topn_band_in", 0)
+            snap["topnBandKept"] = getattr(ex, "topn_band_kept", 0)
             batcher = getattr(ex, "batcher", None)
             if batcher is not None:
                 snap["countBatcher"] = batcher.snapshot()
