@@ -38,9 +38,25 @@ at 2^10 ... 2^19 slots a shard under a count vector of 128 (H = 1) and of
 16,384 (H = 128): the table ops/bitvector.py PAIRS_BY_COLUMN_SLOTS was set
 from (PR 32), the slots from which an entry that may lie by column does.
 
+--slab times the shipped program as a window slab (the rows' own words
+over the window their columns touch, a row a lane) beside by pairs, in two
+shapes of one shard. The similarity search's: --slab-rows fingerprints of
+about 48 bits over 2,048 columns (500,000 rows: 2^25 slots, H = 4,096),
+where by pairs' launch is also split into its gather (the bit test alone)
+and its one-hot product (the sum by rank with every bit taken as set). A
+grid field's (--rows rows across all 2^20 columns, --slots slots a shard,
+by column too), where the window is the whole shard and the slab 2 GiB a
+shard at 10,000 rows: the executor never takes it there. Each time is the
+host's, to the fetched count vector: at the similarity shape that fetch
+(2 MB) is most of the slab's time, and the device trace
+(`recount_roofline`) gives the launch alone.
+
     chiprun -- python3 benches/recount_kernels.py            # the chip
     chiprun -- python3 benches/recount_kernels.py --crossover
+    python3 benches/recount_kernels.py --slab                # on the chip
     python3 benches/recount_kernels.py --shards 2 --slots 8192 --rows 300
+    python3 benches/recount_kernels.py --slab --slab-rows 3000 --rows 300 \
+        --slots 8192
 """
 
 from __future__ import annotations
@@ -148,6 +164,107 @@ def crossover(args):
     print(json.dumps(out))
 
 
+def fingerprints(seed, R, draws=49, width=2048):
+    """R rows of `draws` positions drawn over `width` columns, repeats
+    dropped (about 48 bits a row at 49 draws): (columns, ranks) sorted by
+    rank, then column."""
+    rng = np.random.default_rng(seed)
+    p = np.sort(rng.integers(0, width, (R, draws), dtype=np.int32), axis=1)
+    keep = np.ones(p.shape, bool)
+    keep[:, 1:] = p[:, 1:] != p[:, :-1]
+    rank = np.repeat(np.arange(R, dtype=np.int32), draws)[keep.ravel()]
+    return p.ravel()[keep.ravel()], rank
+
+
+def slab(args):
+    """The shipped program by pairs and as a window slab, one shard, in the
+    similarity search's shape and in a grid field's: one line a shape."""
+    import jax
+    import jax.numpy as jnp
+
+    from pilosa_tpu.ops import bitvector as bv
+    out = {"device": jax.devices()[0].device_kind, "shapes": []}
+
+    def one(name, kept, R, slots, extra=()):
+        n_slots = bv.pairs_count_slots(R)
+        cols, rank = kept[0]
+        want = oracle(cols[None], rank[None], src, R)
+        lo, n_words = bv.slab_window(kept)
+        row = {"shape": name, "rows": R, "bits": int(cols.size),
+               "slots": slots, "H": n_slots // 128, "window_words": n_words,
+               "slab_bytes": n_words * n_slots * 4, "pairs_bytes": slots * 8}
+        dsrc = jax.device_put(src)
+        arr = jax.device_put(bv.pairs_by_pairs(kept, slots))
+        row["pairs"] = timed(lambda p, f: bv.pairs_count(p, f, n_slots)[:R],
+                             (arr, dsrc), want, args.reps)
+        for label, fn, a in extra:
+            row[label] = timed(fn, a(arr, dsrc), None, args.reps)
+        del arr
+        dev = jax.device_put(bv.pairs_by_slab(kept, lo, n_words, n_slots))
+        row["slab"] = timed(
+            lambda p, f, w: bv.pairs_count(p, f, n_slots, w)[:R],
+            (dev, dsrc, np.int32(lo)), want, args.reps)
+        row["slab_gb_per_s"] = (row["slab_bytes"] / 1e6
+                                / row["slab"]["ms_median"])
+        del dev
+        print(json.dumps(row), flush=True)
+        out["shapes"].append(row)
+
+    # the similarity search: fingerprints over 2,048 columns of one shard
+    R = args.slab_rows
+    cols, rank = fingerprints(38, R)
+    slots = 8
+    while slots < cols.size:
+        slots *= 2
+    n_slots = bv.pairs_count_slots(R)
+    rng = np.random.default_rng(380)
+    src = rng.integers(0, 1 << 32, size=(1, W), dtype=np.uint32)
+
+    @jax.jit
+    def gather_alone(pairs, f):
+        """by pairs' bit test over every slot, summed: the gather alone"""
+        return jnp.sum(bv._dense_bit_test(pairs[0, 0], f[0]).astype(
+            jnp.int32))
+
+    def onehot_alone(pairs, f):
+        """by pairs' launch with the bit test taken as every bit set: the
+        one-hot product alone (the body traced with the test replaced)"""
+        was = bv._dense_bit_test
+        bv._dense_bit_test = lambda c, plane: c < bv.SPARSE_SENTINEL
+        try:
+            return onehot_fn(pairs, f)
+        finally:
+            bv._dense_bit_test = was
+
+    onehot_fn = jax.jit(lambda p, f: bv.pairs_count_local(p, f, n_slots))
+    one("similarity", [(cols, rank)], R, slots, extra=(
+        ("pairs_gather_alone", gather_alone, lambda a, f: (a, f)),
+        ("pairs_onehot_alone", onehot_alone, lambda a, f: (a, f))))
+
+    # a grid field's shard: rows across all 2^20 columns
+    R, K = args.rows, args.slots
+    gcols, grank, _, _, gsrc = make(29, 1, K, R, 0.73, 0.05)
+    ok = gcols[0] < C
+    src = gsrc
+    kept = [(gcols[0][ok], grank[0][ok])]
+    n_slots = bv.pairs_count_slots(R)
+    bycol = jax.device_put(bv.pairs_by_column(kept))
+    one("grid", kept, R, K, extra=(
+        ("column", lambda b, f: bv.pairs_count(b, f, n_slots)[:R],
+         lambda a, f: (bycol, f)),))
+    del bycol
+    print("| shape | rows | bits | slab MB | pairs MB | by pairs ms | "
+          "slab ms | slab GB/s | agrees |\n|---|---|---|---|---|---|---|---|"
+          "---|")
+    for r in out["shapes"]:
+        print(f"| {r['shape']} | {r['rows']} | {r['bits']} | "
+              f"{r['slab_bytes'] / 1e6:.1f} | {r['pairs_bytes'] / 1e6:.1f} | "
+              f"{r['pairs']['ms_median']:.3f} | {r['slab']['ms_median']:.3f} "
+              f"| {r['slab_gb_per_s']:.1f} | "
+              f"{r['pairs']['agrees'] and r['slab']['agrees']} |")
+    print(json.dumps(out))
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--shards", type=int, default=32)
@@ -167,9 +284,17 @@ def main():
                     "column, over the slots a shard at H = 1 and H = 128")
     ap.add_argument("--min-log-slots", type=int, default=10,
                     help="--crossover starts at 2^this slots a shard")
+    ap.add_argument("--slab", action="store_true",
+                    help="only the shipped program as a window slab beside "
+                    "by pairs, at the similarity search's shape and a grid "
+                    "field's, one shard each")
+    ap.add_argument("--slab-rows", type=int, default=500_000,
+                    help="--slab: fingerprint rows of the similarity shape")
     args = ap.parse_args()
     if args.crossover:
         return crossover(args)
+    if args.slab:
+        return slab(args)
     import jax
     import jax.numpy as jnp
     S, K, R = args.shards, args.slots, args.rows

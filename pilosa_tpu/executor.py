@@ -73,16 +73,20 @@ class ExecutionError(ValueError):
 
 
 class PairsEntry:
-    """The small rows of one field, resident: `dev` int32[2, S', K] or, by
-    column, int32[1, S', 32, W] on the device (ops/bitvector.py
+    """The small rows of one field, resident: `dev` int32[2, S', K], by
+    column int32[1, S', 32, W] or as a window slab uint32[1, S', N, R]
+    whose window starts at word `lo` on the device (ops/bitvector.py
     pairs_count), `ids` the rows in rank order
     (ascending), `stored` the bits each holds over all shards. Residency
     charges it `nbytes`, like any leaf."""
 
-    def __init__(self, dev, ids: np.ndarray, stored: np.ndarray):
+    def __init__(self, dev, ids: np.ndarray, stored: np.ndarray,
+                 lo: Optional[int] = None):
         self.dev, self.ids, self.stored = dev, ids, stored
         self.nbytes = dev.nbytes
-        self.by_column = dev.ndim == 4
+        self.by_slab = lo is not None
+        self.lo = None if lo is None else np.int32(lo)
+        self.by_column = dev.ndim == 4 and not self.by_slab
 
 
 class Pairs(list):
@@ -144,6 +148,9 @@ class Executor:
         # fragment.go:112)
         self._row_cache: dict[tuple, np.ndarray] = {}
         self._row_cache_epoch = 0  # bumped by clear_caches(); fences misses
+        # (shape but the slots, placement) of every sparse block widened to
+        # planes so far: its slot buckets are compiled (_sparse_to_dense)
+        self._widened_blocks: set = set()
         # rows materialized for TopN recounts — observability for the
         # threshold-pruning walk (tests assert ≪ total rows; /debug/vars)
         self.topn_recount_rows = 0
@@ -152,10 +159,12 @@ class Executor:
         # the data and the request (4 a stored bit of the rows recounted,
         # 128 KiB a shard for the filter plane), not from what was
         # uploaded; of the launches, those over an entry laid by column
-        # (nothing gathered); entries built and the bytes they hold on
-        # the device
+        # (nothing gathered) and those over a window slab (neither a
+        # gather nor a one-hot product); entries built and the bytes they
+        # hold on the device
         self.topn_pairs_recounts = 0
         self.topn_pairs_recounts_by_column = 0
+        self.topn_pairs_recounts_by_slab = 0
         self.topn_pairs_bytes = 0
         self.pairs_entries_built = 0
         self.pairs_entry_bytes = 0
@@ -756,7 +765,7 @@ class Executor:
                 sp = self.residency.peek(skey)
                 if sp is not None:
                     hyb.record_materialize()
-                    return bv.sparse_to_dense(sp, WORDS)
+                    return self._sparse_to_dense(sp)
                 if hyb.run_threshold > 0:
                     # same probe for a resident RUN twin (interval-pair
                     # array): slot bucket comes from the write-maintained
@@ -1436,11 +1445,32 @@ class Executor:
         kind, arr = bv.eval_hybrid(program, leaves, kinds, WORDS)
         if kind == "sparse":
             self.hybrid.record_materialize()
-            return bv.sparse_to_dense(arr, WORDS)
+            return self._sparse_to_dense(arr)
         if kind == "run":
             self.hybrid.record_materialize()
             return bv.run_to_dense(arr, WORDS)
         return arr
+
+    def _sparse_to_dense(self, sp):
+        """The plane [S', W] of a sparse row or root [S', K]. The first
+        time a block of shards (its slot count and placement) is widened,
+        the program of every other slot bucket a row under the sparse
+        threshold can take is launched once on a row of no bits, so that
+        it compiles then: a bucket is the row's own size, and where every
+        request brings a cold row (a similarity search's query molecule)
+        the rare sizes would otherwise first compile under load."""
+        from pilosa_tpu.ops import bitvector as bv
+        block = (sp.shape[:-1], sp.sharding)
+        if block not in self._widened_blocks:
+            self._widened_blocks.add(block)
+            k = self.hybrid.pad_slots(1)
+            while k <= self.hybrid.pad_slots(self.hybrid.threshold):
+                if k != sp.shape[-1]:
+                    bv.sparse_to_dense(self.runner.put_leaf(
+                        np.full(sp.shape[:-1] + (k,), bv.SPARSE_SENTINEL,
+                                np.int32), fill=bv.SPARSE_SENTINEL), WORDS)
+                k <<= 1
+        return bv.sparse_to_dense(sp, WORDS)
 
     def _heat_call_touch(self, index: Index, call: Call, shards,
                          reads: int = 0, device_ms: float = 0.0) -> None:
@@ -2034,7 +2064,11 @@ class Executor:
         would take PAIRS_BY_COLUMN_SLOTS slots a shard or more (from
         there the gather by pairs costs more than the pass over every
         column) and 4 MiB a shard fit a quarter of the residency budget.
-        By pairs, sorted columns with the row's rank beside each, 8 bytes
+        As a window slab, the rows' own words over the one window of words
+        their columns touch, a row a lane, 4 bytes a word: where that is no
+        more than the pairs' bytes and fits the quarter (rows whose columns
+        all lie in a narrow window, a fingerprint's). By pairs, sorted
+        columns with the row's rank beside each, 8 bytes
         a slot: every other field. One entry a
         (field, view, shard set, fragment generations), built once however
         many threads ask (DeviceResidency.leaf is single-flight), charged
@@ -2092,11 +2126,24 @@ class Executor:
                     and len(shards) * SHARD_WIDTH * 4 <= quarter
                     and all(np.bincount(cols, minlength=1).max(initial=0)
                             <= 1 for cols, _ in kept))
-                if not by_column and len(shards) * slots * 8 > quarter:
+                # else as a window slab where it is no larger than the
+                # pairs and fits
+                lo, n_words = bv.slab_window(kept)
+                n_slots = bv.pairs_count_slots(ids.size)
+                by_slab = not by_column and (
+                    len(shards) * n_words * n_slots * 4
+                    <= min(len(shards) * slots * 8, quarter))
+                if (not (by_column or by_slab)
+                        and len(shards) * slots * 8 > quarter):
                     ids, slots = ids[:0], hyb.pad_slots(1)
                     kept = [(np.empty(0, np.int32),) * 2] * len(shards)
-                arr = (bv.pairs_by_column(kept) if by_column
-                       else bv.pairs_by_pairs(kept, slots))
+                if by_column:
+                    arr = bv.pairs_by_column(kept)
+                elif by_slab:
+                    arr = bv.pairs_by_slab(kept, lo, n_words, n_slots)
+                else:
+                    arr = bv.pairs_by_pairs(kept, slots)
+                built["lo"] = lo if by_slab else None
                 stored = np.zeros(ids.size, np.int64)
                 for _, rank in kept:
                     stored += np.bincount(rank, minlength=ids.size)
@@ -2106,7 +2153,7 @@ class Executor:
         def put(arr):
             hyb.record_upload("sparse", arr.nbytes)
             entry = PairsEntry(self.runner.put_pairs(arr), built["ids"],
-                               built["stored"])
+                               built["stored"], built["lo"])
             self.pairs_entries_built += 1
             self.pairs_entry_bytes += entry.nbytes
             return entry
@@ -2122,9 +2169,11 @@ class Executor:
         at = np.searchsorted(entry.ids, row_ids)
         with tracing.span("dispatch"):
             handle = self.runner.pairs_count(
-                entry.dev, src_dense, pairs_count_slots(entry.ids.size))
+                entry.dev, src_dense, pairs_count_slots(entry.ids.size),
+                entry.lo)
         self.topn_pairs_recounts += 1
         self.topn_pairs_recounts_by_column += int(entry.by_column)
+        self.topn_pairs_recounts_by_slab += int(entry.by_slab)
         self.topn_pairs_bytes += (4 * int(entry.stored[at].sum())
                                   + n_shards * WORDS * 4)
 

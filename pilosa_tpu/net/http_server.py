@@ -651,6 +651,8 @@ class Handler:
             snap["topnPairsRecounts"] = getattr(ex, "topn_pairs_recounts", 0)
             snap["topnPairsRecountsByColumn"] = getattr(
                 ex, "topn_pairs_recounts_by_column", 0)
+            snap["topnPairsRecountsBySlab"] = getattr(
+                ex, "topn_pairs_recounts_by_slab", 0)
             snap["topnPairsBytes"] = getattr(ex, "topn_pairs_bytes", 0)
             snap["pairsEntriesBuilt"] = getattr(ex, "pairs_entries_built", 0)
             snap["pairsEntryBytes"] = getattr(ex, "pairs_entry_bytes", 0)

@@ -433,8 +433,8 @@ def sparse_to_dense(sp: jax.Array, n_words: int) -> jax.Array:
 
 # -- the small rows of one field, recounted under a filter in one launch -----
 # A `pairs` entry (executor._pairs_entry) holds every row of a field whose
-# fullest shard has no more bits than the sparse threshold, in one of two
-# layouts, the shard axis second in both. By pairs, int32[2, S, K]: per
+# fullest shard has no more bits than the sparse threshold, in one of three
+# layouts, the shard axis second in all. By pairs, int32[2, S, K]: per
 # shard the rows' concatenated sorted columns (plane 0, sentinel-padded)
 # and beside each the row's rank in the entry's id list (plane 1). By
 # column, int32[1, S, 32, W], bit-major: at [0, s, b, w] the rank of the
@@ -451,7 +451,15 @@ def sparse_to_dense(sp: jax.Array, n_words: int) -> jax.Array:
 # where they lie: bit b of a tile of words, `(words >> b) & 1`, is a lane
 # vector that lines up with row b of the entry's tile element for element,
 # with no reshape across lanes (column order, bit minor, needs a
-# [tile, 32] -> [32 · tile] relayout a step).
+# [tile, 32] -> [32 · tile] relayout a step). As a window slab,
+# uint32[1, S, N, R]: the rows' own words, word-major, over the N words
+# [lo, lo + N) that cover every stored column of every shard, the row of
+# rank r on lane r (R = pairs_count_slots(rows), so the count vector is the
+# slab's last axis): a row of a few dozen bits is N words where by pairs it
+# is as many slots of 8 bytes, and where the columns of all the rows lie in
+# a narrow window (a fingerprint's 2,048 positions) that is the smaller
+# entry. Its recount is a streaming AND + popcount summed over the shard
+# and word axes, with neither a gather nor a one-hot product.
 
 # entry slots one scan step covers, all of one shard: by column a tile of
 # 2,048 filter words and the [32, 2048] ranks under their bits, by pairs
@@ -508,10 +516,65 @@ def pairs_by_pairs(shards: list, slots: int) -> np.ndarray:
     return arr
 
 
-def pairs_count_local(pairs: jax.Array, src: jax.Array,
-                      n_slots: int) -> jax.Array:
+# words a window slab covers at the least: one sublane tile of the [N, R]
+# words a shard, which fewer words would be padded to on the device anyway
+SLAB_WORDS_MIN = 8
+
+
+def slab_window(shards: list) -> tuple[int, int]:
+    """(lo, n) of the one window slab over [(columns, ranks)] a shard: n
+    the power of two of words, at least SLAB_WORDS_MIN, over the words
+    that any shard's columns touch, lo its first word, moved down where
+    the window would pass the shard's last word."""
+    touched = [(int(c.min()) >> 5, int(c.max()) >> 5) for c, _ in shards
+               if c.size]
+    lo = min((a for a, _ in touched), default=0)
+    hi = max((b for _, b in touched), default=0) + 1
+    n = SLAB_WORDS_MIN
+    while n < hi - lo:
+        n <<= 1
+    return min(lo, SHARD_WIDTH // WORD_BITS - n), n
+
+
+def pairs_by_slab(shards: list, lo: int, n_words: int,
+                  n_slots: int) -> np.ndarray:
+    """Host-side builder of an entry as a window slab: [(columns, ranks)]
+    a shard -> uint32[1, S, n_words, n_slots], at [0, s, w, r] word lo + w
+    of shard s of the row of rank r. Every stored bit is one bit position
+    of the shard's [n_words, n_slots] words, all of them set in one pass
+    of the storage library's positions_to_dense."""
+    from pilosa_tpu.native import positions_to_dense
+
+    arr = np.empty((1, len(shards), n_words, n_slots), np.uint32)
+    for s, (cols, rank) in enumerate(shards):
+        pos = ((cols >> 5) - lo).astype(np.int64)
+        pos *= n_slots
+        pos += rank
+        pos <<= 5
+        pos |= cols & 31
+        arr[0, s] = positions_to_dense(
+            pos.view(np.uint64), 0, n_words * n_slots * WORD_BITS
+        ).reshape(n_words, n_slots)
+    return arr
+
+
+def _slab_count(slab: jax.Array, src: jax.Array, lo) -> jax.Array:
+    """counts int32[R] of a window slab uint32[1, S, N, R] under src
+    uint32[S, W]: Σ_s Σ_w popcount(slab[0, s, w, r] & src[s, lo + w]), the
+    filter's window broadcast along the lanes. One streaming pass over the
+    slab, fused into the sum: nothing of its size is materialized."""
+    win = lax.dynamic_slice_in_dim(src, lo, slab.shape[2], axis=1)
+    hit = lax.population_count(slab[0] & win[:, :, None])
+    return jnp.sum(hit.astype(jnp.int32), axis=(0, 1))
+
+
+def pairs_count_local(pairs: jax.Array, src: jax.Array, n_slots: int,
+                      lo=None) -> jax.Array:
     """counts int32[n_slots] of one block of shards: pairs int32[2, S, K]
-    or, by column, int32[1, S, 32, W]; src uint32[S, W]. One scan over
+    or, by column, int32[1, S, 32, W], or a window slab uint32[1, S, N,
+    n_slots] whose window starts at word `lo` (an operand, so that a
+    rebuilt entry's window compiles nothing; the slab is
+    `_slab_count`'s); src uint32[S, W]. The other two layouts: one scan over
     steps of PAIRS_STEP slots, each within one shard. A step takes its
     ranks as r int32[A, T], slots along lanes, -1 where the filter has no
     bit (by pairs A = 1 and the bit is `_dense_bit_test`'s gather from
@@ -525,6 +588,8 @@ def pairs_count_local(pairs: jax.Array, src: jax.Array,
     Nothing of the entry's size is materialized beside it: sixteen
     request threads may have this program in flight at once. Timings of
     the alternatives: benches/recount_kernels.py, PERF.md section 6."""
+    if pairs.dtype == jnp.uint32:
+        return _slab_count(pairs, src, 0 if lo is None else lo)
     by_column = pairs.ndim == 4
     if by_column:
         pairs = pairs[0]
@@ -562,10 +627,11 @@ def pairs_count_local(pairs: jax.Array, src: jax.Array,
 
 
 @counted_jit("sparse", cross_shard=True, static_argnames=("n_slots",))
-def pairs_count(pairs: jax.Array, src: jax.Array, n_slots: int) -> jax.Array:
+def pairs_count(pairs: jax.Array, src: jax.Array, n_slots: int,
+                lo=None) -> jax.Array:
     """|row ∩ src| for every row of a pairs entry, summed over shards on
     the device: one launch and one fetch of n_slots int32 a TopN."""
-    return pairs_count_local(pairs, src, n_slots)
+    return pairs_count_local(pairs, src, n_slots, lo)
 
 
 def sparse_from_columns(columns: np.ndarray, slots: int) -> np.ndarray:

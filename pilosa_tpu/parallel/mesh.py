@@ -542,20 +542,22 @@ def groupby_chunk_live_mesh(mesh: Mesh, axis_slabs: tuple, idx: tuple,
 
 
 @functools.lru_cache(maxsize=None)
-def _pairs_count_mesh_fn(mesh: Mesh, n_slots: int, ndim: int):
+def _pairs_count_mesh_fn(mesh: Mesh, n_slots: int, ndim: int,
+                         windowed: bool):
     """Per-mesh, per-size shard_map program of ops/bitvector.pairs_count:
-    every device counts over its own shard slots (the entry, in either
+    every device counts over its own shard slots (the entry, in any
     layout, is sharded on its second axis and the filter on its first),
-    one psum on the interconnect, the count vector replicated."""
+    one psum on the interconnect, the count vector replicated. A window
+    slab's first word (`windowed`) is one more operand, replicated."""
     entry = P(None, SHARD_AXIS, *([None] * (ndim - 2)))
+    specs = (entry, P(SHARD_AXIS, None)) + ((P(),) if windowed else ())
 
     @jax.jit
     @functools.partial(
-        jax.shard_map, mesh=mesh,
-        in_specs=(entry, P(SHARD_AXIS, None)),
+        jax.shard_map, mesh=mesh, in_specs=specs,
         out_specs=P(), check_vma=False)
-    def pairs_count_mesh(pairs, src):
-        return jax.lax.psum(pairs_count_local(pairs, src, n_slots),
+    def pairs_count_mesh(pairs, src, *lo):
+        return jax.lax.psum(pairs_count_local(pairs, src, n_slots, *lo),
                             SHARD_AXIS)
 
     return pairs_count_mesh
@@ -653,28 +655,32 @@ class DeviceRunner:
         return self._put_shard_padded(rows, 0, fill=fill)
 
     def put_pairs(self, pairs: np.ndarray) -> jax.Array:
-        """Place one pairs entry (ops/bitvector.py), by pairs int32[2, S, K]
-        or by column int32[1, S, 32, W]: the shard axis, the second of
-        both, padded with what reads as no bit (the sparse sentinel, no
-        rank) and sharded like a leaf's."""
-        return self._put_shard_padded(
-            pairs, 1, fill=-1 if pairs.ndim == 4 else SPARSE_SENTINEL)
+        """Place one pairs entry (ops/bitvector.py), by pairs int32[2, S, K],
+        by column int32[1, S, 32, W] or as a window slab uint32[1, S, N, R]:
+        the shard axis, the second of all three, padded with what reads as
+        no bit (the sparse sentinel, no rank, no word) and sharded like a
+        leaf's."""
+        fill = (0 if pairs.dtype == np.uint32
+                else -1 if pairs.ndim == 4 else SPARSE_SENTINEL)
+        return self._put_shard_padded(pairs, 1, fill=fill)
 
     def pairs_count(self, pairs: jax.Array, src: jax.Array,
-                    n_slots: int) -> jax.Array:
+                    n_slots: int, lo=None) -> jax.Array:
         """int32[n_slots] device counts of a pairs entry's rows under the
-        filter plane `src` [S', W], launched and not fetched. With a mesh
-        the explicit shard_map + psum form, launched like every program
-        that holds a collective on the one collective thread."""
+        filter plane `src` [S', W], launched and not fetched (`lo`: a
+        window slab's first word). With a mesh the explicit shard_map +
+        psum form, launched like every program that holds a collective on
+        the one collective thread."""
         if self.mesh is None:
-            return pairs_count(pairs, src, n_slots)
+            return pairs_count(pairs, src, n_slots, lo)
+        lo = () if lo is None else (lo,)
 
         def launch():
             record_dispatch("ici_program", self.mesh, "pairs", n_slots,
                             pairs, src, devices=self.mesh.size,
                             collective=True)
-            return _pairs_count_mesh_fn(self.mesh, n_slots,
-                                        pairs.ndim)(pairs, src)
+            return _pairs_count_mesh_fn(self.mesh, n_slots, pairs.ndim,
+                                        bool(lo))(pairs, src, *lo)
 
         return on_collective_thread(launch)
 

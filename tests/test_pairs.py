@@ -1,14 +1,17 @@
 """The recount of a field's small rows from their sorted columns: the
 kernel (ops/bitvector.py pairs_count, its shard_map form in
-parallel/mesh.py) against a numpy oracle, and the resident entry
-(executor._pairs_entry) on a field of 10,000 rows: no plane stacked for a
-row below the sparse threshold, one build however many threads ask, and
-the cases that fall to the dense walk whole (the hybrid representation
-off; an entry that would not fit a quarter of the residency budget)."""
+parallel/mesh.py) in its three layouts against a numpy oracle, and the
+resident entry (executor._pairs_entry) on a field of 10,000 rows: no plane
+stacked for a row below the sparse threshold, one build however many
+threads ask, the layout the rows' columns choose, and the cases that fall
+to the dense walk whole (the hybrid representation off; an entry that
+would not fit a quarter of the residency budget)."""
 
+import json
 import pathlib
 import re
 import threading
+import urllib.request
 
 import jax
 import numpy as np
@@ -234,6 +237,119 @@ def test_mesh_form_agrees(n_devices, n_shards, slots):
     assert (got[:n_rows] == oracle(shards, src, n_rows)).all()
 
 
+def window_entry(seed, n_shards, n_rows, c_lo, c_hi, empty_shards=()):
+    """Rows of 1 to 60 bits a shard whose columns all lie in [c_lo, c_hi)
+    (a fingerprint's positions), the window's first and last column in
+    row 0 of the first shard that holds rows; the filter random."""
+    rng = np.random.default_rng(seed)
+    shards = []
+    for s in range(n_shards):
+        rows = {}
+        if s not in empty_shards:
+            for rank in range(n_rows):
+                n = int(rng.integers(1, min(60, c_hi - c_lo) + 1))
+                rows[rank] = np.sort(rng.choice(np.arange(c_lo, c_hi), n,
+                                                replace=False))
+        shards.append(rows)
+    first = next(r for r in shards if r)
+    first[0] = np.union1d(first[0], [c_lo, c_hi - 1])
+    src = rng.integers(0, 1 << 32, size=(n_shards, W), dtype=np.uint32)
+    return shards, src
+
+
+def slab_of(shards, n_rows):
+    kept = _kept(shards)
+    lo, n_words = bv.slab_window(kept)
+    n_slots = bv.pairs_count_slots(n_rows)
+    return bv.pairs_by_slab(kept, lo, n_words, n_slots), np.int32(lo)
+
+
+SLAB_CASES = {
+    # shards, rows, the columns' window, shards left without rows
+    "one-shard-fingerprints": (1, 500, 0, 2048, ()),
+    "several-shards": (3, 300, 0, 2048, ()),
+    "an-empty-shard": (3, 130, 0, 2048, (1,)),
+    "window-off-word-0": (2, 129, 5000, 5700, ()),      # R no multiple of 128
+    "window-at-the-shard-end": (2, 77, SHARD_WIDTH - 1000, SHARD_WIDTH, ()),
+    "two-words-across-an-edge": (1, 1, 31, 33, ()),
+}
+
+
+@pytest.mark.parametrize("case", SLAB_CASES)
+def test_slab_count_equals_pairs_and_oracle(case):
+    n_shards, n_rows, c_lo, c_hi, empty = SLAB_CASES[case]
+    shards, src = window_entry(n_rows, n_shards, n_rows, c_lo, c_hi, empty)
+    slab, lo = slab_of(shards, n_rows)
+    n_slots = bv.pairs_count_slots(n_rows)
+    assert slab.dtype == np.uint32 and slab.shape[:2] == (1, n_shards)
+    assert slab.shape[3] == n_slots
+    got = np.asarray(bv.pairs_count(slab, src, n_slots, lo))
+    want = oracle(shards, src, n_rows)
+    assert (got[:n_rows] == want).all() and not got[n_rows:].any()
+    slots = 8
+    while slots < max(sum(c.size for c in r.values()) for r in shards):
+        slots *= 2
+    pairs = entry_of(shards, n_rows, slots)
+    assert (np.asarray(bv.pairs_count(pairs, src, n_slots)) == got).all()
+    # the window's edges are in it: a filter of those two columns alone
+    one = np.zeros_like(src)
+    for c in (c_lo, c_hi - 1):
+        one[:, c >> 5] |= np.uint32(1 << (c & 31))
+    edge = np.asarray(bv.pairs_count(slab, one, n_slots, lo))
+    assert edge[0] == oracle(shards, one, n_rows)[0] >= 2
+
+
+def test_slab_window_covers_the_columns_in_a_power_of_two():
+    """One window for every shard: the words any shard's columns touch,
+    a power of two of them and at least SLAB_WORDS_MIN, moved down where
+    it would pass the shard's last word; no column, the least window."""
+    def kept(*cols):
+        return [(np.asarray(c, np.int32), np.zeros(len(c), np.int32))
+                for c in cols]
+
+    assert bv.slab_window(kept([0, 2047])) == (0, 64)
+    assert bv.slab_window(kept([64], [2047, 2048])) == (2, 64)
+    assert bv.slab_window(kept([100])) == (3, bv.SLAB_WORDS_MIN)
+    assert bv.slab_window(kept([SHARD_WIDTH - 1])) == (W - 8, 8)
+    assert bv.slab_window(kept([], [])) == (0, bv.SLAB_WORDS_MIN)
+    assert bv.slab_window(kept([0, SHARD_WIDTH - 1])) == (0, W)
+
+
+def test_slab_lowers_to_a_streaming_sum():
+    """As a window slab nothing is gathered or scattered and nothing goes
+    to the matrix unit, and the program keeps the recount's name."""
+    slab = jax.ShapeDtypeStruct((1, 2, 64, 512), np.uint32)
+    src = jax.ShapeDtypeStruct((2, W), np.uint32)
+    lo = jax.ShapeDtypeStruct((), np.int32)
+    lowered = bv.pairs_count.lower(slab, src, 512, lo)
+    ops = set(re.findall(r"stablehlo\.(\w+)", lowered.as_text()))
+    assert not ops & {"gather", "scatter", "dot_general"}, sorted(ops)
+    assert "popcnt" in ops
+    text = lowered.compile().as_text()
+    assert re.search(r"HloModule (\w+)", text).group(1) == "jit_pairs_count"
+
+
+@pytest.mark.parametrize("n_devices,n_shards", [(4, 4), (4, 6), (4, 1),
+                                                (8, 3)])
+def test_mesh_form_agrees_as_a_slab(n_devices, n_shards):
+    """A window slab on the CPU's forced devices: sharded on its second
+    axis, pad shards of no word, the window's first word replicated; the
+    counts the single-device kernel's."""
+    mesh = make_mesh(jax.devices()[:n_devices])
+    runner = DeviceRunner(mesh)
+    n_rows = 200
+    shards, src = window_entry(n_shards + 40, n_shards, n_rows, 3000, 5048)
+    slab, lo = slab_of(shards, n_rows)
+    n_slots = bv.pairs_count_slots(n_rows)
+    dev = runner.put_pairs(slab)
+    assert dev.shape[1] % n_devices == 0 and dev.dtype == np.uint32
+    got = np.asarray(runner.pairs_count(dev, runner.put_leaf(src), n_slots,
+                                        lo))
+    assert (got[:n_rows] == oracle(shards, src, n_rows)).all()
+    single = np.asarray(DeviceRunner().pairs_count(slab, src, n_slots, lo))
+    assert (got == single).all()
+
+
 # ------------------------------------------------------- the resident entry
 
 N_ROWS = 10_000
@@ -377,6 +493,7 @@ def test_the_layout_is_the_faster_recount(tmp_path, case):
             assert ex.topn_recount_rows == 0
             assert ex.topn_pairs_recounts == launches
             assert ex.topn_pairs_recounts_by_column == launches * by_column
+            assert ex.topn_pairs_recounts_by_slab == 0
         (key,) = [k for k, _ in ex.residency.entries_snapshot()
                   if k[0] == "pairs"]
         entry = ex.residency.peek(key)
@@ -455,3 +572,162 @@ def test_cases_that_fall_to_the_dense_walk(wide, why):
         (r, by_id[r]) for r in (0, 1, 5000, 9999) if r in by_id)
     assert ex.topn_pairs_recounts == 0
     assert ex.topn_recount_rows == 4
+
+
+def _fingerprints(rng, n_rows, n_shards, spread):
+    """Rows of 30 to 60 bits a shard over the first 2,048 columns of every
+    shard (`spread` False: a fingerprint's positions) or anywhere in it."""
+    width = SHARD_WIDTH if spread else 2048
+    rows_l, cols_l = [], []
+    for s in range(n_shards):
+        for r in range(n_rows):
+            c = rng.choice(width, int(rng.integers(30, 61)), replace=False)
+            rows_l.append(np.full(c.size, r, np.uint64))
+            cols_l.append((s * SHARD_WIDTH + c).astype(np.uint64))
+    return np.concatenate(rows_l), np.concatenate(cols_l)
+
+
+SLAB_RULE_CASES = {
+    # shards, rows spread over the whole shard, the residency budget,
+    # the layout the entry takes
+    "fingerprints-one-shard": (1, False, None, "slab"),
+    "fingerprints-two-shards": (2, False, None, "slab"),
+    "rows-across-the-shard": (2, True, None, "pairs"),
+    # a quarter of it is one byte short of the slab: no room in either
+    "no-room-for-the-slab": (1, False, 4 * 64 * 512 * 4 - 4, "none"),
+}
+
+
+@pytest.mark.parametrize("case", SLAB_RULE_CASES)
+def test_the_rows_columns_choose_the_slab(tmp_path, case):
+    """500 rows of 30-60 bits a shard: over a fingerprint's 2,048 columns
+    the window slab (64 words × 512 lanes, 128 KiB a shard) is smaller
+    than the pairs (2^15 slots × 8 B) and the entry takes it; across the
+    whole shard the slab would be 64 MiB a shard, and the entry lies by
+    pairs; where the slab passes a quarter of the budget so do the pairs,
+    and every row goes to the dense walk. Same Pairs as brute force every
+    time, and topn_pairs_recounts_by_slab counts the slab launches
+    alone."""
+    n_shards, spread, budget, layout = SLAB_RULE_CASES[case]
+    rng = np.random.default_rng(38)
+    n_rows = 500
+    rows, cols = _fingerprints(rng, n_rows, n_shards, spread)
+    h = Holder(str(tmp_path / "d")).open()
+    try:
+        idx = h.create_index("c", track_existence=False)
+        idx.create_field("fp", FieldOptions(cache_size=50000)).import_bits(
+            rows, cols)
+        filt = np.flatnonzero(rng.random(n_shards * SHARD_WIDTH) < 0.3)
+        idx.create_field("f").import_bits(np.zeros(filt.size, np.uint64),
+                                          filt.astype(np.uint64))
+        ex = Executor(h)
+        if budget is not None:
+            ex.residency.budget = budget
+        hit = np.isin(cols, filt)
+        under = np.bincount(rows[hit].astype(np.int64), minlength=n_rows)
+        want = sorted(((int(c), -r) for r, c in enumerate(under) if c),
+                      reverse=True)
+        for launches, n in enumerate((40, 7), start=1):
+            (got,) = ex.execute("c", f"TopN(fp, Row(f=0), n={n})")
+            assert [tuple(p) for p in got] == [(-nr, c)
+                                               for c, nr in want[:n]]
+            if layout == "none":
+                assert ex.topn_pairs_recounts == 0 < ex.topn_recount_rows
+                continue
+            assert ex.topn_recount_rows == 0
+            assert ex.topn_pairs_recounts == launches
+            assert ex.topn_pairs_recounts_by_slab == launches * (
+                layout == "slab")
+            assert ex.topn_pairs_recounts_by_column == 0
+        if layout == "none":
+            return
+        (key,) = [k for k, _ in ex.residency.entries_snapshot()
+                  if k[0] == "pairs"]
+        entry = ex.residency.peek(key)
+        assert entry.by_slab == (layout == "slab") and not entry.by_column
+        assert entry.ids.size == n_rows
+        if layout == "slab":
+            assert entry.dev.shape == (1, n_shards, 64, 512)
+            assert entry.dev.dtype == np.uint32 and int(entry.lo) == 0
+        else:
+            assert entry.dev.shape[:2] == (2, n_shards)
+    finally:
+        h.close()
+
+
+def test_debug_vars_count_the_slab_launches(tmp_path):
+    """/debug/vars: `topnPairsRecountsBySlab` beside `topnPairsRecounts`,
+    one a filtered TopN over a field of fingerprints, none over a field
+    whose rows run across the shard."""
+    from pilosa_tpu.server import Server
+
+    rng = np.random.default_rng(3838)
+    s = Server(str(tmp_path / "n"), port=0).open()
+
+    def ask(pql):
+        req = urllib.request.Request(
+            s.uri + "/index/c/query", data=pql.encode(), method="POST",
+            headers={"Content-Type": "text/plain"})
+        with urllib.request.urlopen(req, timeout=120) as r:
+            return json.loads(r.read())["results"][0]
+
+    def dvars():
+        with urllib.request.urlopen(s.uri + "/debug/vars", timeout=60) as r:
+            v = json.loads(r.read())
+        return v["topnPairsRecounts"], v["topnPairsRecountsBySlab"]
+
+    try:
+        idx = s.holder.create_index("c", track_existence=False)
+        for name, spread in (("fp", False), ("wide", True)):
+            idx.create_field(name, FieldOptions(cache_size=50000)
+                             ).import_bits(*_fingerprints(rng, 300, 1,
+                                                          spread))
+        filt = np.arange(0, SHARD_WIDTH, 3, dtype=np.uint64)
+        idx.create_field("f").import_bits(np.zeros(filt.size, np.uint64),
+                                          filt)
+        assert dvars() == (0, 0)
+        ask("TopN(fp, Row(f=0), n=5)")
+        ask("TopN(fp, Row(f=0), n=9)")
+        assert dvars() == (2, 2)
+        ask("TopN(wide, Row(f=0), n=5)")
+        assert dvars() == (3, 2)
+    finally:
+        s.close()
+
+
+def test_every_slot_bucket_compiles_at_the_first_widening(tmp_path):
+    """A similarity search brings a cold filter row every request, and the
+    row's size picks the slot bucket its sparse list is widened from: the
+    first filtered TopN over a block of shards compiles the widening of
+    every bucket under the sparse threshold, and filter rows of any other
+    size compile nothing after it. Same Pairs as brute force."""
+    from pilosa_tpu.utils.telemetry import xla
+
+    rng = np.random.default_rng(3839)
+    sizes = [3, 12, 40, 100, 200, 700, 1500]
+    rows_l, cols_l, sets = [], [], {}
+    for r in range(140):
+        c = rng.choice(2048, sizes[r % len(sizes)], replace=False)
+        sets[r] = set(c.tolist())
+        rows_l.append(np.full(c.size, r, np.uint64))
+        cols_l.append((2 * SHARD_WIDTH + c).astype(np.uint64))  # shard 2
+    h = Holder(str(tmp_path / "d")).open()
+    try:
+        idx = h.create_index("c", track_existence=False)
+        idx.create_field("fp", FieldOptions(cache_size=50000)).import_bits(
+            np.concatenate(rows_l), np.concatenate(cols_l))
+        ex = Executor(h)
+
+        def check(q):
+            (got,) = ex.execute("c", f"TopN(fp, Row(fp={q}), n=4)")
+            want = sorted(((len(sets[q] & s), -r) for r, s in sets.items()
+                           if sets[q] & s), reverse=True)[:4]
+            assert [tuple(p) for p in got] == [(-nr, c) for c, nr in want]
+
+        check(2)                                    # 40 bits: 64 slots
+        compiles = xla.snapshot()["families"]["sparse"]["compiles"]
+        for q in (0, 1, 3, 4, 5, 6):                # 8 ... 2,048 slots
+            check(q)
+        assert xla.snapshot()["families"]["sparse"]["compiles"] == compiles
+    finally:
+        h.close()

@@ -172,6 +172,8 @@ def test_grid_topn_is_the_exact_pairs(taxi, case):
         # rows on both sides of the threshold in one answer: the dense
         # rows through stacked planes, the others in one launch
         assert after["topnPairsRecounts"] == before["topnPairsRecounts"] + 1
+        # a grid's rows run across the whole shard: never a window slab
+        assert after["topnPairsRecountsBySlab"] == 0
         assert after["topnRecountRows"] > before["topnRecountRows"]
         bits = taxi["data"].fields[GRID]
         dense = sum(1 for r in bits
