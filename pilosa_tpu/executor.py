@@ -190,7 +190,8 @@ class Executor:
         import threading as _threading
         self._topn_merge_memo: collections.OrderedDict = \
             collections.OrderedDict()
-        self._topn_memo_lock = _threading.Lock()
+        self._topn_memo_lock = tracing.TimedLock("topn.memo",
+                                                 _threading.Lock())
         # HBM residency manager: query leaves cached as device arrays keyed
         # by content generation; repeat queries run without host->HBM
         # transfers (parallel/residency.py)

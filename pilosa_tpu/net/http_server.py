@@ -141,7 +141,8 @@ class Handler:
         # in-flight work-route requests (query/import/query-batch): the
         # drain sequence waits for this to hit zero before snapshotting
         self.active_queries = 0
-        self._counter_lock = threading.Lock()
+        self._counter_lock = tracing.TimedLock("http.active",
+                                               threading.Lock())
         self.serializer = Serializer()
         self._local = threading.local()
 
@@ -807,6 +808,11 @@ class Handler:
         # the clock (`nowMs`) a delta of two dumps is taken over — the
         # one source per layer boundary
         snap["spans"] = tracing.spans.snapshot()
+        # where threads wait (utils/tracing.py): contended waits at each
+        # named lock, and the interpreter's own — its collections by
+        # generation and, during a capture, its lock's hand-off
+        snap["locks"] = tracing.locks_snapshot()
+        snap["runtime"] = tracing.runtime_snapshot()
         # which devices this node serves from (platform, device_kind,
         # allocator stats): the first thing to read before trusting any
         # device number from this dump

@@ -153,7 +153,7 @@ class ContinuousBatcher:
         self.max_batch = max_batch
         self.runner = runner  # the device batchers' DeviceRunner, if any
         self.admission_s = _ADMISSION_S
-        self._lock = threading.Lock()
+        self._lock = tracing.TimedLock("batcher", threading.Lock())
         self._pending: dict[tuple, list[_Req]] = defaultdict(list)
         self._leaders: set[tuple] = set()
         self._leader_threads: dict[tuple, threading.Thread] = {}

@@ -247,7 +247,11 @@ def on_collective_thread(fn, *args, **kwargs):
 
     def run():
         _on_collective_thread.here = True
-        return fn(*args, **kwargs)
+        # the caller's open span counts the launch; in a capture the
+        # launch is also a `pilosa.dispatch` event on this thread, the
+        # one the trace finds the program's launch on
+        with tracing.trace_event("dispatch"):
+            return fn(*args, **kwargs)
 
     return _collective_pool.submit(
         contextvars.copy_context().run, run).result()
@@ -308,7 +312,7 @@ def eval_count_total(leaves: jax.Array, program) -> jax.Array:
 # storm the telemetry exists to catch).
 
 _ici_programs: dict = {}
-_ici_lock = threading.Lock()
+_ici_lock = tracing.TimedLock("mesh.ici", threading.Lock())
 _ici_stats = {"hits": 0, "misses": 0}
 
 

@@ -23,6 +23,7 @@ from __future__ import annotations
 import dataclasses
 import os
 import threading
+import time
 from collections import OrderedDict
 from typing import Callable, Optional
 
@@ -42,13 +43,15 @@ class DeviceResidency:
         self.runner = runner
         self.budget = budget_bytes
         self._lru: "OrderedDict[tuple, jax.Array]" = OrderedDict()
-        self._lock = threading.Lock()
+        self._lock = tracing.TimedLock("residency.lru", threading.Lock())
         self.bytes = 0
         self.hits = 0
         self.misses = 0
         self.evictions = 0
         self.epoch = 0  # bumped by clear(); fences in-flight misses
         self._building: dict = {}  # key -> Event of the thread building it
+        # the single-flight wait for that thread, counted under _lock
+        self._build_waits = tracing.WaitCounter("residency.build")
         # fragment heat map (utils/heat.py HeatTracker, set by the
         # Executor; None = untracked): uploads/evictions and h2d reload
         # bytes are charged per fragment coordinate, and `eviction =
@@ -76,8 +79,11 @@ class DeviceResidency:
         pad with the sentinel, not zero — parallel/mesh.py put_leaf's fill
         parameter)."""
         prof = qprofile.current_profile.get()  # None = profiling off
+        waited_ms = None
         while True:
             with self._lock:
+                if waited_ms is not None:
+                    self._build_waits.add(waited_ms)
                 arr = self._lru.get(key)
                 building = None
                 if arr is not None:
@@ -102,7 +108,9 @@ class DeviceResidency:
                 return arr
             if building is None:
                 break
+            t0 = time.perf_counter()
             building.wait()  # then look again: resident, or ours to build
+            waited_ms = (time.perf_counter() - t0) * 1e3
         try:
             return self._build(key, make, put, prof, epoch)
         finally:
@@ -360,7 +368,9 @@ class RowStatsMemo:
         self.bound = max(1, int(bound))
         self._lru: "OrderedDict[tuple, RowStats]" = OrderedDict()
         self._frag_keys: dict[tuple, list] = {}
-        self._lock = threading.Lock()  # inserts and evictions only
+        # inserts and evictions only
+        self._lock = tracing.TimedLock("residency.rowstats",
+                                       threading.Lock())
         self.hits = 0
         self.misses = 0
 
@@ -720,7 +730,7 @@ class PlanCache:
         self.budget = budget_bytes
         self.enabled = True
         self._lru: OrderedDict = OrderedDict()  # key -> (value, nbytes)
-        self._lock = threading.Lock()
+        self._lock = tracing.TimedLock("plancache", threading.Lock())
         self.bytes = 0
         self.hits = 0
         self.misses = 0

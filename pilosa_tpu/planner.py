@@ -66,6 +66,7 @@ from pilosa_tpu.models.field import FieldType
 from pilosa_tpu.models.view import VIEW_STANDARD
 from pilosa_tpu.pql import Call, Condition
 from pilosa_tpu.utils.profile import truncate_pql
+from pilosa_tpu.utils.tracing import TimedLock
 
 # the plan node of the call currently executing (the profiler's "plan"
 # entry): the executor sets it around dispatch so cache hit/miss events
@@ -144,7 +145,7 @@ class QueryPlanner:
     def __init__(self, executor):
         self.executor = executor
         self.enabled = True
-        self._lock = threading.Lock()
+        self._lock = TimedLock("planner", threading.Lock())
         self.plans = 0
         self.reorders = 0
         self.pushdowns = 0

@@ -39,6 +39,8 @@ import threading
 import time
 from typing import Optional
 
+from pilosa_tpu.utils.tracing import TimedLock
+
 PRINCIPAL_HEADER = "X-Pilosa-Principal"
 
 # the spill bucket: charges from principals beyond the ledger bound land
@@ -143,7 +145,7 @@ class UsageLedger:
         self.enabled = True  # runtime toggle (bench A/B); env kill switch
         # is checked at account-install time (see http_server.dispatch)
         self.max_principals = max(2, int(max_principals))
-        self._lock = threading.Lock()
+        self._lock = TimedLock("accounting.usage", threading.Lock())
         self._p: "collections.OrderedDict[str, dict]" = \
             collections.OrderedDict()
         self.spilled_principals = 0  # distinct principals merged into SPILL
@@ -343,7 +345,7 @@ class SLOTracker:
         self.long_window = long_window
         self.burn_yellow = burn_yellow
         self.burn_red = burn_red
-        self._lock = threading.Lock()
+        self._lock = TimedLock("accounting.slo", threading.Lock())
         # per objective: deque of [bucket_start_monotonic, total, bad]
         self._buckets: list[collections.deque] = [
             collections.deque() for _ in self.objectives]

@@ -11,7 +11,6 @@ never affect serving.
 
 from __future__ import annotations
 
-import gc
 import json
 import os
 import platform
@@ -20,7 +19,7 @@ import time
 import urllib.request
 from typing import Optional
 
-from pilosa_tpu.utils import threads
+from pilosa_tpu.utils import threads, tracing
 
 
 class SystemInfo:
@@ -209,21 +208,23 @@ class DiagnosticsCollector:
 
 class RuntimeMonitor:
     """Periodic process gauges -> stats (monitorRuntime, server.go:726-770:
-    goroutines/heap/GC become threads/RSS/collections here)."""
+    goroutines/heap/GC become threads/RSS/collections here). Collections
+    are counted by the one process-wide hook that also times their
+    pauses (utils/tracing.py gc_pauses, /debug/vars `runtime.gc`), which
+    the monitor installs."""
 
     def __init__(self, stats, interval: float = 0.0):
         self.stats = stats
         self.interval = interval
         self._timer: Optional[threading.Timer] = None
         self.closed = False
+        tracing.gc_pauses.install()
 
     def sample(self) -> None:
-        counts = gc.get_count()
         self.stats.gauge("threads", threading.active_count())
         self.stats.gauge("memory/rss", process_rss())
-        self.stats.gauge("garbage/gen0", counts[0])
         self.stats.gauge("garbage/collections",
-                         sum(s["collections"] for s in gc.get_stats()))
+                         tracing.gc_pauses.collections())
 
     def start(self) -> None:
         if self.interval > 0:

@@ -37,6 +37,8 @@ import threading
 import time
 from typing import Callable, Optional
 
+from pilosa_tpu.utils.tracing import TimedLock
+
 # -- kill switch -------------------------------------------------------------
 
 
@@ -67,7 +69,7 @@ class HybridLogicalClock:
         # a deliberately wrong wall and assert causality survives
         self._wall_ms = wall_ms or (
             lambda: int(time.time() * 1000))  # wall-clock: HLC physical half
-        self._lock = threading.Lock()
+        self._lock = TimedLock("events.hlc", threading.Lock())
         self._physical = 0
         self._logical = 0
 

@@ -46,6 +46,8 @@ import threading
 import time
 from typing import Optional
 
+from pilosa_tpu.utils.tracing import TimedLock
+
 # the spill bucket: charges from fragments beyond the table bound land
 # here (top-K-by-heat semantics — the coldest entry is merged out, never
 # the data; totals stay exact)
@@ -179,7 +181,7 @@ class HeatTracker:
         self.enabled = True  # runtime toggle (bench A/B); the env kill
         # switch is read at Executor construction (no tracker is built)
         self.max_fragments = max(2, int(max_fragments))
-        self._lock = threading.Lock()
+        self._lock = TimedLock("heat", threading.Lock())
         self._f: dict[tuple, dict] = {}
         self._other = dict.fromkeys(FIELDS, 0.0)  # the SPILL aggregate
         self.spilled_fragments = 0

@@ -29,9 +29,12 @@ class StatsClient:
                  _store=None):
         self._prefix = prefix
         self.tags = sorted(tags or [])
-        self._store = _store if _store is not None else {
-            "lock": threading.Lock(), "counts": {}, "gauges": {},
-            "timings": {}, "sets": {}}
+        if _store is None:
+            # utils/tracing.py imports this module: its lock type, lazily
+            from pilosa_tpu.utils.tracing import TimedLock
+            _store = {"lock": TimedLock("stats", threading.Lock()),
+                      "counts": {}, "gauges": {}, "timings": {}, "sets": {}}
+        self._store = _store
 
     def _key(self, name: str) -> str:
         tag_part = ("," + ",".join(self.tags)) if self.tags else ""

@@ -258,7 +258,7 @@ class XLACounters:
         # event_fn(family, new_shapes_in_window, signature_diff) on each
         # storm trip — the diff names the leaf whose shape churned
         self.event_fn = None
-        self._lock = threading.Lock()
+        self._lock = tracing.TimedLock("telemetry.xla", threading.Lock())
         self._families: dict[str, dict] = {}
         self.storms = 0
 
@@ -392,7 +392,8 @@ class KernelStats:
     dispatch."""
 
     def __init__(self):
-        self._lock = threading.Lock()
+        self._lock = tracing.TimedLock("telemetry.kernels",
+                                       threading.Lock())
         # (family, rep, arity) -> {n, ms, min, max, buckets}
         self._calls: dict[tuple, dict] = {}
         self._wait: dict[str, dict] = {}   # family -> {ms, n}
@@ -775,9 +776,12 @@ class DeviceProfiler:
             options.host_tracer_level = 2
             jax.profiler.start_trace(out_dir, profiler_options=options)
             tracing.set_capturing(True)
+            # the interpreter lock's hand-off, for the capture alone
+            tracing.handoff_probe.start()
             try:
                 time.sleep(seconds)
             finally:
+                tracing.handoff_probe.stop()
                 tracing.set_capturing(False)
                 t_stop = time.perf_counter()
                 jax.profiler.stop_trace()

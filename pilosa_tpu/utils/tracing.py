@@ -10,8 +10,8 @@ id, wall time (`time.perf_counter`) and the thread's CPU time
 (`time.thread_time`) at both ends. On finish it reports to three sinks:
 
   a. aggregates, always on: the process-global `spans` table (per name
-     n / wallMs / selfMs / cpuMs / log2 buckets), served by /debug/vars
-     `spans` and /metrics `pilosa_spanMs{span=...}`;
+     n / wallMs / selfMs / cpuMs / waitMs / log2 buckets), served by
+     /debug/vars `spans` and /metrics `pilosa_spanMs{span=...}`;
      PILOSA_TPU_TELEMETRY=0 turns it off.
   b. the device trace, only while DeviceProfiler.capture runs: the span
      is also a `jax.profiler.TraceAnnotation("pilosa.<name>",
@@ -25,12 +25,23 @@ id, wall time (`time.perf_counter`) and the thread's CPU time
 The tracer a span reports to is found through `current_tracer`, which the
 HTTP layer sets to its node's Tracer; outside a request there is none and
 only sink a sees the span.
+
+Where a thread waits is counted where it waits: a `TimedLock` (or a
+`WaitCounter` beside a wait of another kind) books each contended wait
+to its own name (/debug/vars `locks`) and to the innermost open span's
+`wait_ms` (the span table's `waitMs`, the part of `selfMs` spent blocked
+at a named lock). The interpreter's own waits are in `runtime`: its
+collections by generation (`gc_pauses`, one process-wide `gc.callbacks`
+hook) and, only while a capture runs, how late a sleeping thread gets
+the interpreter lock back (`handoff_probe`).
 """
 
 from __future__ import annotations
 
 import collections
+import contextlib
 import contextvars
+import gc
 import os
 import random
 import threading
@@ -79,6 +90,18 @@ def capturing() -> bool:
     return _capturing
 
 
+def trace_event(name: str):
+    """Sink b alone: while a capture runs, a `pilosa.<name>` event on
+    this thread under the open span's trace id, for work a span of
+    another thread already counts; off a capture, nothing."""
+    if not _capturing:
+        return contextlib.nullcontext()
+    import jax
+    sp = current_span.get()
+    return jax.profiler.TraceAnnotation(
+        "pilosa." + name, trace_id=sp.trace_id if sp is not None else "")
+
+
 def new_trace_id() -> str:
     """Mint a fresh trace id, one for a whole request, so the slow-query
     log, /debug/query-history and exported spans all join on it."""
@@ -98,11 +121,83 @@ def _covered(intervals: list, lo: float, hi: float) -> float:
     return total
 
 
+# -- where threads wait -------------------------------------------------------
+
+# every WaitCounter ever made, by name: the /debug/vars `locks` block sums
+# a name's counters (a batcher a kind, a heat map a server), and keeping
+# them all makes the sums grow monotonically, like the span table's
+_waits: dict[str, list] = {}
+_waits_mu = threading.Lock()
+
+
+class WaitCounter:
+    """One named place where threads wait: how many waits, their summed
+    and longest milliseconds. `add` runs on the thread that waited, while
+    it holds a lock that serializes every `add` of this counter (the lock
+    it waited for, or its owner's), so the counts need no lock of their
+    own; the wait is also booked to the innermost open span."""
+
+    __slots__ = ("waited", "wait_ms", "max_wait_ms")
+
+    def __init__(self, name: str):
+        self.waited = 0
+        self.wait_ms = 0.0
+        self.max_wait_ms = 0.0
+        with _waits_mu:
+            _waits.setdefault(name, []).append(self)
+
+    def add(self, ms: float) -> None:
+        self.waited += 1
+        self.wait_ms += ms
+        if ms > self.max_wait_ms:
+            self.max_wait_ms = ms
+        sp = current_span.get()
+        if sp is not None:
+            sp.wait_ms += ms
+
+
+class TimedLock:
+    """A lock built at the call site (`TimedLock("heat",
+    threading.Lock())`, so the lock witness keeps that site) whose
+    contended waits are counted. An acquire that finds it free costs one
+    non-blocking try and records nothing; only a thread that has to
+    block times its wait, and books it, holding the lock, to the name's
+    counter and the innermost open span. Taken with `with` only."""
+
+    __slots__ = ("_lock", "waits")
+
+    def __init__(self, name: str, lock):
+        self._lock = lock
+        self.waits = WaitCounter(name)
+
+    def __enter__(self):
+        if not self._lock.acquire(False):
+            t0 = time.perf_counter()
+            self._lock.acquire()
+            self.waits.add((time.perf_counter() - t0) * 1e3)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._lock.release()
+
+
+def locks_snapshot() -> dict:
+    """The /debug/vars `locks` block: per name the contended waits since
+    the process started, summed over every lock of that name."""
+    with _waits_mu:
+        by_name = {name: list(cs) for name, cs in _waits.items()}
+    return {name: {"waited": sum(c.waited for c in cs),
+                   "waitMs": sum(c.wait_ms for c in cs),
+                   "maxWaitMs": max((c.max_wait_ms for c in cs),
+                                    default=0.0)}
+            for name, cs in sorted(by_name.items())}
+
+
 class Span:
     __slots__ = ("tracer", "name", "trace_id", "span_id", "parent", "start",
                  "end", "start_wall", "tags", "self_ms", "cpu_ms",
-                 "launches", "devices", "_cpu0", "_kids", "_token",
-                 "_annotation")
+                 "wait_ms", "launches", "devices", "_cpu0", "_kids",
+                 "_token", "_annotation")
 
     def __init__(self, tracer, name: str, trace_id: Optional[str] = None,
                  tags: Optional[dict] = None):
@@ -118,6 +213,7 @@ class Span:
         self.end: Optional[float] = None
         self.self_ms = 0.0
         self.cpu_ms = 0.0
+        self.wait_ms = 0.0  # blocked at a named lock while innermost
         self.launches = 0  # device programs enqueued under this span
         self.devices = 1  # the most devices one of them went to
         self._kids: list = []  # (start, end) of finished child spans
@@ -159,13 +255,18 @@ class Span:
         kids = self._kids
         self.self_ms = wall_ms - (_covered(kids, self.start, end) * 1e3
                                   if kids else 0.0)
+        # a wait lies in this thread's own time, but a child on a pool
+        # thread may cover the same stretch: never more than selfMs
+        if self.wait_ms > self.self_ms:
+            self.wait_ms = self.self_ms
         if self.parent is not None:
             self.parent._kids.append((self.start, end))
         if self.launches:
             self.tags["dispatches"] = self.launches
             self.tags["devices"] = self.devices
         if _telemetry_on():
-            spans.add(self.name, wall_ms, self.self_ms, self.cpu_ms)
+            spans.add(self.name, wall_ms, self.self_ms, self.cpu_ms,
+                      self.wait_ms)
         prof = qprofile.current_profile.get()
         if prof is not None:
             prof.record_stage(self)
@@ -213,7 +314,7 @@ class MeshLaunches:
     `mesh` block: `collectiveThreads` reads 0 or 1)."""
 
     def __init__(self):
-        self._lock = threading.Lock()
+        self._lock = TimedLock("mesh.launches", threading.Lock())
         self.watching = False  # a DeviceRunner with a mesh exists
         self._collective = 0
         self._local = 0
@@ -251,28 +352,30 @@ def _telemetry_on() -> bool:
 
 class SpanStats:
     """Sink a: per span name, how many finished and their summed wall,
-    self and thread-CPU milliseconds, with log2 buckets of the wall time
-    (the pattern of telemetry.kernels). A layer's metric is a delta of
-    two snapshots: self time partitions a request's wall, so the names'
-    selfMs sum to the roots' wallMs."""
+    self, thread-CPU and lock-wait milliseconds, with log2 buckets of the
+    wall time (the pattern of telemetry.kernels). A layer's metric is a
+    delta of two snapshots: self time partitions a request's wall, so the
+    names' selfMs sum to the roots' wallMs; waitMs is the part of a
+    name's selfMs spent blocked at a named lock (TimedLock)."""
 
     def __init__(self):
-        self._lock = threading.Lock()
+        self._lock = TimedLock("tracing.spans", threading.Lock())
         self._by_name: dict[str, dict] = {}
 
     def add(self, name: str, wall_ms: float, self_ms: float,
-            cpu_ms: float) -> None:
+            cpu_ms: float, wait_ms: float) -> None:
         b = _pow2_bucket(wall_ms)
         with self._lock:
             e = self._by_name.get(name)
             if e is None:
                 e = self._by_name[name] = {
                     "n": 0, "wallMs": 0.0, "selfMs": 0.0, "cpuMs": 0.0,
-                    "buckets": {}}
+                    "waitMs": 0.0, "buckets": {}}
             e["n"] += 1
             e["wallMs"] += wall_ms
             e["selfMs"] += self_ms
             e["cpuMs"] += cpu_ms
+            e["waitMs"] += wait_ms
             e["buckets"][b] = e["buckets"].get(b, 0) + 1
 
     def snapshot(self) -> dict:
@@ -301,6 +404,133 @@ class SpanStats:
 # process-global, like telemetry.kernels: every span adds to it, and
 # /debug/vars, /metrics and the benchmark's readers read it
 spans = SpanStats()
+
+
+class GcPauses:
+    """The interpreter's collections by generation: how many, and the
+    summed and longest milliseconds from a collection's `start` to its
+    `stop`, a stretch in which no other thread runs Python. One
+    process-wide `gc.callbacks` hook (`install`, idempotent: a process
+    may build many servers). Collections never overlap, so the counts
+    need no lock. While a capture runs, a collection of generation 1 or
+    2 is also a `pilosa.gc` event (tag `generation`) on the collecting
+    thread, on the device trace's clock."""
+
+    def __init__(self):
+        self._gens = [[0, 0.0, 0.0] for _ in range(3)]  # n, sum, max
+        self._t0: Optional[float] = None
+        self._annotation = None
+        self._installed = False
+        self._install_mu = threading.Lock()
+
+    def install(self) -> None:
+        with self._install_mu:
+            if not self._installed:
+                gc.callbacks.append(self._callback)
+                self._installed = True
+
+    def _callback(self, phase: str, info: dict) -> None:
+        gen = info["generation"]
+        if phase == "start":
+            if _capturing and gen >= 1:
+                import jax
+                self._annotation = jax.profiler.TraceAnnotation(
+                    "pilosa.gc", generation=gen)
+                self._annotation.__enter__()
+            self._t0 = time.perf_counter()
+            return
+        t0, self._t0 = self._t0, None
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
+        if t0 is None:
+            return
+        ms = (time.perf_counter() - t0) * 1e3
+        e = self._gens[gen]
+        e[0] += 1
+        e[1] += ms
+        if ms > e[2]:
+            e[2] = ms
+
+    def collections(self) -> int:
+        return sum(e[0] for e in self._gens)
+
+    def snapshot(self) -> dict:
+        """/debug/vars `runtime.gc`: per generation n / pauseMs /
+        maxPauseMs since the hook was installed."""
+        return {str(g): {"n": n, "pauseMs": total, "maxPauseMs": most}
+                for g, (n, total, most) in enumerate(self._gens)}
+
+
+# process-global: the interpreter is one
+gc_pauses = GcPauses()
+
+# the probe's sleep: at most one switch interval (sys.getswitchinterval(),
+# 5 ms by default), so a thread that holds the interpreter lock for a
+# whole interval shows in the probe's next wake-up
+HANDOFF_PERIOD_S = 0.005
+
+
+class HandoffProbe:
+    """How late a sleeping thread gets the interpreter lock back: one
+    daemon thread sleeps HANDOFF_PERIOD_S again and again and records by
+    how much it woke late (the Python analogue of the Go runtime's
+    scheduling latency). DeviceProfiler.capture starts it and stops it
+    with the capture; off a capture no probe thread exists. The probe
+    thread is the only writer; its lock is for the reader."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._thread: Optional[threading.Thread] = None
+        self._stop: Optional[threading.Event] = None
+        self.n = 0
+        self.sum_ms = 0.0
+        self.max_ms = 0.0
+        self.buckets: dict[str, int] = {}
+
+    def start(self) -> None:
+        if self._thread is not None:
+            return
+        self._stop = threading.Event()
+        self._thread = threads.spawn(self._run, self._stop,
+                                     name="pilosa-interp-probe")
+
+    def stop(self) -> None:
+        t, self._thread = self._thread, None
+        if t is not None:
+            self._stop.set()
+            t.join()
+
+    def _run(self, stop: threading.Event) -> None:
+        while not stop.is_set():
+            t0 = time.perf_counter()
+            time.sleep(HANDOFF_PERIOD_S)
+            late_ms = max(0.0, (time.perf_counter() - t0
+                                - HANDOFF_PERIOD_S) * 1e3)
+            b = _pow2_bucket(late_ms)
+            with self._lock:
+                self.n += 1
+                self.sum_ms += late_ms
+                if late_ms > self.max_ms:
+                    self.max_ms = late_ms
+                self.buckets[b] = self.buckets.get(b, 0) + 1
+
+    def snapshot(self) -> dict:
+        """/debug/vars `runtime.interpHandoff`: n / sumMs / maxMs and
+        log2 buckets of the wake-ups' lateness, over every capture."""
+        with self._lock:
+            return {"n": self.n, "sumMs": self.sum_ms, "maxMs": self.max_ms,
+                    "buckets": dict(self.buckets)}
+
+
+handoff_probe = HandoffProbe()
+
+
+def runtime_snapshot() -> dict:
+    """The /debug/vars `runtime` block: where the interpreter itself
+    makes every thread wait."""
+    return {"gc": gc_pauses.snapshot(),
+            "interpHandoff": handoff_probe.snapshot()}
 
 
 def trace_export_enabled() -> bool:
@@ -628,7 +858,8 @@ class Tracer:
     def __init__(self, limit: int = 1000,
                  exporter: Optional[TraceExporter] = None,
                  sampler_type: str = "const", sampler_param: float = 1.0):
-        self._lock = threading.Lock()
+        # the ring takes no lock: a bounded deque's append, and the copy
+        # list() makes of it, each run whole under the interpreter lock
         self.spans: "collections.deque[Span]" = collections.deque(
             maxlen=limit)
         self.exporter = exporter
@@ -652,14 +883,12 @@ class Tracer:
         return self.sampler_param >= 1  # const
 
     def _record(self, span: Span) -> None:
-        with self._lock:
-            self.spans.append(span)
+        self.spans.append(span)
         if self._sampled(span):
             self.exporter.export(span)
 
     def finished(self, name: Optional[str] = None) -> list[Span]:
-        with self._lock:
-            return [s for s in self.spans if name is None or s.name == name]
+        return [s for s in list(self.spans) if name is None or s.name == name]
 
     # HTTP propagation (tracing/tracing.go:22-26)
     def inject_headers(self, span: Span, headers: dict) -> None:
