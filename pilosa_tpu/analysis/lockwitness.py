@@ -321,6 +321,13 @@ def _make_rlock():
     return WitnessRLock(inner, site, _GLOBAL) if site is not None else inner
 
 
+def native_rlock():
+    """A reentrant lock the witness never tracks: for a lock held across
+    a dispatch by design (utils/telemetry.py's launch order on the CPU
+    backend), which the witness would otherwise report at every launch."""
+    return _real_rlock()
+
+
 def install() -> None:
     """Patch the threading lock factories; idempotent."""
     global ACTIVE

@@ -1443,7 +1443,8 @@ class Executor:
         if "sparse" not in kinds and "run" not in kinds:
             return self.runner.row_leaves_dev(leaves, program)
         from pilosa_tpu.ops import bitvector as bv
-        kind, arr = bv.eval_hybrid(program, leaves, kinds, WORDS)
+        kind, arr = bv.eval_hybrid(program, leaves, kinds, WORDS,
+                                   on_bridge=self.hybrid.record_bridge)
         if kind == "sparse":
             self.hybrid.record_materialize()
             return self._sparse_to_dense(arr)
@@ -1471,6 +1472,7 @@ class Executor:
                         np.full(sp.shape[:-1] + (k,), bv.SPARSE_SENTINEL,
                                 np.int32), fill=bv.SPARSE_SENTINEL), WORDS)
                 k <<= 1
+        self.hybrid.record_bridge(sp.shape[-1])
         return bv.sparse_to_dense(sp, WORDS)
 
     def _heat_call_touch(self, index: Index, call: Call, shards,
@@ -1619,13 +1621,15 @@ class Executor:
         if "sparse" in kinds or "run" in kinds:
             # hybrid program: count through the sparse/run kernel
             # families — a sparse root counts its live slots, a run root
-            # sums its interval lengths, with no plane ever materialized
-            # (the hybrid-count pushdown). Skips the batcher and the
-            # dense chain kernel, which both assume uint32 planes.
+            # sums its interval lengths (the hybrid-count pushdown).
+            # Skips the batcher and the dense chain kernel, which both
+            # assume uint32 planes.
             from pilosa_tpu.ops import bitvector as bv
 
             def launch():
-                return bv.hybrid_count_dev(program, leaves, kinds)
+                return bv.hybrid_count_dev(
+                    program, leaves, kinds,
+                    on_bridge=self.hybrid.record_bridge)
         elif self.batcher is not None and (
                 shape := self._batchable(program, leaves)) is not None:
             # concurrent Counts coalesce into one device dispatch

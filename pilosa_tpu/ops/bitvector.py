@@ -267,22 +267,23 @@ def xor_range(x: jax.Array, mask: jax.Array) -> jax.Array:
 # instead of a 128 KiB dense plane, so resident capacity scales with
 # CARDINALITY, not shard width. Kernels broadcast over leading axes like
 # the dense algebra (one row [S, K], or anything stacked above it); every
-# kernel returns sorted sentinel-padded output, so compositions chain
-# freely. Every sparse×sparse kernel is a MERGE: one sort of the
-# concatenated operands and a compare with the neighbour — no gather, no
-# scatter, no loop. On the chip a sort of [64, 8192] costs a fraction of
-# a millisecond while a gather costs ~10 ns an element, so a binary-search
-# probe (log2(K)+1 dependent gathers, what sparse ∩ / − ran until PR 27)
-# cost seventy times a merge (PERF.md §6). The planner chooses
-# representation per operand (pilosa_tpu/planner.py choose_representation)
-# and eval_hybrid() below evaluates a mixed sparse/dense program tree,
-# materializing to dense only where an op demands a plane (Not, wide
-# unions, GroupBy slabs, BSI).
+# kernel that returns a list returns it sorted and sentinel-padded, so
+# compositions chain freely. Every sparse×sparse kernel is a MERGE: one
+# sort of the concatenated operands and a compare with the neighbour — no
+# gather, no scatter, no loop. On the chip a sort of [64, 8192] costs a
+# fraction of a millisecond while a gather costs ~10 ns an element, so a
+# binary-search probe (log2(K)+1 dependent gathers, what sparse ∩ / − ran
+# before the merge) cost seventy times a merge (PERF.md §6). A list meeting a
+# plane becomes a plane: the list-to-plane bridge (_bridge) is a product
+# on the matrix unit with no per-entry addressing, fused with the one
+# bitwise op. The planner chooses representation per operand
+# (pilosa_tpu/planner.py choose_representation) and eval_hybrid() below
+# evaluates a mixed sparse/dense program tree.
 # ---------------------------------------------------------------------------
 
 # one past the last legal column offset; sorts after every real entry.
 # Fits int32 (SHARD_WIDTH = 2^20), and its word index (SHARD_WIDTH >> 5)
-# is one past the last dense lane, so scatter mode="drop" discards pads.
+# is one past the last dense lane, which no one-hot of _bridge matches.
 SPARSE_SENTINEL = SHARD_WIDTH
 
 # sparse∪sparse output keeps Ka+Kb slots; past this the padded arrays stop
@@ -356,33 +357,78 @@ def sparse_difference(a: jax.Array, b: jax.Array) -> jax.Array:
 
 def _dense_bit_test(sp: jax.Array, dense: jax.Array) -> jax.Array:
     """Gather-and-test: for each sparse entry, its bit in the dense
-    operand (the sparse∩dense primitive — K word gathers instead of a
-    W-lane bitwise pass). Sentinel slots test the last real lane and are
-    masked out by the range check."""
+    operand — K word gathers. Only the by-pairs recount
+    (pairs_count_local) takes it; a list meeting a plane in a program
+    tree goes through _bridge. Sentinel slots test the last real lane
+    and are masked out by the range check."""
     safe = jnp.minimum(sp, SPARSE_SENTINEL - 1)
     w = jnp.take_along_axis(dense, safe >> 5, axis=-1)
     bit = (w >> (safe & 31).astype(jnp.uint32)) & jnp.uint32(1)
     return (bit != 0) & (sp < SPARSE_SENTINEL)
 
 
+# words a row of the bridge's product covers: word w of the plane lies at
+# row w // BRIDGE_WORDS, column w % BRIDGE_WORDS of it. At 32,768 words
+# a shard that is 256 rows of 128, which on a v5e is 3-8 % faster a
+# launch than 128 rows of 256 at every shape timed, 1 to 64 shards of 64
+# to 16,384 slots (benches/sparse_kernels.py, PERF.md section 6)
+BRIDGE_WORDS = 128
+
+
+def _bridge(sp: jax.Array, n_words: int) -> jax.Array:
+    """The list-to-plane bridge: sparse[..., K] -> uint32[..., n_words]
+    as a batched product on the matrix unit, with no per-entry gather or
+    scatter. Entry c is word w = c >> 5 = B·a + b (B = BRIDGE_WORDS) and
+    bit j = c & 31, whose value 1 << j is split into two 16-bit halves.
+    A shard's plane is then onehot(a)ᵀ [A, K] @ V [K, 2B], V[k, h·B + b]
+    entry k's half h — both operands built with the slot index minor and
+    contracted over it, the form the matrix unit takes without a
+    transpose. Exact: the operands are 0, 1 and powers of two below 2^16
+    (exact in bfloat16), entries are unique per row, so an output element
+    sums distinct powers of two below 2^16 in a float32 accumulator.
+    Sentinel slots, and any column past the plane, take a = -1 and match
+    no one-hot. The operands are fused into the product: nothing of
+    their [K, A] and [K, 2B] size is written to memory."""
+    lead, k = sp.shape[:-1], sp.shape[-1]
+    flat = sp.reshape(-1, k)
+    b_words = min(BRIDGE_WORDS, n_words)
+    a_rows = -(-n_words // b_words)
+    w = flat >> 5
+    a = jnp.where(flat < n_words * WORD_BITS, w // b_words, -1)
+    col = ((flat >> 4) & 1) * b_words + w % b_words
+    half = (jnp.int32(1) << (flat & 15)).astype(jnp.bfloat16)
+    onehot = (a[:, None, :] == lax.broadcasted_iota(
+        jnp.int32, (1, a_rows, 1), 1)).astype(jnp.bfloat16)
+    values = jnp.where(col[:, None, :] == lax.broadcasted_iota(
+        jnp.int32, (1, 2 * b_words, 1), 1), half[:, None, :],
+        jnp.bfloat16(0))
+    got = lax.dot_general(onehot, values, (((2,), (2,)), ((0,), (0,))),
+                          preferred_element_type=jnp.float32)
+    got = got.astype(jnp.uint32)
+    words = got[..., :b_words] | (got[..., b_words:] << 16)
+    return words.reshape(-1, a_rows * b_words)[:, :n_words].reshape(
+        *lead, n_words)
+
+
 @counted_jit("sparse")
 def sparse_intersect_dense(sp: jax.Array, dense: jax.Array) -> jax.Array:
-    """sparse ∩ dense -> sparse[..., K] via gather-and-test."""
-    return _resort(sp, _dense_bit_test(sp, dense))
+    """sparse ∩ dense -> the plane uint32[..., W]: the bridge and one AND,
+    fused into one program."""
+    return jnp.bitwise_and(_bridge(sp, dense.shape[-1]), dense)
 
 
 @counted_jit("sparse")
 def sparse_difference_dense(sp: jax.Array, dense: jax.Array) -> jax.Array:
-    """sparse &~ dense -> sparse[..., K]."""
-    keep = ~_dense_bit_test(sp, dense) & (sp < SPARSE_SENTINEL)
-    return _resort(sp, keep)
+    """sparse &~ dense -> the plane uint32[..., W], fused as above."""
+    return jnp.bitwise_and(_bridge(sp, dense.shape[-1]),
+                           jnp.bitwise_not(dense))
 
 
 @counted_jit("sparse")
 def sparse_dense_count(sp: jax.Array, dense: jax.Array) -> jax.Array:
-    """popcount(sparse ∩ dense) -> int32[...] without materializing the
-    intersection (the Count(Intersect(sparse_row, dense_mask)) pushdown)."""
-    return jnp.sum(_dense_bit_test(sp, dense).astype(jnp.int32), axis=-1)
+    """popcount(sparse ∩ dense) -> int32[...]: the fused plane of
+    sparse_intersect_dense counted in the same program."""
+    return popcount(jnp.bitwise_and(_bridge(sp, dense.shape[-1]), dense))
 
 
 def _merge_sorted(a: jax.Array, b: jax.Array):
@@ -416,19 +462,9 @@ def sparse_xor(a: jax.Array, b: jax.Array) -> jax.Array:
 @counted_jit("sparse", static_argnames=("n_words",))
 def sparse_to_dense(sp: jax.Array, n_words: int) -> jax.Array:
     """Materialize sparse[..., K] -> dense uint32[..., n_words] — the
-    bridge for ops that need planes (Not, GroupBy slabs, BSI folds, the
-    final Row result). Entries are unique per row, so the per-word
-    scatter-add assembles distinct bits without carries; sentinel slots
-    index one word past the plane and mode=\"drop\" discards them."""
-    lead, k = sp.shape[:-1], sp.shape[-1]
-    flat = sp.reshape(-1, k)
-
-    def one(idx):
-        bit = jnp.uint32(1) << (idx & 31).astype(jnp.uint32)
-        return jnp.zeros((n_words,), jnp.uint32).at[idx >> 5].add(
-            bit, mode="drop")
-
-    return jax.vmap(one)(flat).reshape(*lead, n_words)
+    bridge (_bridge) alone, for ops that need planes (Not, unions with a
+    plane, GroupBy slabs, BSI folds, the final Row result)."""
+    return _bridge(sp, n_words)
 
 
 # -- the small rows of one field, recounted under a filter in one launch -----
@@ -884,23 +920,29 @@ def patch_sparse_rows(sp: jax.Array, adds: jax.Array,
 
 
 def eval_hybrid(program, leaves: list, kinds: list,
-                n_words: int = SHARD_WIDTH // WORD_BITS):
+                n_words: int = SHARD_WIDTH // WORD_BITS, on_bridge=None):
     """Evaluate a nested-tuple bitmap program over MIXED dense/sparse/run
     leaves -> (kind, device array). The representation flows bottom-up:
-    intersections keep the cheapest faithful representation (sparse∩* is
-    sparse: a merge against a sparse side, gather-and-test against a dense
-    one; run∩run stays run via interval merge,
-    run∩dense materializes the fused run mask), differences keep the left
-    operand's kind where a dedicated kernel exists, unions of two small
-    sparse rows stay sparse until SPARSE_UNION_CAP, and Not — whose
+    sparse∩sparse and sparse∩run stay sparse (a merge, a containment
+    probe), a list meeting a plane becomes a plane (the bridge fused with
+    the AND or AND-NOT: one program), run∩run stays run via interval
+    merge, run∩dense materializes the fused run mask, differences keep
+    the left operand's kind where a list kernel exists, unions of two
+    small sparse rows stay sparse until SPARSE_UNION_CAP, and Not — whose
     complement is dense by construction — materializes, as do run
     operands of unions/xors (point-set growth under ∪/^ is unbounded for
     intervals). Dispatched eagerly per node (operand shapes differ per
-    node, so one fused program would recompile per query shape anyway);
-    each kernel is a tiny K- or R-slot pass."""
+    node, so one fused program would recompile per query shape anyway).
+    `on_bridge(k)`, if given, is told of every launch that bridges a
+    sparse[..., k] list to a plane, alone or fused."""
+    def bridged(sp):
+        if on_bridge is not None:
+            on_bridge(sp.shape[-1])
+        return sp
+
     def dense_of(kind, arr):
         if kind == "sparse":
-            return sparse_to_dense(arr, n_words)
+            return sparse_to_dense(bridged(arr), n_words)
         if kind == "run":
             return run_to_dense(arr, n_words)
         return arr
@@ -925,9 +967,9 @@ def eval_hybrid(program, leaves: list, kinds: list,
                 elif k == "run" and k2 == "run":
                     acc = run_intersect(acc, x)
                 elif k == "sparse":
-                    acc = sparse_intersect_dense(acc, x)
+                    acc, k = sparse_intersect_dense(bridged(acc), x), "dense"
                 elif k2 == "sparse":
-                    acc, k = sparse_intersect_dense(x, acc), "sparse"
+                    acc = sparse_intersect_dense(bridged(x), acc)
                 elif k == "run":
                     acc, k = run_intersect_dense(acc, x, n_words), "dense"
                 elif k2 == "run":
@@ -940,7 +982,7 @@ def eval_hybrid(program, leaves: list, kinds: list,
                 elif k == "sparse" and k2 == "run":
                     acc = sparse_difference_run(acc, x)
                 elif k == "sparse":
-                    acc = sparse_difference_dense(acc, x)
+                    acc, k = sparse_difference_dense(bridged(acc), x), "dense"
                 else:
                     acc = bandnot(dense_of(k, acc), dense_of(k2, x))
                     k = "dense"
@@ -969,12 +1011,14 @@ def hybrid_count(program, leaves: list, kinds: list,
 
 
 def hybrid_count_dev(program, leaves: list, kinds: list,
-                     n_words: int = SHARD_WIDTH // WORD_BITS) -> jax.Array:
+                     n_words: int = SHARD_WIDTH // WORD_BITS,
+                     on_bridge=None) -> jax.Array:
     """Per-shard counts of a mixed dense/sparse/run program, launched and
     NOT fetched — sparse results count their live slots, run results sum
     interval lengths (neither ever materializes a plane), dense results
     popcount. Every kernel is enqueued asynchronously; the caller's fetch
-    of the returned array is what waits for the device.
+    of the returned array is what waits for the device. `on_bridge` as
+    eval_hybrid's.
 
     The reduction stays PER-SHARD on device and sums on host: every
     hybrid kernel is per-shard local (zero collectives), so on a mesh the
@@ -997,7 +1041,8 @@ def hybrid_count_dev(program, leaves: list, kinds: list,
             acc = run_intersect(acc, x)
         return run_intersect_count(acc, ops[-1])
 
-    kind, arr = eval_hybrid(program, leaves, kinds, n_words=n_words)
+    kind, arr = eval_hybrid(program, leaves, kinds, n_words=n_words,
+                            on_bridge=on_bridge)
     if kind == "sparse":
         return sparse_count(arr)
     if kind == "run":

@@ -41,7 +41,11 @@ from pilosa_tpu.ops.bitvector import (
 )
 from pilosa_tpu.analysis import lockwitness
 from pilosa_tpu.utils import tracing
-from pilosa_tpu.utils.telemetry import counted_jit, record_dispatch
+from pilosa_tpu.utils.telemetry import (
+    counted_jit,
+    launch_in_order,
+    record_dispatch,
+)
 
 SHARD_AXIS = "shard"
 REPLICA_AXIS = "replica"
@@ -379,7 +383,7 @@ def eval_count_mesh(mesh: Mesh, leaves: tuple, program) -> jax.Array:
     def launch():
         record_dispatch("ici_program", mesh, "count", program, len(leaves),
                         devices=mesh.size, collective=True)
-        return fn(tuple(leaves))
+        return launch_in_order(mesh, fn, tuple(leaves))
 
     return on_collective_thread(launch)
 
@@ -393,7 +397,7 @@ def eval_row_mesh(mesh: Mesh, leaves: tuple, program) -> jax.Array:
                      lambda: _build_row_mesh(mesh, program, len(leaves)))
     record_dispatch("ici_program", mesh, "row", program, len(leaves),
                     devices=mesh.size)
-    return fn(tuple(leaves))
+    return launch_in_order(mesh, fn, tuple(leaves))
 
 
 @counted_jit("stream", cross_shard=True)
@@ -456,7 +460,8 @@ def pair_stream_counts(mesh: Mesh, rows: jax.Array, ii: np.ndarray,
     def launch():
         record_dispatch("stream_mesh", mesh, rows, ii_d, jj_d,
                         devices=mesh.size, collective=True)
-        return _pair_stream_fn(mesh)(rows, ii_d, jj_d)
+        return launch_in_order(mesh, _pair_stream_fn(mesh), rows, ii_d,
+                               jj_d)
 
     out = np.asarray(on_collective_thread(launch)).astype(np.int64)
     return out[:k]
@@ -527,8 +532,8 @@ def groupby_chunk_matrix_mesh(mesh: Mesh, axis_slabs: tuple, idx: tuple,
     def launch():
         record_dispatch("groupby_mesh", mesh, len(idx), axis_slabs, idx,
                         axis, devices=mesh.size, collective=True)
-        return _groupby_cmat_mesh_fn(mesh, len(idx))(
-            axis_slabs, idx, axis, n_valid)
+        return launch_in_order(mesh, _groupby_cmat_mesh_fn(mesh, len(idx)),
+                               axis_slabs, idx, axis, n_valid)
 
     return on_collective_thread(launch)
 
@@ -683,8 +688,10 @@ class DeviceRunner:
             record_dispatch("ici_program", self.mesh, "pairs", n_slots,
                             pairs, src, devices=self.mesh.size,
                             collective=True)
-            return _pairs_count_mesh_fn(self.mesh, n_slots, pairs.ndim,
-                                        bool(lo))(pairs, src, *lo)
+            return launch_in_order(
+                self.mesh, _pairs_count_mesh_fn(self.mesh, n_slots,
+                                                pairs.ndim, bool(lo)),
+                pairs, src, *lo)
 
         return on_collective_thread(launch)
 

@@ -543,6 +543,11 @@ class HybridManager:
         self.demoted = 0       # transition to a lighter rep
         self.run_transitions = 0  # transitions entering or leaving "run"
         self.materialized = 0  # sparse/run leaves expanded to device planes
+        # launches that bridged a sparse list to a plane (alone or fused
+        # with a bitwise op; ops/bitvector.py _bridge) and the slots they
+        # carried
+        self.plane_bridges = 0
+        self.plane_bridge_slots = 0
         self.sparse_bytes_uploaded = 0
         self.run_bytes_uploaded = 0
         self.dense_bytes_uploaded = 0
@@ -681,6 +686,11 @@ class HybridManager:
         with self._lock:
             self.materialized += 1
 
+    def record_bridge(self, slots: int) -> None:
+        with self._lock:
+            self.plane_bridges += 1
+            self.plane_bridge_slots += int(slots)
+
     def snapshot(self) -> dict:
         with self._lock:
             return {
@@ -695,6 +705,8 @@ class HybridManager:
                 "demoted": self.demoted,
                 "runTransitions": self.run_transitions,
                 "materialized": self.materialized,
+                "planeBridges": self.plane_bridges,
+                "planeBridgeSlots": self.plane_bridge_slots,
                 "sparseBytesUploaded": self.sparse_bytes_uploaded,
                 "runBytesUploaded": self.run_bytes_uploaded,
                 "denseBytesUploaded": self.dense_bytes_uploaded,

@@ -575,16 +575,52 @@ def record_dispatch(family: str, *args, devices: int = 1,
         pass
 
 
-def _launch_devices(args, kwargs) -> int:
-    """The most devices an operand of a launch is laid over."""
+def _launch_devices(args, kwargs) -> tuple[int, str]:
+    """(the most devices an operand of a launch is laid over, their
+    platform)."""
     import jax
 
-    n = 1
+    n, platform = 1, ""
     for leaf in jax.tree_util.tree_leaves((args, kwargs)):
         if isinstance(leaf, jax.Array) and not isinstance(
                 leaf, jax.core.Tracer):
-            n = max(n, len(leaf.sharding.device_set))
-    return n
+            devs = leaf.sharding.device_set
+            if len(devs) > n:
+                n, platform = len(devs), next(iter(devs)).platform
+    return n, platform
+
+
+# On the CPU backend the parts of a program laid over several devices do
+# not run independently of each other as they do on the chip: two threads
+# that launch such programs at once, each reaching the devices in its own
+# order, can leave the all-reduce of a collective program waiting on one
+# device for a part that is queued on another behind the other thread's
+# program, for ever (a served four-device mesh under eight client threads
+# on a loaded host hung so once a kernel took milliseconds there). So on
+# the CPU every launch to several devices is made under this one lock,
+# and the programs reach every device in one order. The chip's runtime
+# needs no such order.
+_cpu_launch_order = lockwitness.native_rlock()
+
+
+def _launch(jitted, order, args, kwargs):
+    """jitted(*args, **kwargs), under `order` where it is a lock."""
+    if order is None:
+        return jitted(*args, **kwargs)
+    with order:
+        return jitted(*args, **kwargs)
+
+
+def _order_for(devices: int, platform: str):
+    return _cpu_launch_order if devices > 1 and platform == "cpu" else None
+
+
+def launch_in_order(mesh, fn, *args):
+    """fn(*args), a launch of a program laid over `mesh` that is not a
+    counted_jit kernel (the mesh's shard_map programs), in the one launch
+    order the CPU backend needs."""
+    return _launch(fn, _order_for(mesh.size, mesh.devices.flat[0].platform),
+                   args, {})
 
 
 def counted_jit(family: str, cross_shard: bool = False, **jit_kwargs):
@@ -622,9 +658,11 @@ def counted_jit(family: str, cross_shard: bool = False, **jit_kwargs):
             # holding a witnessed lock stalls every sibling of that lock
             # behind the accelerator (no-op unless PILOSA_TPU_LOCKCHECK=1)
             lockwitness.note_blocking("dispatch", family)
+            order = None
             if tracing.mesh_launches.watching:
-                devices = _launch_devices(args, kwargs)
+                devices, platform = _launch_devices(args, kwargs)
                 tracing.note_launch(devices, cross_shard and devices > 1)
+                order = _order_for(devices, platform)
             else:
                 tracing.note_launch()
             arity = -1
@@ -650,10 +688,10 @@ def counted_jit(family: str, cross_shard: bool = False, **jit_kwargs):
                 except Exception:  # noqa: BLE001 — never break dispatch
                     pass
             if arity < 0:  # stats off, tracer context, or flatten failed
-                return jitted(*args, **kwargs)
+                return _launch(jitted, order, args, kwargs)
             t0 = time.perf_counter()
             try:
-                return jitted(*args, **kwargs)
+                return _launch(jitted, order, args, kwargs)
             finally:
                 try:
                     kernels.record_call(

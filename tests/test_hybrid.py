@@ -41,6 +41,11 @@ def _as_set(sp_row):
     return set(arr[arr < SENT].tolist())
 
 
+def _plane_set(plane):
+    """The columns of a plane's first row."""
+    return set(bv.columns_from_dense(np.asarray(plane)[0]).tolist())
+
+
 # ------------------------------------------------------------- kernels
 
 
@@ -59,8 +64,8 @@ def test_sparse_kernel_algebra_matches_set_oracle(seed):
     assert int(np.asarray(bv.sparse_count(a))[0]) == len(sa)
     dense_b = jnp.asarray(
         bv.dense_from_columns(np.asarray(sorted(sb)))[None])
-    assert _as_set(bv.sparse_intersect_dense(a, dense_b)) == sa & sb
-    assert _as_set(bv.sparse_difference_dense(a, dense_b)) == sa - sb
+    assert _plane_set(bv.sparse_intersect_dense(a, dense_b)) == sa & sb
+    assert _plane_set(bv.sparse_difference_dense(a, dense_b)) == sa - sb
     assert int(np.asarray(bv.sparse_dense_count(a, dense_b))[0]) \
         == len(sa & sb)
     # round trip through the materializer
@@ -159,6 +164,167 @@ def test_sparse_merge_kernels_lower_without_gather_or_loop(kernel):
     ops = set(re.findall(r"stablehlo\.(\w+)", lowered.as_text()))
     assert "sort" in ops
     assert not ops & {"gather", "scatter", "while"}, sorted(ops)
+
+
+# every slot bucket a sparse leaf can take (8 ... 16,384) and a union's
+# 12,288 (8,192 + 4,096 slots)
+_BRIDGE_WIDTHS = tuple(8 << i for i in range(12)) + (12288,)
+
+
+def _bridge_case_rows(k, rng):
+    """The sets one leaf of k slots holds, a shard each: a full word, a
+    full half word, the last legal column, an all-sentinel row, an empty
+    row (built from no columns), a random row and a full one."""
+    last = SHARD_WIDTH - 1
+    word = set(range(32 * 777, 32 * 778))              # all 32 bits
+    half = set(range(32 * 5 + 16, 32 * 6))             # bits 16-31
+    edge = {0, 31, 32, last - 31, last}
+
+    def cut(cols):
+        return set(sorted(cols)[:k])
+
+    rnd = set(rng.choice(SHARD_WIDTH, k * 3 // 4, replace=False).tolist())
+    full = set(rng.choice(SHARD_WIDTH, k, replace=False).tolist())
+    return [cut(word | edge), cut(half | {last}), cut({last}), None,
+            set(), rnd, full, cut(word | half | edge | rnd)]
+
+
+def _leaf_of(rows, k):
+    return jnp.asarray(np.stack([
+        np.full(k, SENT, np.int32) if r is None
+        else bv.sparse_from_columns(np.asarray(sorted(r), np.int64), k)
+        for r in rows]))
+
+
+def _oracle_plane(rows):
+    """np.bitwise_or.at of every column's bit into a zeroed plane."""
+    out = np.zeros((len(rows), W), np.uint32)
+    for s, r in enumerate(rows):
+        cols = np.asarray(sorted(r or ()), np.int64)
+        np.bitwise_or.at(out[s], cols >> 5,
+                         (np.uint32(1) << (cols & 31).astype(np.uint32)))
+    return out
+
+
+@pytest.mark.parametrize("k", _BRIDGE_WIDTHS)
+def test_sparse_to_dense_matches_bitwise_or_at(k):
+    """The bridge as a product on the matrix unit, bit for bit against
+    np.bitwise_or.at, at every slot bucket, with a leading shard axis."""
+    rows = _bridge_case_rows(k, np.random.default_rng(k))
+    got = np.asarray(bv.sparse_to_dense(_leaf_of(rows, k), W))
+    assert got.shape == (len(rows), W) and got.dtype == np.uint32
+    np.testing.assert_array_equal(got, _oracle_plane(rows))
+    assert not got[3].any() and not got[4].any()   # sentinel-only rows
+    if k >= 64:
+        assert got[0, 777] == 0xFFFFFFFF and got[1, 5] == 0xFFFF0000
+
+
+@pytest.mark.parametrize("k", _BRIDGE_WIDTHS)
+def test_sparse_dense_fused_kernels_match_set_oracle(k):
+    """sparse ∩ / − dense as one program each, and their count: the
+    plane's columns against Python sets, at every slot bucket."""
+    rng = np.random.default_rng(k + 1)
+    rows = _bridge_case_rows(k, rng)
+    sets = [r or set() for r in rows]
+    # the plane: every other column of each row's set, a run around the
+    # full word, and a random tenth of the shard
+    planes = [set(sorted(r)[::2]) | set(range(32 * 770, 32 * 780))
+              | set(rng.choice(SHARD_WIDTH, SHARD_WIDTH // 10,
+                               replace=False).tolist()) for r in sets]
+    dense = jnp.asarray(np.stack([bv.dense_from_columns(
+        np.asarray(sorted(p), np.int64)) for p in planes]))
+    sp = _leaf_of(rows, k)
+    inter = np.asarray(bv.sparse_intersect_dense(sp, dense))
+    diff = np.asarray(bv.sparse_difference_dense(sp, dense))
+    count = np.asarray(bv.sparse_dense_count(sp, dense))
+    assert inter.shape == diff.shape == (len(rows), W)
+    for s, (r, p) in enumerate(zip(sets, planes)):
+        assert set(bv.columns_from_dense(inter[s]).tolist()) == r & p
+        assert set(bv.columns_from_dense(diff[s]).tolist()) == r - p
+        assert int(count[s]) == len(r & p)
+
+
+@pytest.mark.parametrize("kernel", ["sparse_to_dense", "sparse_intersect_dense",
+                                    "sparse_difference_dense"])
+def test_plane_bridge_kernels_lower_to_a_product_without_gather(kernel):
+    """The bridge and the two fused kernels are one dot_general with
+    elementwise work around it: a per-entry gather or scatter cost some
+    3 ms a call on the chip at [64, 4096] (PERF.md section 6), and the
+    sort that restored a list's order went with the list result."""
+    import re
+
+    import jax
+
+    spec = jax.ShapeDtypeStruct((64, 4096), jnp.int32)
+    fn = getattr(bv, kernel)
+    if kernel == "sparse_to_dense":
+        lowered = fn.lower(spec, W)
+    else:
+        lowered = fn.lower(spec, jax.ShapeDtypeStruct((64, W), jnp.uint32))
+    ops = set(re.findall(r"stablehlo\.(\w+)", lowered.as_text()))
+    assert "dot_general" in ops
+    assert not ops & {"gather", "scatter", "sort", "while"}, sorted(ops)
+
+
+def test_plane_bridge_on_four_devices_is_the_one_device_plane():
+    """A leaf sharded over four devices bridges, alone and fused, to the
+    plane one device makes, and the launch is a local one: the product
+    is per shard, so GSPMD partitions its batch axis with no collective."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from pilosa_tpu.parallel.mesh import SHARD_AXIS, make_mesh
+    from pilosa_tpu.utils import tracing
+
+    mesh = make_mesh(jax.devices()[:4])
+    rng = np.random.default_rng(41)
+    rows = (_bridge_case_rows(512, rng) * 2)[:8]
+    host = np.asarray(_leaf_of(rows, 512))
+    plane = np.stack([bv.dense_from_columns(rng.choice(
+        SHARD_WIDTH, 5000, replace=False)) for _ in rows])
+    on = NamedSharding(mesh, P(SHARD_AXIS))
+    sp4, dense4 = jax.device_put(host, on), jax.device_put(plane, on)
+    want = np.asarray(bv.sparse_to_dense(jnp.asarray(host), W))
+    tracing.mesh_launches.watching = True
+    before = tracing.mesh_launches.snapshot()
+    got = bv.sparse_to_dense(sp4, W)
+    inter = bv.sparse_intersect_dense(sp4, dense4)
+    after = tracing.mesh_launches.snapshot()
+    assert len({s.device for s in got.addressable_shards}) == 4
+    np.testing.assert_array_equal(np.asarray(got), want)
+    np.testing.assert_array_equal(np.asarray(inter), want & plane)
+    assert after["collectiveLaunches"] == before["collectiveLaunches"]
+    assert after["localLaunches"] == before["localLaunches"] + 2
+    hlo = bv.sparse_intersect_dense.lower(sp4, dense4).compile().as_text()
+    assert not any(c in hlo for c in ("all-reduce", "all-gather",
+                                      "collective-permute", "all-to-all"))
+
+
+def test_eval_hybrid_tells_of_every_bridge():
+    """eval_hybrid's on_bridge hears every launch that bridges a list to
+    a plane, alone or fused, with its slots; a list meeting a plane
+    under ∩ or − yields a plane, a list meeting a list stays a list."""
+    rng = np.random.default_rng(17)
+    sets = [set(rng.choice(SHARD_WIDTH, n, replace=False).tolist())
+            for n in (100, 300, 9000)]
+    leaves = [_sparse(sets[0], 128), _sparse(sets[1], 512),
+              jnp.asarray(bv.dense_from_columns(
+                  np.asarray(sorted(sets[2])))[None])]
+    kinds = ["sparse", "sparse", "dense"]
+    for prog, want_kind, want_bridges in (
+            (("and", ("leaf", 0), ("leaf", 1)), "sparse", []),
+            (("andnot", ("leaf", 1), ("leaf", 0)), "sparse", []),
+            (("and", ("leaf", 0), ("leaf", 2)), "dense", [128]),
+            (("and", ("leaf", 2), ("leaf", 1)), "dense", [512]),
+            (("andnot", ("leaf", 1), ("leaf", 2)), "dense", [512]),
+            (("andnot", ("leaf", 2), ("leaf", 0)), "dense", [128]),
+            (("or", ("leaf", 2), ("leaf", 0)), "dense", [128]),
+            (("and", ("or", ("leaf", 0), ("leaf", 1)), ("leaf", 2)),
+             "dense", [640])):
+        heard = []
+        kind, _ = bv.eval_hybrid(prog, leaves, kinds, W,
+                                 on_bridge=heard.append)
+        assert (kind, heard) == (want_kind, want_bridges), prog
 
 
 def test_eval_hybrid_mixed_tree():

@@ -244,6 +244,54 @@ def test_collective_thread_keeps_the_callers_span_and_raises_to_it():
         pmesh.on_collective_thread(lambda: 1 // 0)
 
 
+def test_cpu_launches_to_several_devices_keep_one_order():
+    """On the CPU backend every launch to several devices is made under
+    one lock (utils/telemetry.py), so that programs reach every device in
+    one order: without it a served mesh under load hung with an
+    all-reduce waiting for a part queued behind another thread's program.
+    While another thread holds the order, a launch to four devices waits
+    and a launch to one does not."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from pilosa_tpu.ops import bitvector as bv
+    from pilosa_tpu.parallel.mesh import SHARD_AXIS, make_mesh
+    from pilosa_tpu.utils import telemetry, tracing
+
+    mesh = make_mesh(jax.devices()[:4])
+    rows = jax.device_put(np.ones((8, 64), np.uint32),
+                          NamedSharding(mesh, P(SHARD_AXIS)))
+    one = jnp.ones((8, 64), jnp.uint32)
+    tracing.mesh_launches.watching = True  # as after any mesh in the process
+    jax.block_until_ready((bv.popcount(rows), bv.popcount(one)))  # compiled
+    held, release = threading.Event(), threading.Event()
+
+    def holder():
+        with telemetry._cpu_launch_order:
+            held.set()
+            release.wait(30)
+
+    def launch(x):
+        return threading.Thread(
+            target=lambda: jax.block_until_ready(bv.popcount(x)), daemon=True)
+
+    owner = threading.Thread(target=holder, daemon=True)
+    owner.start()
+    assert held.wait(30)
+    single, sharded = launch(one), launch(rows)
+    single.start()
+    sharded.start()
+    single.join(30)
+    sharded.join(0.5)
+    assert not single.is_alive()     # one device: no order to keep
+    assert sharded.is_alive()        # four: waits for the order
+    release.set()
+    sharded.join(30)
+    owner.join(30)
+    assert not sharded.is_alive()
+
+
 def test_one_device_counts_no_mesh_launch():
     """Without a mesh the block reads one device, a launch goes to one
     and moves none of the three counters; `collective` is the plain
