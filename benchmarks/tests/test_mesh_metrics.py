@@ -2,17 +2,12 @@
 of a window: a value where the server has a `mesh` block of several
 devices and the trace several device planes; None, and no exception, on
 the parent of the PR that brought them (no `mesh` block), on one chip and
-on nothing. And what the cell reports since PR 36 took `query_p95_ms` out
-of its end-to-end metrics: the tail as a per-layer reading, and no metric
-set against an end-to-end one the cell does not report."""
-
-import json
-import os
+on nothing. And the tail the cell records as a per-layer reading, since
+`query_p95_ms` is no end-to-end metric of it (what each cell reports:
+test_cell_metrics.py)."""
 
 import pytest
 
-from conftest import REPO
-import run as harness
 from test_span_metrics import reader
 
 PLANES = ["/device:TPU:0", "/device:TPU:1", "/device:TPU:2", "/device:TPU:3"]
@@ -66,29 +61,3 @@ def test_the_tail_where_it_is_no_end_to_end_metric():
     assert reader("tail_p95_ms")({"latencies_ms": ms[::-1]}) == 190.0
     assert reader("tail_p95_ms")({"latencies_ms": []}) is None
     assert reader("tail_p95_ms")({}) is None
-    assert reader("window_compiles.mesh4")({"window_compiles": 2}) == 2.0
-
-
-def test_a_cell_reads_no_metric_set_against_one_it_does_not_report():
-    with open(os.path.join(REPO, "BENCHMARK.json")) as fh:
-        bench = json.load(fh)
-    names = {}
-    for cell in bench["workloads"]:
-        end = {m["name"] for m in harness.metrics_of(bench, cell,
-                                                     "end_to_end")}
-        layer = harness.metrics_of(bench, cell, "per_layer")
-        assert "setup_s" in end and len(end) >= 2 and layer
-        assert all(m["moves"] in end for m in layer)
-        names[cell["name"]] = (end, {m["name"] for m in layer})
-    end, layer = names["segmentation-mesh4.adhoc"]
-    assert end == {"query_p50_ms", "queries_per_s", "setup_s"}
-    assert {"tail_p95_ms", "leaf_resolve_ms_per_query.mesh4",
-            "window_compiles.mesh4", "host_cpu_pct"} <= layer
-    assert not {"window_compiles", "leaf_resolve_ms_per_query"} & layer
-    for cell in ("segmentation.adhoc", "taxi.flight"):
-        end, layer = names[cell]
-        assert "query_p95_ms" in end
-        assert {"window_compiles", "leaf_resolve_ms_per_query",
-                "residency_hit_pct", "h2d_bytes_per_query"} <= layer
-        assert not {n for n in layer if n.endswith(".mesh4")}
-        assert "tail_p95_ms" not in layer

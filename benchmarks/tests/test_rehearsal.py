@@ -47,7 +47,8 @@ def test_rehearsal_ends_in_the_contracts_line(cell, trace):
     with open(os.path.join(REPO, "BENCHMARK.json")) as fh:
         bench = json.load(fh)
     group = "per_layer" if trace else "end_to_end"
-    allowed = {m["name"] for m in bench[group]
+    entry = {w["name"]: w for w in bench["workloads"]}[cell]
+    allowed = {m["name"] for m in harness.metrics_of(bench, entry, group)
                if m["source"] != "device_trace"}
     got = set(res["rehearsal_metrics"])
     assert got <= allowed

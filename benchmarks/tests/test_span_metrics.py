@@ -56,7 +56,6 @@ WANT = {
     "plan_ms_per_query": 60 / 10,
     "batcher_wait_ms_per_query": 120 / 4,
     "leaf_resolve_ms_per_query": 40 / 10,
-    "leaf_resolve_ms_per_query.mesh4": 40 / 10,
     "dispatch_host_ms_per_query": 700 / 10,
     "device_wait_ms_per_query": 18_000 / 10,
     "host_reduce_ms_per_query": (10 + 80 + 10) / 10,
